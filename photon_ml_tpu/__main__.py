@@ -31,6 +31,9 @@ def main(argv=None) -> None:
         raise SystemExit(0 if argv and argv[0] in ("-h", "--help") else 2)
     import importlib
 
+    from photon_ml_tpu import compile_cache
+
+    compile_cache.configure()
     driver = importlib.import_module(_DRIVERS[argv[0]])
     result = driver.run(argv[1:])
     if result:

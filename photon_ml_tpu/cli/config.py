@@ -410,6 +410,20 @@ def install_telemetry(config: TelemetryConfig):
                            metrics_port=config.metrics_port)
 
 
+def install_supervisor_telemetry(args):
+    """The ``--supervise`` parent's session: its spans and the
+    ``photon_supervisor_*`` bridge metrics land under ``supervisor/``; the
+    workers own the run's telemetry dirs and the metrics port (binding it
+    here too would collide with the chief worker's server). No poll either:
+    the device sampler calls ``jax.devices()``, and a parent that has
+    initialized the backend owns the chip — every worker would then fail
+    or hang. ``metrics.prom`` is still written at close."""
+    return install_telemetry(dataclasses.replace(
+        telemetry_from_args(args,
+                            subdir=os.path.join("supervisor", "telemetry")),
+        metrics_port=0, poll_interval_s=0.0))
+
+
 # ---------------------------------------------------------------------------
 # Retained-telemetry configuration (serve_game and serve_fleet)
 # ---------------------------------------------------------------------------

@@ -190,11 +190,7 @@ def _run_supervised(raw_argv: Sequence[str], args) -> dict:
     at N > 1) and return the chief's result dict + the restart count.
     Runs BEFORE any jax/backend touch — the supervisor process itself
     never trains."""
-    from photon_ml_tpu.cli.config import (
-        install_telemetry,
-        parse_grid,
-        telemetry_from_args,
-    )
+    from photon_ml_tpu.cli.config import install_supervisor_telemetry
     from photon_ml_tpu.resilience.supervisor import supervise_from_args
 
     if args.tuning != "NONE" or len(parse_grid(args.grid)) != 1:
@@ -205,16 +201,7 @@ def _run_supervised(raw_argv: Sequence[str], args) -> dict:
     worker_flags = ["--checkpoint", "--resume"]
     if args.supervise > 1:
         worker_flags.append("--multihost")
-    # the supervisor's own telemetry (supervisor.run/attempt spans and the
-    # photon_supervisor_* bridge metrics) lands under supervisor/ — the
-    # worker processes own the run's telemetry dirs AND the metrics port
-    # (binding it here too would collide with the chief worker's server)
-    import dataclasses as _dc
-
-    telemetry = install_telemetry(_dc.replace(
-        telemetry_from_args(args,
-                            subdir=os.path.join("supervisor", "telemetry")),
-        metrics_port=0))
+    telemetry = install_supervisor_telemetry(args)
     try:
         return supervise_from_args("train_game", raw_argv, args,
                                    worker_flags=worker_flags)

@@ -247,14 +247,10 @@ def run(argv: Optional[Sequence[str]] = None) -> dict:
         # FleetSupervisor (before any jax/backend touch). The GLM sweep
         # has no checkpoint — a restarted fleet re-solves from scratch,
         # which the deterministic sweep makes exactly repeatable.
-        import dataclasses as _dc
-
+        from photon_ml_tpu.cli.config import install_supervisor_telemetry
         from photon_ml_tpu.resilience.supervisor import supervise_from_args
 
-        telemetry = install_telemetry(_dc.replace(
-            telemetry_from_args(
-                args, subdir=os.path.join("supervisor", "telemetry")),
-            metrics_port=0))
+        telemetry = install_supervisor_telemetry(args)
         try:
             return supervise_from_args(
                 "train_glm", raw_argv, args,

@@ -155,6 +155,12 @@ class FixedEffectCoordinate:
               if warm_start is None
               else jnp.asarray(warm_start.model.coefficients.means))
         if self.dataset.n_shards > 1:
+            from photon_ml_tpu.parallel.mesh import replicated
+
+            # the cold start (zeros on one device) and the warm start (the
+            # previous solve's output, replicated over the mesh) must reach
+            # the program under ONE placement, or sweep 1 recompiles it
+            w0 = jax.device_put(w0, replicated(self.dataset.mesh))
             train_fn = _fixed_train_fn_dist(self.task, self.config,
                                             self.dataset.mesh)
         else:

@@ -91,18 +91,30 @@ class CoordinateDescentResult:
     final_evaluation: object = None  # Optional[EvaluationResults]
 
 
+#: what the score-memory guard assumes per device where the backend keeps no
+#: memory accounting (the CPU backend: ``memory_stats()`` is None) — host
+#: memory is not the guarded resource there
+_UNACCOUNTED_DEVICE_BYTES = 16 << 30
+
+
 def _device_memory_bytes() -> int:
-    """Best-effort per-device memory limit (used by the score-memory
-    guard); a conservative 16 GiB (v5e HBM) when the backend won't say."""
+    """Per-device memory limit for the score-memory guard: the smallest
+    ``bytes_limit`` over the local devices (the decomposition must fit on
+    each). A TPU backend that does not report one is an error, not a
+    guess."""
     import jax
 
-    try:
-        stats = jax.devices()[0].memory_stats()
+    limits = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
         if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return 16 << 30
+            limits.append(int(stats["bytes_limit"]))
+        elif d.platform == "tpu":
+            raise RuntimeError(
+                f"{d} reports no memory_stats()['bytes_limit'] (got "
+                f"{stats!r}): cannot size the score-memory guard — pass "
+                f"max_score_memory_bytes explicitly")
+    return min(limits) if limits else _UNACCOUNTED_DEVICE_BYTES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,7 +301,14 @@ class CoordinateDescent:
                                     "optimizer.step", new_scores,
                                     coordinate=cid, sweep=sweep)
                                 step_error = None
-                            except Exception as e:
+                            except FloatingPointError as e:
+                                # jax_debug_nans (--debug-nans) reports a
+                                # non-finite value by raising: that IS
+                                # divergence. Any other exception — a
+                                # compiler refusal, an out-of-memory, a bug
+                                # — is not, and propagates: freezing on it
+                                # would end the run with exit 0 and an
+                                # untrained coordinate.
                                 if guard is None:
                                     raise
                                 model, new_scores, step_error = None, None, e

@@ -155,10 +155,10 @@ class GameEstimator:
                               locked: Sequence[str]) -> None:
         """Dispatch the async host→device uploads the coordinates will need
         BEFORE the host-side bucket builds start: jax transfers are
-        asynchronous, so the ~35 MB/s wire streams the dense shard images /
-        labels / weights while the host packs buckets. Without this the
-        wire only starts when the first solve asks for the image — fully
-        serialized after the builds."""
+        asynchronous, so the dense shard images / labels / weights stream
+        to the device while the host packs buckets. Without this the
+        transfer only starts when the first solve asks for the image —
+        fully serialized after the builds."""
         from photon_ml_tpu.game.data import (
             choose_dense_design,
             design_dtype_of,

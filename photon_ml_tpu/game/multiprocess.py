@@ -1210,7 +1210,10 @@ def train_game_multiprocess(
                             new_scores = g_sc[primary_rows]
                     new_scores = fault_value("optimizer.step", new_scores,
                                              coordinate=cid, sweep=sweep)
-                except Exception as e:
+                except FloatingPointError as e:
+                    # only a non-finite report (jax_debug_nans) is
+                    # divergence; any other exception propagates — see
+                    # game/coordinate_descent.py
                     if guard is None:
                         raise
                     # deterministic faults raise SYMMETRICALLY (the plan's

@@ -1,21 +1,30 @@
 """Build/load the native ingest library and decode Avro training files.
 
 Pairs with ``native/avro_reader.cc`` (see its header comment for the role).
-The module compiles the shared library on first use (g++ -O2, linked against
-zlib), caches it under ``native/build/``, and exposes
-:func:`decode_training_file` returning flat numpy arrays. Callers must treat
-this as an optional fast path: :data:`available` is False when no compiler
-or library is usable, and ``AvroDataReader`` falls back to the pure-Python
-codec (:mod:`photon_ml_tpu.io.avro`).
+The module compiles the shared library on first use from the three committed
+sources (g++ -O3 -march=native, linked against zlib) into
+``native/build/libphoton_native-<key>.so`` and exposes
+:func:`decode_training_file` returning flat numpy arrays. ``<key>`` hashes
+the sources, the compiler flags and this host's CPU: ``-march=native`` bakes
+the build host's instruction set into the artifact, so a library built on
+one machine is never loaded on another (a checkout copied between hosts
+rebuilds instead of faulting on an unknown instruction), and an edited
+source can never pair with a stale binary. :data:`available` is False when
+the library cannot be built or loaded — said once, with the reason, at
+WARNING — and callers then take their pure-Python paths
+(``AvroDataReader`` decodes ~30x slower through :mod:`photon_ml_tpu.io.avro`).
 """
 
 from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import io
 import json
+import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Sequence
@@ -24,156 +33,170 @@ import numpy as np
 
 from photon_ml_tpu.io import avro as avro_mod
 
+logger = logging.getLogger(__name__)
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_SRC = os.path.join(_REPO_ROOT, "native", "avro_reader.cc")
-_SRC_WRITER = os.path.join(_REPO_ROOT, "native", "avro_writer.cc")
-_SRC_BUCKET = os.path.join(_REPO_ROOT, "native", "bucket_pack.cc")
+_SOURCES = tuple(os.path.join(_REPO_ROOT, "native", name) for name in
+                 ("avro_reader.cc", "avro_writer.cc", "bucket_pack.cc"))
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_BUILD_DIR, "libphoton_native.so")
+#: -march=native measured ~7% on the decode hot loop (figure from before
+#: PR 1; not measured on the present hosts)
+_FLAGS = ("-std=c++17", "-O3", "-march=native", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
+_load_error: Optional[str] = None
 
 #: canonical field order we emit; the file's order is matched against names
 _FIELDS = ("uid", "response", "offset", "weight", "features", "metadataMap")
 
 
-def _build() -> bool:
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the CPU model and its
+    instruction-set flags."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            return "".join(sorted({line for line in f if line.startswith(
+                ("model name", "flags"))}))
+    except OSError:
+        return platform.machine() + platform.processor()
+
+
+def _artifact_path() -> str:
+    """``native/build/libphoton_native-<key>.so`` for these sources, these
+    flags and this host. Raises OSError when a source is missing."""
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_cpu().encode())
+    return os.path.join(_BUILD_DIR,
+                        f"libphoton_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(lib_path: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    # -march=native first (measured ~7% on the decode hot loop; the library
-    # is always compiled on the machine that runs it), plain -O2 fallback
-    # for toolchains that reject it
-    for extra in (["-O3", "-march=native"], ["-O2"]):
-        cmd = (["g++", "-std=c++17"] + extra
-               + ["-shared", "-fPIC", "-o", _LIB,
-                  _SRC, _SRC_WRITER, _SRC_BUCKET, "-lz"])
-        try:
-            proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=120)
-        except (OSError, subprocess.TimeoutExpired):
-            continue  # let the plainer flag set have its try
-        if proc.returncode == 0 and os.path.exists(_LIB):
-            return True
-    return False
+    # compile beside the target and rename: a concurrent process (a
+    # supervised fleet's workers start together) never loads a half-written
+    # library
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp, *_SOURCES, "-lz"]
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True,
+                              timeout=300)
+        if proc.returncode != 0:
+            raise OSError(f"{' '.join(cmd)} exited {proc.returncode}: "
+                          f"{proc.stderr[-2000:]}")
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    global _lib, _load_error
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None or _load_error is not None:
             return _lib
         try:
-            src_mtime = max(os.path.getmtime(_SRC),
-                            os.path.getmtime(_SRC_WRITER),
-                            os.path.getmtime(_SRC_BUCKET))
-        except OSError:
-            # sources absent (installed wheel without the native tree):
-            # unbuildable → degrade to the Python fallback, never raise
-            src_mtime = None
-        if src_mtime is None and not os.path.exists(_LIB):
-            _load_failed = True
-            return None
-        if not os.path.exists(_LIB) or (
-                src_mtime is not None
-                and os.path.getmtime(_LIB) < src_mtime):
-            if not _build():
-                _load_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            _load_failed = True
-            return None
-        try:
-            lib.photon_decode_blocks.restype = ctypes.c_void_p
-            lib.photon_decode_blocks.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_int), ctypes.c_char_p, ctypes.c_char_p]
-            lib.photon_result_error.restype = ctypes.c_char_p
-            lib.photon_result_error.argtypes = [ctypes.c_void_p]
-            for name, res in (("n_records", ctypes.c_int64),
-                              ("nnz", ctypes.c_int64),
-                              ("n_feature_keys", ctypes.c_int32),
-                              ("feature_bytes_len", ctypes.c_int64)):
-                fn = getattr(lib, f"photon_result_{name}")
-                fn.restype = res
-                fn.argtypes = [ctypes.c_void_p]
-            lib.photon_result_copy_core.argtypes = [ctypes.c_void_p] + \
-                [np.ctypeslib.ndpointer(dtype=d, flags="C_CONTIGUOUS")
-                 for d in (np.float64, np.float64, np.float64, np.int64,
-                           np.int32, np.float64)]
-            lib.photon_result_copy_feature_keys.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p,
-                np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")]
-            lib.photon_result_id_vocab_size.restype = ctypes.c_int32
-            lib.photon_result_id_vocab_size.argtypes = [ctypes.c_void_p,
-                                                        ctypes.c_int32]
-            lib.photon_result_id_vocab_bytes_len.restype = ctypes.c_int64
-            lib.photon_result_id_vocab_bytes_len.argtypes = [ctypes.c_void_p,
-                                                             ctypes.c_int32]
-            lib.photon_result_copy_id_col.argtypes = [
-                ctypes.c_void_p, ctypes.c_int32,
-                np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS"),
-                ctypes.c_char_p,
-                np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")]
-            lib.photon_result_free.argtypes = [ctypes.c_void_p]
-            _i64p = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
-            _i32p = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
-            _f32p = np.ctypeslib.ndpointer(dtype=np.float32, flags="C_CONTIGUOUS")
-            _f64p = np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS")
-            lib.photon_shard_split_count.restype = None
-            lib.photon_shard_split_count.argtypes = [
-                _i64p, _i32p, ctypes.c_int64, _i32p, ctypes.c_int32, _i64p]
-            lib.photon_shard_split_fill.restype = None
-            lib.photon_shard_split_fill.argtypes = [
-                _i64p, _i32p, _f64p, ctypes.c_int64, _i32p, ctypes.c_int32,
-                _i64p, _i32p, _f32p]
-            lib.photon_counting_sort.restype = None
-            lib.photon_counting_sort.argtypes = [
-                _i64p, ctypes.c_int64, _i64p, _i64p]
-            lib.photon_re_feature_counts.restype = None
-            lib.photon_re_feature_counts.argtypes = [
-                _i64p, _i32p, _i64p, _i64p,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                _i64p, _i64p, _i64p]
-            lib.photon_re_bucket_fill.restype = None
-            lib.photon_re_bucket_fill.argtypes = [
-                _i64p, _i32p, _f32p, _i64p, _i64p, _f32p, _f32p, _i64p,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int64, _i64p, _i64p, _i64p, _i64p,
-                _f32p, _f32p, _f32p, _i64p, _i64p]
-            lib.photon_re_bucket_indices.restype = None
-            lib.photon_re_bucket_indices.argtypes = [
-                _i64p, _i32p, _i64p, _i64p, _i64p,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                _i64p, _i64p, _i64p, _i64p]
-            lib.photon_write_scoring_results.restype = ctypes.c_int64
-            lib.photon_write_scoring_results.argtypes = [
-                ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64,
-                np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS"),
-                ctypes.c_void_p,  # labels (f64*) or NULL
-                ctypes.c_char_p,  # uid bytes or NULL
-                ctypes.c_void_p,  # uid offsets (i64*) or NULL
-                ctypes.c_int64, ctypes.c_int64]
-            _f64p = np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS")
-            lib.photon_write_re_models.restype = ctypes.c_int64
-            lib.photon_write_re_models.argtypes = [
-                ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_char_p, _i64p,
-                ctypes.c_char_p, ctypes.c_int64,
-                _i64p, _i32p, _f64p,
-                ctypes.c_void_p,  # variances (f64*) or NULL
-                ctypes.c_char_p, _i64p, ctypes.c_char_p, _i64p,
-                ctypes.c_int64]
-        except AttributeError:
-            # a stale prebuilt library (sources absent, no
-            # rebuild possible) missing newer symbols must
-            # degrade to the pure-Python fallback, never raise
-            _load_failed = True
+            lib_path = _artifact_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            lib = ctypes.CDLL(lib_path)
+            _declare(lib)
+        except (OSError, subprocess.SubprocessError) as e:
+            _load_error = f"{type(e).__name__}: {e}"
+            logger.warning(
+                "native library unavailable, callers take their pure-Python "
+                "paths (Avro decode ~30x slower): %s", _load_error)
             return None
         _lib = lib
         return _lib
+
+
+def _declare(lib: ctypes.CDLL) -> None:
+    """ctypes signatures of every exported function."""
+    lib.photon_decode_blocks.restype = ctypes.c_void_p
+    lib.photon_decode_blocks.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int), ctypes.c_char_p, ctypes.c_char_p]
+    lib.photon_result_error.restype = ctypes.c_char_p
+    lib.photon_result_error.argtypes = [ctypes.c_void_p]
+    for name, res in (("n_records", ctypes.c_int64),
+                      ("nnz", ctypes.c_int64),
+                      ("n_feature_keys", ctypes.c_int32),
+                      ("feature_bytes_len", ctypes.c_int64)):
+        fn = getattr(lib, f"photon_result_{name}")
+        fn.restype = res
+        fn.argtypes = [ctypes.c_void_p]
+    lib.photon_result_copy_core.argtypes = [ctypes.c_void_p] + \
+        [np.ctypeslib.ndpointer(dtype=d, flags="C_CONTIGUOUS")
+         for d in (np.float64, np.float64, np.float64, np.int64,
+                   np.int32, np.float64)]
+    lib.photon_result_copy_feature_keys.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p,
+        np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")]
+    lib.photon_result_id_vocab_size.restype = ctypes.c_int32
+    lib.photon_result_id_vocab_size.argtypes = [ctypes.c_void_p,
+                                                ctypes.c_int32]
+    lib.photon_result_id_vocab_bytes_len.restype = ctypes.c_int64
+    lib.photon_result_id_vocab_bytes_len.argtypes = [ctypes.c_void_p,
+                                                     ctypes.c_int32]
+    lib.photon_result_copy_id_col.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32,
+        np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS"),
+        ctypes.c_char_p,
+        np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")]
+    lib.photon_result_free.argtypes = [ctypes.c_void_p]
+    _i64p = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
+    _i32p = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
+    _f32p = np.ctypeslib.ndpointer(dtype=np.float32, flags="C_CONTIGUOUS")
+    _f64p = np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS")
+    lib.photon_shard_split_count.restype = None
+    lib.photon_shard_split_count.argtypes = [
+        _i64p, _i32p, ctypes.c_int64, _i32p, ctypes.c_int32, _i64p]
+    lib.photon_shard_split_fill.restype = None
+    lib.photon_shard_split_fill.argtypes = [
+        _i64p, _i32p, _f64p, ctypes.c_int64, _i32p, ctypes.c_int32,
+        _i64p, _i32p, _f32p]
+    lib.photon_counting_sort.restype = None
+    lib.photon_counting_sort.argtypes = [
+        _i64p, ctypes.c_int64, _i64p, _i64p]
+    lib.photon_re_feature_counts.restype = None
+    lib.photon_re_feature_counts.argtypes = [
+        _i64p, _i32p, _i64p, _i64p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        _i64p, _i64p, _i64p]
+    lib.photon_re_bucket_fill.restype = None
+    lib.photon_re_bucket_fill.argtypes = [
+        _i64p, _i32p, _f32p, _i64p, _i64p, _f32p, _f32p, _i64p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, _i64p, _i64p, _i64p, _i64p,
+        _f32p, _f32p, _f32p, _i64p, _i64p]
+    lib.photon_re_bucket_indices.restype = None
+    lib.photon_re_bucket_indices.argtypes = [
+        _i64p, _i32p, _i64p, _i64p, _i64p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        _i64p, _i64p, _i64p, _i64p]
+    lib.photon_write_scoring_results.restype = ctypes.c_int64
+    lib.photon_write_scoring_results.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64,
+        np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS"),
+        ctypes.c_void_p,  # labels (f64*) or NULL
+        ctypes.c_char_p,  # uid bytes or NULL
+        ctypes.c_void_p,  # uid offsets (i64*) or NULL
+        ctypes.c_int64, ctypes.c_int64]
+    _f64p = np.ctypeslib.ndpointer(dtype=np.float64, flags="C_CONTIGUOUS")
+    lib.photon_write_re_models.restype = ctypes.c_int64
+    lib.photon_write_re_models.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_char_p, _i64p,
+        ctypes.c_char_p, ctypes.c_int64,
+        _i64p, _i32p, _f64p,
+        ctypes.c_void_p,  # variances (f64*) or NULL
+        ctypes.c_char_p, _i64p, ctypes.c_char_p, _i64p,
+        ctypes.c_int64]
 
 
 def available() -> bool:

@@ -33,6 +33,8 @@ the reference's normalization-aware aggregators.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import logging
 from typing import Optional
 
 import jax
@@ -48,6 +50,17 @@ from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import NormalizationContext, NoNormalization
 
 Array = jax.Array
+
+logger = logging.getLogger(__name__)
+
+
+@functools.lru_cache(maxsize=1024)
+def _log_declined(kernel: str, reason: str) -> None:
+    """A Pallas kernel that was asked for gives way to the XLA closed form:
+    say which predicate declined — once per distinct (kernel, reason), so
+    once per shape for the shape predicates; the gates run at trace time,
+    several times per trace."""
+    logger.info("%s declined: %s — XLA closed form", kernel, reason)
 
 
 @jax.tree_util.register_dataclass
@@ -163,41 +176,75 @@ class GLMObjective:
         return jnp.sum(contrib) + self._l2_term(w, l2)
 
     # --- derivatives ------------------------------------------------------
+    def _kernel_decline(self, design: Design) -> Optional[str]:
+        """What both Pallas kernels need whatever the shape, as the reason
+        they cannot serve ``design`` (None when they can): Mosaic lowering
+        needs a TPU (tests opt into the interpreter via fused_interpret), a
+        dense design, identity normalization."""
+        backend = jax.default_backend()
+        if backend != "tpu" and not self.fused_interpret:
+            return f"backend is {backend!r}, not 'tpu'"
+        if not isinstance(design, DenseDesign):
+            return f"design is {type(design).__name__}, not DenseDesign"
+        if not self.normalization.is_identity:
+            return "normalization is not the identity"
+        return None
+
     def _fused_eligible(self, data: GLMData) -> bool:
         """Single home of the fused-kernel gate (shared by value_and_grad,
-        hvp_prefers_operator, hvp_operator — they must not drift): Mosaic
-        lowering needs a TPU (tests opt into the interpreter via
-        fused_interpret), dense design, identity normalization, and a
-        no-copy auto block (shapes with no tile-aligned dividing block
-        would force the kernel to re-pad the full design per evaluation —
-        a net loss vs the closed form)."""
-        on_tpu = jax.default_backend() == "tpu"
-        if not (self.fused and (on_tpu or self.fused_interpret)
-                and isinstance(data.design, DenseDesign)
-                and self.normalization.is_identity):
+        hvp_prefers_operator, hvp_operator — they must not drift):
+        :meth:`_kernel_decline` plus a no-copy auto block (shapes with no
+        tile-aligned dividing block would force the kernel to re-pad the
+        full design per evaluation — a net loss vs the closed form). A
+        requested kernel that gives way says so (:func:`_log_declined`)."""
+        if not self.fused:
             return False
         from photon_ml_tpu.ops.pallas_glm import auto_block_rows
 
-        return auto_block_rows(data.n_samples, data.design.x.dtype) is not None
+        reason = self._kernel_decline(data.design)
+        if reason is None and auto_block_rows(
+                data.n_samples, data.design.x.dtype) is None:
+            reason = (f"no tile-aligned block divides the "
+                      f"{data.n_samples} rows of the ({data.n_samples}, "
+                      f"{data.dim}) design (the kernel would re-pad it per "
+                      f"evaluation)")
+        if reason is not None:
+            _log_declined("pallas_glm", reason)
+        return reason is None
 
-    def _entity_fused_eligible(self, data: GLMData) -> bool:
-        """Gate for the entity-batched kernel (``fused_entity``) — same
-        backend/design/normalization conditions as :meth:`_fused_eligible`,
-        but the shape test is the per-entity VMEM plan: under the bucket
-        vmap this objective sees ONE (S, D) lane, and the kernel blocks
-        over entities, so ``auto_block_rows`` over samples is the wrong
-        question."""
-        on_tpu = jax.default_backend() == "tpu"
-        if not (self.fused_entity and (on_tpu or self.fused_interpret)
-                and isinstance(data.design, DenseDesign)
-                and self.normalization.is_identity):
+    def _entity_kernel_serves(self, design: Design, s: int, d: int) -> bool:
+        """Gate for the entity-batched kernel (``fused_entity``) on lanes
+        of ``s`` samples by ``d`` features — :meth:`_kernel_decline` like
+        :meth:`_fused_eligible`, but the shape test is the per-entity VMEM
+        plan: the kernel blocks over entities, so ``auto_block_rows`` over
+        samples is the wrong question."""
+        if not self.fused_entity:
             return False
         from photon_ml_tpu.ops.pallas_re import lane_fits_vmem
 
-        return lane_fits_vmem(data.n_samples, data.dim, data.design.x.dtype)
+        reason = self._kernel_decline(design)
+        if reason is None and not lane_fits_vmem(s, d, design.x.dtype):
+            reason = (f"an 8-entity block of {jnp.dtype(design.x.dtype).name}"
+                      f" ({s}, {d}) lanes exceeds the kernel's VMEM budget")
+        if reason is not None:
+            _log_declined("pallas_re", reason)
+        return reason is None
+
+    def entity_pad(self, x: Array) -> int:
+        """Weight-0 lanes the bucket solver appends to an ``(E, S, D)``
+        bucket so the entity kernel's block plan divides it — 0 when the
+        kernel will not serve the bucket. Asks the gate its lanes will meet
+        under the vmap, so the pre-pad and the dispatch cannot disagree."""
+        e, s, d = x.shape
+        if not self._entity_kernel_serves(DenseDesign(x=x), s, d):
+            return 0
+        from photon_ml_tpu.ops.pallas_re import entity_pad
+
+        return entity_pad(e, s, d, x.dtype)
 
     def value_and_grad(self, w: Array, data: GLMData, l2=0.0) -> tuple[Array, Array]:
-        if self._entity_fused_eligible(data):
+        if self._entity_kernel_serves(data.design, data.n_samples,
+                                      data.dim):
             from photon_ml_tpu.ops.pallas_re import (
                 vmappable_entity_value_and_grad,
             )

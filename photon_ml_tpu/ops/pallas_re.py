@@ -24,10 +24,12 @@ has no single-program shape for. The M=1 matvec form already leaves
 wall), and random-effect dims are small (D is the per-entity local dim,
 typically 4–64, padded to one 128-lane tile), so the rank-3
 multiply-and-reduce on the VPU meets the HBM stream at full rate while
-the slab is read exactly once. Everything stays in the layout it arrives
-in — x blocks ``(BE, S, D)`` with the array's own trailing dims, vectors
-``(BE, S)``, coefficients ``(BE, D)`` — so there are no lane↔sublane
-relayouts (the round-1 killer documented in pallas_glm.py). f32 math runs
+the slab is read exactly once. Blocks keep the arrays' own trailing dims —
+x ``(BE, S, D)``, vectors ``(BE, S)``, coefficients ``(BE, D)`` — so no
+operand is copied to be blocked; inside the body the two contractions do
+move S between sublanes (in x) and lanes (in the vectors), relayouts
+Mosaic (libtpu 0.0.34) compiles at every shape tried and whose cost is not
+measured. f32 math runs
 on the VPU at full f32 precision — no MXU bf16-pass caveat, no
 ``Precision.HIGHEST`` needed; bf16 designs are upcast register-side after
 the half-width DMA (the whole point of storing the design bf16).
@@ -37,7 +39,8 @@ accumulation), so grid steps are independent and Pallas double-buffers the
 slab DMAs across steps.
 
 Block selection: ``entity_plan`` picks the largest multiple-of-8 entity
-block whose padded slab fits the scoped-VMEM budget. Entity counts rarely
+block whose operands and temporaries (``_entity_bytes``) fit the
+scoped-VMEM budget. Entity counts rarely
 divide it, and padding the batch INSIDE the traced objective would copy
 the full (E, S, D) design on every L-BFGS evaluation (the measured
 regression that shaped pallas_glm's auto mode) — so the SOLVER pre-pads
@@ -65,11 +68,14 @@ from jax.experimental.pallas import tpu as pltpu
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.pallas_glm import _out_struct
 
-#: resident bytes budgeted for one grid step's entity slab (x + vectors +
-#: outputs); Pallas double-buffers the next step's DMA on top, and the
-#: 16 MB scoped-VMEM limit caps the sum — 4 MiB keeps 2x pipelining plus
-#: headroom at the largest block
-VMEM_BUDGET_BYTES = 4 * 1024 * 1024
+#: what one grid step may hold in VMEM by :func:`_entity_bytes`' count:
+#: Mosaic's scoped limit for a kernel on the v5e (16 MiB — the kernel passes
+#: no ``vmem_limit_bytes``) less a quarter for what the count cannot see
+#: (relayout buffers, spills). The count is an upper bound — Mosaic streams
+#: most elementwise temporaries through registers: cross-compiled for a v5e
+#: at 16 lane shapes x f32/bf16 (PR 21), every planned block compiled, and
+#: so did twice the planned block.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 #: entity blocks are multiples of this: the f32 vector/output blocks
 #: ``(BE, S)`` / ``(BE, D)`` carry BE in the sublane dim, whose Mosaic
@@ -82,24 +88,36 @@ def _round_up(n: int, k: int) -> int:
 
 
 def _entity_bytes(s: int, d: int, dtype) -> int:
-    """VMEM bytes one entity lane occupies in a kernel block, tile padding
-    included: the (S, D) design slab pads S to the dtype's sublane tile and
-    D to one or more 128-wide lane tiles; the f32 label/offset/weight/
-    margin vectors and the coefficient/gradient rows ride alongside."""
-    sub = 16 if jnp.dtype(dtype) == jnp.bfloat16 else 8
-    s_pad = _round_up(max(s, 1), sub)
+    """VMEM bytes one entity lane costs a grid step, tile padding included
+    — everything :func:`_kernel` holds for it, so the plan and the compiler
+    agree:
+
+    - the pipelined operands, two buffers each: the stored ``(S, D)`` slab
+      (S padded to the dtype's sublane tile, D to 128-wide lane tiles), the
+      three f32 ``(S,)`` label/offset/weight vectors and the coefficient
+      row in, the value and gradient rows out;
+    - the body's slab-sized f32 temporaries: the two rank-3 products
+      (``xf * w[:, None, :]``, ``dvec[:, :, None] * xf``) and, for a bf16
+      design, the upcast ``xf`` itself (an f32 design is used as loaded);
+    - the body's vector-sized f32 temporaries: margins, their masked copy,
+      loss, derivative, weighted loss and the live mask.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
     d_pad = _round_up(max(d, 1), 128)
-    s_vec = _round_up(max(s, 1), 128)
-    slab = s_pad * d_pad * jnp.dtype(dtype).itemsize
-    vectors = 3 * 4 * s_vec  # labels / offsets / weights, f32
-    rows = 2 * 4 * d_pad  # w + grad, f32
-    return slab + vectors + rows
+    slab_stored = _round_up(max(s, 1), 8 * 4 // itemsize) * d_pad * itemsize
+    slab_f32 = _round_up(max(s, 1), 8) * d_pad * 4
+    vector = 4 * _round_up(max(s, 1), 128)
+    row = 4 * d_pad
+    operands = slab_stored + 3 * vector + 2 * row + 4 * 128  # + value row
+    n_slab_temps = 2 if itemsize == 4 else 3
+    return 2 * operands + n_slab_temps * slab_f32 + 6 * vector
 
 
 def entity_plan(e: int, s: int, d: int, dtype) -> "tuple[int, int] | None":
     """``(block_entities, padded_e)`` for an ``(e, s, d)`` bucket, or
     ``None`` when even a minimum (8-entity) block would blow the VMEM
-    budget — callers then keep the XLA closed form. Idempotent on its own
+    budget — callers then keep the XLA closed form (nothing else decides
+    whether a shape reaches Mosaic). Idempotent on its own
     padded size (``entity_plan(padded_e, ...)[1] == padded_e``), which is
     what lets the solver pre-pad once and the kernel re-derive the same
     plan with zero further copies."""
@@ -113,7 +131,7 @@ def entity_plan(e: int, s: int, d: int, dtype) -> "tuple[int, int] | None":
 
 def lane_fits_vmem(s: int, d: int, dtype) -> bool:
     """The E-independent eligibility half of :func:`entity_plan` — the
-    per-lane gate ``GLMObjective._entity_fused_eligible`` checks (under
+    per-lane gate ``GLMObjective._entity_kernel_serves`` checks (under
     vmap the objective sees one (S, D) lane, never the batch size)."""
     return entity_plan(ENTITY_TILE, s, d, dtype) is not None
 

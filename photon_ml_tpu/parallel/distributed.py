@@ -26,8 +26,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from photon_ml_tpu.compat import shard_map
 
 from photon_ml_tpu.ops.design import ChunkedSparseDesign, CsrDesign, DenseDesign
 from photon_ml_tpu.ops.objective import GLMData, GLMObjective

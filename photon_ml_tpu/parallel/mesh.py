@@ -22,12 +22,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.5: meshes carry per-axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 DATA_AXIS = "data"
 ENTITY_AXIS = "entity"
@@ -51,10 +46,9 @@ def make_mesh(
     if n_needed > len(devices):
         raise ValueError(f"mesh {axis_sizes} needs {n_needed} devices, have {len(devices)}")
     # Auto axis types: GSPMD propagates shardings; shard_map enters Manual
-    # mode explicitly where we want hand-placed psums (JAX >= 0.9 defaults
-    # to Explicit mode, which demands a global set_mesh context instead).
-    if AxisType is None:  # pre-AxisType jax: Auto is the only behavior
-        return jax.make_mesh(shape, names, devices=devices[:n_needed])
+    # mode explicitly where we want hand-placed psums (jax.make_mesh
+    # defaults to Explicit mode, which demands a global set_mesh context
+    # instead).
     return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names),
                          devices=devices[:n_needed])
 

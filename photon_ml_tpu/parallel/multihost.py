@@ -57,10 +57,7 @@ def _enable_cpu_collectives() -> None:
                     or os.environ.get("JAX_PLATFORMS", ""))
     if "cpu" not in platforms.split(","):
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pre-0.4.35 jax: no such flag
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize(coordinator_address: Optional[str] = None,
@@ -129,12 +126,8 @@ def initialize(coordinator_address: Optional[str] = None,
     # to fire. Budget each attempt an equal share of the deadline.
     init_kwargs = {}
     if policy.deadline_s is not None:
-        import inspect as _inspect
-
-        if ("initialization_timeout"
-                in _inspect.signature(jax.distributed.initialize).parameters):
-            init_kwargs["initialization_timeout"] = max(
-                1, int(np.ceil(policy.deadline_s / policy.max_attempts)))
+        init_kwargs["initialization_timeout"] = max(
+            1, int(np.ceil(policy.deadline_s / policy.max_attempts)))
     attempts = [0]
 
     def attempt() -> None:

@@ -45,7 +45,9 @@ class DivergenceError(RuntimeError):
 
 @dataclasses.dataclass(frozen=True)
 class DivergencePolicy:
-    """What to do when a coordinate step produces NaN/Inf (or throws).
+    """What to do when a coordinate step produces NaN/Inf (or reports one
+    by raising ``FloatingPointError`` under ``jax_debug_nans``; any other
+    exception is not divergence and propagates).
 
     ``reg_backoff`` multiplies the coordinate's regularization weight on
     every rollback-retry (a backoff schedule in curvature space);

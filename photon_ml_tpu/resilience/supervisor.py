@@ -434,6 +434,20 @@ def strip_supervision_flags(argv: Sequence[str]) -> list[str]:
     return out
 
 
+def _workers_would_open_tpu() -> bool:
+    """Whether worker processes started on this host would open a TPU
+    backend — decided WITHOUT touching JAX (a supervisor that initialized
+    the backend would hold the chip its workers need): JAX picks the TPU
+    whenever libtpu is installed, unless ``JAX_PLATFORMS`` names other
+    platforms only."""
+    import importlib.util
+
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return importlib.util.find_spec("libtpu") is not None
+
+
 def supervise_from_args(driver: str, raw_argv: Sequence[str], args,
                         *, worker_flags: Sequence[str] = ()) -> dict:
     """The drivers' ``--supervise N`` entry point: relaunch THIS command
@@ -441,6 +455,14 @@ def supervise_from_args(driver: str, raw_argv: Sequence[str], args,
     ``--checkpoint --resume --multihost``) as an N-process supervised
     fleet and return the chief's result dict with a ``restarts`` count
     added."""
+    if args.supervise > 1 and _workers_would_open_tpu():
+        raise SystemExit(
+            f"--supervise {args.supervise} starts {args.supervise} worker "
+            f"processes on this host and assigns them no devices: on a TPU "
+            f"host each would claim every chip, and all but one would fail "
+            f"or hang. Use --supervise 1 (one process drives all local "
+            f"chips; shard with --mesh), or set JAX_PLATFORMS=cpu for a "
+            f"CPU fleet.")
     command = [sys.executable, "-m", "photon_ml_tpu", driver]
     command += strip_supervision_flags(raw_argv)
     for f in worker_flags:

@@ -61,6 +61,7 @@ from __future__ import annotations
 import inspect
 import threading
 import time
+import weakref
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -70,11 +71,16 @@ from photon_ml_tpu.telemetry.metrics import MetricsRegistry
 
 __all__ = [
     "ProfiledFunction",
+    "compiled_programs",
     "profile_jit",
     "record_compile",
     "total_compiles",
     "install_xla_hooks",
 ]
+
+#: every live wrapper, so :func:`compiled_programs` can find by name the
+#: executables a driver compiled somewhere below its entry point
+_WRAPPERS: "weakref.WeakSet[ProfiledFunction]" = weakref.WeakSet()
 
 
 def _families(registry: Optional[MetricsRegistry] = None):
@@ -248,8 +254,17 @@ class ProfiledFunction:
         self._peak_memory = fams["peak_memory"].labels(fn=name)
         self._lock = threading.Lock()
         self._cache: dict = {}
+        _WRAPPERS.add(self)
 
     # --- introspection ----------------------------------------------------
+    def executables(self) -> list:
+        """The ``jax.stages.Compiled`` programs this wrapper holds — what
+        actually runs, e.g. for reading ``as_text()`` to see whether a
+        Pallas kernel or the XLA closed form was compiled in."""
+        with self._lock:
+            return [v[0] for v in self._cache.values()
+                    if not isinstance(v, _Pending)]
+
     @property
     def compiles(self) -> int:
         """Executables compiled by THIS wrapper so far."""
@@ -388,6 +403,13 @@ class ProfiledFunction:
                 except StopIteration:
                     break
         return tuple(out)
+
+
+def compiled_programs(name: str) -> list:
+    """Every executable compiled so far under ``fn=name``, across wrappers
+    (the per-(task, config) train functions share a name)."""
+    return [c for w in list(_WRAPPERS) if w.name == name
+            for c in w.executables()]
 
 
 def profile_jit(fn: Callable, name: str, *,

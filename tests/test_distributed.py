@@ -320,7 +320,7 @@ def test_fused_kernel_under_shard_map_interpret():
     # dynamic_slices (the real Mosaic lowering on TPU can — validated
     # on-chip through a mesh), so the interpret-mode check wraps its own
     # shard_map with check_vma=False around the fused objective.
-    from photon_ml_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fused_obj = GLMObjective(LogisticLoss, fused=True, fused_interpret=True)

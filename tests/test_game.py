@@ -435,7 +435,7 @@ class TestGameEstimator:
 
         data, _ = make_mixed_data(n=800, n_entities=11)
 
-        def build(mesh):
+        def build(mesh, sweeps=1):
             return GameEstimator(
                 task=TaskType.LOGISTIC_REGRESSION,
                 coordinate_configs={
@@ -449,7 +449,7 @@ class TestGameEstimator:
                             regularization=L2Regularization)),
                 },
                 update_sequence=["global", "perEntity"],
-                n_cd_iterations=1, mesh=mesh)
+                n_cd_iterations=sweeps, mesh=mesh)
 
         grid = [GameOptimizationConfiguration({"global": 0.01, "perEntity": 1.0})]
         r0 = build(None).fit(data, grid)[0]
@@ -463,6 +463,14 @@ class TestGameEstimator:
         fe1 = np.asarray(
             r1.model.coordinates["global"].model.coefficients.means)
         np.testing.assert_allclose(fe1, fe0, atol=2e-3)
+        # the flat-recompile contract holds on the mesh too: a warm sweep's
+        # inputs (previous coefficients, replicated over the mesh) reach
+        # the programs sweep 0 compiled under the cold start's placement
+        from photon_ml_tpu.telemetry import profiling
+
+        compiled = profiling.total_compiles()
+        build(mesh, sweeps=2).fit(data, grid)
+        assert profiling.total_compiles() == compiled
 
     def test_bf16_designs_on_mesh_match_unsharded_bf16(self):
         """bfloat16 designs through the DATA-SHARDED feed (shard_glm_data

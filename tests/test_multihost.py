@@ -919,7 +919,7 @@ def test_two_process_game_cd(tmp_path):
     effect on the global data mesh, entity-partitioned random effect solved
     process-locally, model table assembled by allgather — asserting the
     result is identical across processes and equal (to float tolerance) to
-    the single-process run (VERDICT r2 item 3; reference
+    the single-process run (reference
     ``data/RandomEffectDatasetPartitioner.scala``)."""
     _run_two_workers(tmp_path, _GAME_WORKER, "MULTIPROC_GAME_OK",
                      timeout=420)

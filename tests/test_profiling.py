@@ -17,8 +17,7 @@ The load-bearing contracts:
 - **perf_report golden**: the critical-path report is a deterministic
   function of (trace.jsonl, metrics.prom);
 - **bench_gate verdicts**: ok / regression / infra-failure /
-  missing-baseline, including the real BENCH_r05 device-unreachable
-  artifact.
+  missing-baseline, including a device-unreachable (rc=3) artifact.
 """
 
 import json
@@ -433,10 +432,17 @@ class TestBenchGate:
         v = bench_gate.gate(bench_gate.load_artifact(wrapped), None)
         assert v["verdict"] == "infra-failure"
 
-    def test_bench_r05_fixture_is_infra_failure(self):
-        """The real device-unreachable artifact: the shape the gate was
-        built to classify."""
-        art = bench_gate.load_artifact(os.path.join(REPO, "BENCH_r05.json"))
+    def test_device_unreachable_artifact_is_infra_failure(self, tmp_path):
+        """The harness wrapper of a run whose probe exited 3 with an error
+        summary and nothing measured: the shape the gate was built to
+        classify, against a real sound baseline."""
+        parsed = _summary({}, error="device unreachable: a trivial device "
+                                    "round-trip did not complete within 90s; "
+                                    "nothing was measured")
+        art = bench_gate.load_artifact(self._write(
+            tmp_path, "unreachable.json",
+            {"n": 5, "cmd": "python bench.py", "rc": 3,
+             "tail": json.dumps(parsed) + "\n", "parsed": parsed}))
         v = bench_gate.gate(art, bench_gate.load_artifact(
             os.path.join(REPO, "BENCH_r04.json")))
         assert v["verdict"] == "infra-failure"

@@ -1,4 +1,4 @@
-"""Prototype: data-sharded CD score vectors (ROADMAP item 5 / VERDICT r2 #5).
+"""Prototype: data-sharded CD score vectors (an earlier roadmap's item 5).
 
 Coordinate descent's score decomposition is device-resident but logically
 unsharded: each vector is one ``(n,)`` f32 array. Past ~2-3 B samples/chip

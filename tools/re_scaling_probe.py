@@ -1,4 +1,4 @@
-"""Random-effect scale-cliff probe (VERDICT r4 item 6).
+"""Random-effect scale-cliff probe.
 
 Measures, across (entities, rows) points, where the host bucket build and
 the device-resident fat tensors actually break:
