@@ -463,6 +463,9 @@ def run(argv: Optional[Sequence[str]] = None) -> dict:
             # the reference's OptimizationStatesTracker iteration table
             log_optimizer_trace(
                 tm.result, f"lambda={tm.regularization_weight:g}", run_logger)
+        # every solve has been waited for above: the glm.solve spans' device
+        # counts can go to trace.jsonl without a wait of their own
+        tracing.flush()
 
         # divergence guard over the sweep (pure reads: finiteness of the
         # trained coefficients). The GLM sweep has no rollback target —

@@ -292,6 +292,7 @@ class GameEstimator:
         the one RandomEffectCoordinate builds, so train() hits the same jit
         cache; RandomEffectSolver._warm_compile joins this thread before
         checking the done flag."""
+        import contextvars
         import threading
 
         from photon_ml_tpu.game.random_effect import RandomEffectSolver
@@ -299,8 +300,12 @@ class GameEstimator:
         solver = RandomEffectSolver(task=self.task, config=cfg.optimization,
                                     mesh=self.mesh,
                                     design_dtype=cfg.design_dtype)
-        th = threading.Thread(target=solver._warm_compile, args=(dataset, n),
-                              daemon=True)
+        # under the caller's span context: the thread's jit.compile spans
+        # belong to the stage that started it, not to the trace's roots
+        ctx = contextvars.copy_context()
+        th = threading.Thread(
+            target=lambda: ctx.run(solver._warm_compile, dataset, n),
+            daemon=True)
         object.__setattr__(dataset, "_warm_thread", th)
         th.start()
 

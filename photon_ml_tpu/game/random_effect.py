@@ -514,13 +514,18 @@ class RandomEffectSolver:
             _PRECOMPILED.add(hash((self, shape_pair)))
 
         import concurrent.futures as cf
+        import contextvars
 
         # upload-and-drop mode bounds peak HBM to ~one bucket; concurrent
         # dummy placements would hold one design per worker, so serialize
         workers = (1 if not dataset.config.cache_device_buckets
                    else min(8, len(shapes)))
+        # each compile under a copy of this thread's span context, so its
+        # jit.compile span is a child of the stage that waits for it here
+        ctxs = [contextvars.copy_context() for _ in shapes]
         with cf.ThreadPoolExecutor(max_workers=workers) as pool:
-            list(pool.map(compile_one, shapes))
+            list(pool.map(lambda ctx, s: ctx.run(compile_one, s),
+                          ctxs, shapes))
         object.__setattr__(dataset, "_warm_compiled", (self.mesh,))
 
     def train(

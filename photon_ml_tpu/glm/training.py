@@ -101,51 +101,69 @@ def train_glm_sweep(
     stacked per-device layout (see :func:`build_problem`) and ``dim`` names
     the coefficient length (the stacked layout's ``dim`` property reflects
     block shapes, not the model).
+
+    Spans (``telemetry/tracing.py``): one ``glm.sweep{solves, warm_start}``
+    around the whole call, one ``glm.solve{regularization_weight, iterations,
+    evaluations, converged}`` around each solve's dispatch, the last three
+    held as the result's device scalars and read only when the record is. A
+    ``glm.solve`` span's ``seconds`` is the HOST's dispatch time, never the
+    device's (the solves are dispatched back to back, nothing here waits for
+    one): the device time of a solve is the ``jit_run`` event of a profiler
+    trace. The span places the host on that timeline and carries the
+    device's counts. The first solve's span holds the call's
+    ``jit.compile``.
     """
     for lam in regularization_weights:
         config.regularization.check_weight(lam)
-    problem = build_problem(task, config, normalization, reg_mask, mesh=mesh)
 
-    from photon_ml_tpu.telemetry import profiling
-
-    # one compile serves the whole lambda sweep (lambda is a traced
-    # scalar); profile_jit makes that visible — photon_compiles_total
-    # {fn="glm.sweep_solve"} must move once per sweep, not per lambda
-    run = profiling.profile_jit(problem.run, "glm.sweep_solve")
-    d = data.dim if dim is None else dim
-    w = jnp.zeros((d,)) if initial is None else jnp.asarray(initial)
-
+    from photon_ml_tpu.resilience import fault_point, fault_value, heartbeat
+    from photon_ml_tpu.telemetry import profiling, tracing
     # fleet-metrics fold point (no-op unless --metrics-port installed a
     # hook). The lambda loop is the GLM driver's sweep boundary and is
     # collective-symmetric under --multihost: every process runs the
     # identical sorted sweep over the psum'd objective.
     from photon_ml_tpu.telemetry.aggregate import sweep_boundary
 
-    from photon_ml_tpu.resilience import fault_point, fault_value, heartbeat
-
     out: list[TrainedModel] = []
-    for lam in sorted(regularization_weights, reverse=True):
-        # per-lambda liveness + injection: the lambda loop is the GLM
-        # driver's sweep boundary (what the GAME drivers' per-sweep
-        # worker.stall / optimizer.step sites are to coordinate descent)
-        heartbeat("glm.sweep")
-        fault_point("worker.stall", regularization_weight=float(lam))
-        result = run(data, w, jnp.asarray(lam, w.dtype))
-        w_solved = fault_value("optimizer.step", result.w,
-                               regularization_weight=float(lam))
-        variances = problem.compute_variances(w_solved, data, lam)
-        coeffs = Coefficients(means=w_solved, variances=variances)
-        model = GeneralizedLinearModel(
-            coefficients=to_original_space(coeffs, normalization), task=task)
-        out.append(TrainedModel(float(lam), model, result))
-        if warm_start:
-            # an injected-NaN solve must not poison the NEXT lambda's warm
-            # start (nan init never recovers); the finiteness sync runs
-            # only when a fault actually corrupted the value, so the
-            # healthy path keeps its async dispatch untouched
-            if w_solved is result.w or bool(jnp.isfinite(w_solved).all()):
-                w = w_solved
-        sweep_boundary(regularization_weight=float(lam))
+    with tracing.span("glm.sweep", solves=len(regularization_weights),
+                      warm_start=bool(warm_start)):
+        problem = build_problem(task, config, normalization, reg_mask,
+                                mesh=mesh)
+        # one compile serves the whole lambda sweep (lambda is a traced
+        # scalar); profile_jit makes that visible — photon_compiles_total
+        # {fn="glm.sweep_solve"} must move once per sweep, not per lambda
+        run = profiling.profile_jit(problem.run, "glm.sweep_solve")
+        d = data.dim if dim is None else dim
+        w = jnp.zeros((d,)) if initial is None else jnp.asarray(initial)
+
+        for lam in sorted(regularization_weights, reverse=True):
+            # per-lambda liveness + injection: the lambda loop is the GLM
+            # driver's sweep boundary (what the GAME drivers' per-sweep
+            # worker.stall / optimizer.step sites are to coordinate descent)
+            heartbeat("glm.sweep")
+            fault_point("worker.stall", regularization_weight=float(lam))
+            with tracing.span("glm.solve",
+                              regularization_weight=float(lam)) as solve:
+                result = run(data, w, jnp.asarray(lam, w.dtype))
+                solve.set(iterations=result.iterations,
+                          evaluations=result.evaluations,
+                          converged=result.converged)
+            w_solved = fault_value("optimizer.step", result.w,
+                                   regularization_weight=float(lam))
+            variances = problem.compute_variances(w_solved, data, lam)
+            coeffs = Coefficients(means=w_solved, variances=variances)
+            model = GeneralizedLinearModel(
+                coefficients=to_original_space(coeffs, normalization),
+                task=task)
+            out.append(TrainedModel(float(lam), model, result))
+            if warm_start:
+                # an injected-NaN solve must not poison the NEXT lambda's
+                # warm start (nan init never recovers); the finiteness sync
+                # runs only when a fault actually corrupted the value, so
+                # the healthy path keeps its async dispatch untouched
+                if w_solved is result.w or bool(jnp.isfinite(w_solved).all()):
+                    w = w_solved
+            sweep_boundary(regularization_weight=float(lam))
     return out
 
 

@@ -22,12 +22,14 @@ matmuls against the SAME resident x block:
     margins  (1,B) = dot_general(w (1,D), x (B,D), contract D with D)
     grad    +(1,D) = dot_general(dvec (1,B), x (B,D), contract B with rows)
 
-Every figure in this docstring was measured on a TPU v5e between 2026-07-30
-and 2026-08-01, before PR 1, through a device runtime that no longer exists;
-none has been measured on the present chip, and the "~340 GB/s ceiling" in
-particular may have been that runtime's and not the chip's (819 GB/s
-published). They record why the kernel has the shape it has, not how fast it
-is. At (200k, 1024), 50-iteration compiled loop (objective evaluation only):
+How fast it is on the present chip is the benchmark's to say (PERF.md §5:
+on a TPU v5e the f32 kernel moves 1.5M x 1024 at about 71% of the chip's
+819 GB/s; ``glm_kernel_roofline_pct`` is its metric). Every figure below was
+measured on a TPU v5e between 2026-07-30 and 2026-08-01, before PR 1, through
+a device runtime that no longer exists, and none has been measured on the
+present chip: they record why the kernel has the shape it has, relative to
+one another, not how fast it is. At (200k, 1024), 50-iteration compiled loop
+(objective evaluation only):
 
     XLA two-pass closed form       3.61 ms/iter   (453 GB/s effective)
     this kernel, f32 (HIGHEST)     2.65 ms/iter   (1.36x)
@@ -37,8 +39,7 @@ is. At (200k, 1024), 50-iteration compiled loop (objective evaluation only):
 
 Round-2 block-size sweep (same shape, 50-iter fori_loop, best of 3):
 
-    f32  B=400 (auto)   2.658 ms/iter   308 GB/s effective — 91% of the
-                        ~340 GB/s single-op ceiling measured then.
+    f32  B=400 (auto)   2.658 ms/iter   308 GB/s effective
     f32  B=800          VMEM OOM (19.7 MB scoped > 16 MB limit)
     bf16 B=800 (auto)   1.947 ms/iter   210 GB/s eff
     bf16 B=1000         3.890 ms/iter   (sublane-hostile: 1000 % 16 != 0
@@ -49,7 +50,7 @@ Round-2 block-size sweep (same shape, 50-iter fori_loop, best of 3):
 bf16 is NOT bandwidth-bound: halving the bytes recovered only 1.37x over
 fused f32, flat across block sizes — the M=1 matvec shape leaves 127/128
 MXU rows idle, so at bf16's byte rate the kernel hits the issue/compute
-wall (~210 GB/s effective) before the HBM wall (~340). End-to-end the
+wall (~210 GB/s effective) before the HBM wall. End-to-end the
 bf16-design solve still measures ~1.4–1.5x over the f32 fused solve
 (101 ms vs 150 ms, 50 iterations) because line-search evaluations share
 the same kernel. Auto block sizes (f32 400, bf16 800) are within 2% of
@@ -265,6 +266,10 @@ def fused_value_and_grad(loss: PointwiseLoss, x, w, labels, offsets, weights,
     # multiple of 128 and usually rule out the no-copy dividing block size
     out = pl.pallas_call(
         functools.partial(_kernel, loss),
+        # the operation's name in a profiler trace, held here so that a
+        # refactoring cannot rename what the benchmark's readers match
+        # (benchmark/metrics/glm_kernel_roofline_pct.json)
+        name="fused_value_and_grad",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((b, d), lambda i: (i, 0), memory_space=pltpu.VMEM),

@@ -64,12 +64,18 @@ class OptimizerResult:
     ``values``/``grad_norms`` are fixed-length ``(max_iterations + 1,)`` traces
     padded with +inf beyond ``iterations`` — the reference's
     ``OptimizationStatesTracker`` as arrays.
+
+    ``evaluations`` counts the calls of the value-and-gradient function the
+    solve made, the one at ``w0`` included: ``iterations + 1`` when no line
+    search (or trust region) rejected a trial point, more by one for each
+    rejected one. TRON's Hessian-vector products are not evaluations.
     """
 
     w: Array
     value: Array
     grad_norm: Array
     iterations: Array  # int32 scalar
+    evaluations: Array  # int32 scalar
     converged: Array  # bool scalar
     values: Array
     grad_norms: Array
@@ -108,6 +114,9 @@ def armijo_backtracking(trial, sufficient, alpha0: Array, max_steps: int):
     bool`` is the acceptance predicate and MUST be written so NaN trial values
     return False (e.g. ``f_t <= bound``), which makes overflowing trial steps
     shrink instead of exiting the loop.
+
+    Returns ``(alpha, w_t, f_t, g_t, ok, trials)``; ``trials`` (int32) is the
+    number of calls of ``trial`` made, the first at ``alpha0`` included.
     """
     def cond(st):
         alpha, w_t, f_t, _, ls = st
@@ -119,10 +128,10 @@ def armijo_backtracking(trial, sufficient, alpha0: Array, max_steps: int):
         return alpha, w_t, f_t, g_t, st[4] + 1
 
     w1, f1, g1 = trial(alpha0)
-    alpha, w_t, f_t, g_t, _ = jax.lax.while_loop(
+    alpha, w_t, f_t, g_t, ls = jax.lax.while_loop(
         cond, body, (alpha0, w1, f1, g1, jnp.int32(0)))
     ok = sufficient(alpha, w_t, f_t) & jnp.isfinite(f_t)
-    return alpha, w_t, f_t, g_t, ok
+    return alpha, w_t, f_t, g_t, ok, ls + 1
 
 
 def update_history(s_hist: Array, y_hist: Array, rho: Array, n_pairs: Array,

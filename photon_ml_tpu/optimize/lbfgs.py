@@ -58,6 +58,7 @@ class _State:
     rho: Array  # (m,) 1 / (s.y)
     n_pairs: Array  # int32: total pairs ever stored (ring position = n % m)
     it: Array
+    evals: Array  # int32: calls of ``fun`` so far, the one at w0 included
     converged: Array
     failed: Array  # line search found no decrease
     stalls: Array  # int32: consecutive accepted steps with zero fp progress
@@ -105,7 +106,8 @@ def backtracking_line_search(fun: ValueAndGrad, w: Array, f: Array, g: Array,
                              d: Array, alpha0: Array, max_steps: int):
     """Armijo backtracking: shrink alpha until sufficient decrease.
 
-    Returns ``(alpha, f_new, g_new, w_new, ok)``. On total failure returns the
+    Returns ``(alpha, f_new, g_new, w_new, ok, trials)``, ``trials`` the
+    number of calls of ``fun``. On total failure returns the
     last trial point with ``ok=False`` (the reference's breeze throws a
     ``LineSearchFailed``; here the outer loop terminates via the flag). The
     acceptance predicate is NaN-safe: an overflowing trial (f=NaN/inf) shrinks
@@ -120,9 +122,9 @@ def backtracking_line_search(fun: ValueAndGrad, w: Array, f: Array, g: Array,
     def sufficient(alpha, w_t, f_t):
         return f_t <= f + _ARMIJO_C1 * alpha * gd
 
-    alpha, w_new, f_new, g_new, ok = armijo_backtracking(
+    alpha, w_new, f_new, g_new, ok, trials = armijo_backtracking(
         trial, sufficient, alpha0, max_steps)
-    return alpha, f_new, g_new, w_new, ok
+    return alpha, f_new, g_new, w_new, ok, trials
 
 
 def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
@@ -141,6 +143,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
         rho=jnp.zeros((m,), w0.dtype),
         n_pairs=jnp.int32(0),
         it=jnp.int32(0),
+        evals=jnp.int32(1),
         converged=gnorm0 <= tol,
         failed=jnp.asarray(False),
         stalls=jnp.int32(0),
@@ -158,7 +161,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
         # First step scales by 1/||g||, later steps start at 1 (standard L-BFGS).
         alpha0 = jnp.where(s.n_pairs > 0, 1.0,
                            1.0 / jnp.maximum(jnp.linalg.norm(d_dir), 1.0))
-        alpha, f_new, g_new, w_new, ok = backtracking_line_search(
+        alpha, f_new, g_new, w_new, ok, trials = backtracking_line_search(
             fun, s.w, s.f, s.g, d_dir, alpha0, config.max_line_search)
 
         s_hist, y_hist, rho, n_pairs = update_history(
@@ -184,6 +187,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
             g=jnp.where(ok, g_new, s.g),
             s_hist=s_hist, y_hist=y_hist, rho=rho, n_pairs=n_pairs,
             it=it,
+            evals=s.evals + trials,
             converged=ok & (gnorm <= tol),
             failed=(~ok) | (stalls >= 2),
             stalls=stalls,
@@ -193,6 +197,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
     final = lax.while_loop(cond, body, init)
     return OptimizerResult(
         w=final.w, value=final.f, grad_norm=jnp.linalg.norm(final.g),
-        iterations=final.it, converged=final.converged,
+        iterations=final.it, evaluations=final.evals,
+        converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
     )

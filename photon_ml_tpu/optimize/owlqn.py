@@ -78,7 +78,7 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
         s_hist=jnp.zeros((m, d), w0.dtype),
         y_hist=jnp.zeros((m, d), w0.dtype),
         rho=jnp.zeros((m,), w0.dtype),
-        n_pairs=jnp.int32(0), it=jnp.int32(0),
+        n_pairs=jnp.int32(0), it=jnp.int32(0), evals=jnp.int32(1),
         converged=pgnorm0 <= tol, failed=jnp.asarray(False),
         stalls=jnp.int32(0),
         values=values, grad_norms=gnorms,
@@ -112,7 +112,7 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
             # Armijo on the projected step, directional derivative pg.(w_t - w).
             return f_t <= s.f + _ARMIJO_C1 * jnp.vdot(s.pg, w_t - s.w)
 
-        alpha, w_new, f_new, g_new, ok = armijo_backtracking(
+        alpha, w_new, f_new, g_new, ok, trials = armijo_backtracking(
             trial, sufficient, alpha0, config.max_line_search)
 
         # Curvature pairs from smooth-gradient differences (A&G).
@@ -136,7 +136,8 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
             g=jnp.where(ok, g_new, s.g),
             pg=jnp.where(ok, pg_new, s.pg),
             s_hist=s_hist, y_hist=y_hist, rho=rho, n_pairs=n_pairs,
-            it=it, converged=ok & (pgnorm <= tol),
+            it=it, evals=s.evals + trials,
+            converged=ok & (pgnorm <= tol),
             failed=(~ok) | (stalls >= 2), stalls=stalls,
             values=values, grad_norms=gnorms,
         )
@@ -144,7 +145,8 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
     final = lax.while_loop(cond, body, init)
     return OptimizerResult(
         w=final.w, value=final.f, grad_norm=jnp.linalg.norm(final.pg),
-        iterations=final.it, converged=final.converged,
+        iterations=final.it, evaluations=final.evals,
+        converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
     )
 
@@ -161,6 +163,7 @@ class _State:
     rho: Array
     n_pairs: Array
     it: Array
+    evals: Array
     converged: Array
     failed: Array
     stalls: Array

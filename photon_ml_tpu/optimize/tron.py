@@ -116,7 +116,8 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
 
     init = _State(
         w=w0, f=f0, g=g0, delta=gnorm0,
-        it=jnp.int32(0), converged=gnorm0 <= tol, failed=jnp.asarray(False),
+        it=jnp.int32(0), evals=jnp.int32(1),
+        converged=gnorm0 <= tol, failed=jnp.asarray(False),
         values=values, grad_norms=gnorms,
     )
 
@@ -175,6 +176,7 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
             f=jnp.where(accept, f_new, s.f),
             g=jnp.where(accept, g_new, s.g),
             delta=delta, it=it,
+            evals=s.evals + 1,  # the one call of ``fun`` above; Hvps apart
             converged=accept & (jnp.linalg.norm(g_new) <= tol),
             failed=stuck,
             values=values, grad_norms=gnorms,
@@ -183,7 +185,8 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
     final = lax.while_loop(cond, body, init)
     return OptimizerResult(
         w=final.w, value=final.f, grad_norm=jnp.linalg.norm(final.g),
-        iterations=final.it, converged=final.converged,
+        iterations=final.it, evaluations=final.evals,
+        converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
     )
 
@@ -196,6 +199,7 @@ class _State:
     g: Array
     delta: Array
     it: Array
+    evals: Array
     converged: Array
     failed: Array
     values: Array

@@ -1,6 +1,6 @@
 """Train-time model-quality baselines + the drift arithmetic (PSI/KS).
 
-The telemetry stack observes the SYSTEM — latency, compiles, FLOPs,
+The telemetry stack observes the SYSTEM — latency, compiles, memory,
 restarts — while the model's predictions serve blind: with the continuous
 refresh loop auto-publishing versions into a watched directory
 (CONTINUOUS.md) and quantized tables introducing documented score

@@ -211,6 +211,11 @@ class FlightRecorder:
                     and mono - last < self._cooldown_s):
                 return None
             self._last_dump[reason] = mono
+        if self._tracer is not None:
+            # the span records still held back for a device value: now, and
+            # without a wait, since the device may be what hangs
+            self._tracer.flush(wait=False)
+        with self._lock:
             records = self._snapshot_locked()
             seq = self._seq
         wall = time.time() if ts is None else float(ts)

@@ -1,12 +1,11 @@
-"""Per-function jit profiling: compile/execute accounting + XLA cost analysis.
+"""Per-function jit profiling: compile/execute accounting + program memory.
 
 The span tree (tracing.py) answers "which STAGE took the wall-clock"; this
 module answers the layer below it — for each hot jitted program, how much of
-the wall went to *compilation* versus *execution*, what the compiled program
-costs per run (FLOPs and bytes accessed, from XLA's own cost model), and
-whether the program keeps recompiling (the training analog of serving's
-zero-recompile contract: the compile counter must go FLAT after the first
-coordinate-descent sweep).
+the wall went to *compilation* versus *execution*, how much memory the
+compiled program needs, and whether the program keeps recompiling (the
+training analog of serving's zero-recompile contract: the compile counter
+must go FLAT after the first coordinate-descent sweep).
 
 :func:`profile_jit` is the one wrapper. It replaces a ``jax.jit`` call site::
 
@@ -15,23 +14,24 @@ coordinate-descent sweep).
 
 and drives the jit through JAX's AOT API instead of the opaque dispatch
 cache: each distinct abstract signature (pytree structure + leaf
-shape/dtype/sharding + static values) is lowered and compiled ONCE, timed,
-cost-analyzed, and held in the wrapper's own executable cache. Every later
-call with that signature dispatches the cached executable directly. The
-accounting lands in the process-global metrics registry, so ``metrics.prom``
+shape/dtype/sharding + static values) is lowered and compiled ONCE, timed
+(inside a ``jit.compile`` span), and held in the wrapper's own executable
+cache. Every later call with that signature dispatches the cached
+executable directly. The accounting lands in the process-global metrics registry, so ``metrics.prom``
 and ``GET /metrics`` expose it with zero extra plumbing:
 
 - ``photon_compiles_total{fn}`` / ``photon_compile_seconds_total{fn}`` —
-  lower+compile events and their wall seconds, per wrapped function;
+  lower+compile events and their wall seconds, per wrapped function. The
+  seconds are those of the ``jit.compile{fn, lower_s, compile_s}`` span
+  around the same region (one bracket, both read it): host work the chip
+  waits for, so unlike a span around a dispatch its seconds are the real
+  thing;
 - ``photon_execute_latency_seconds{fn}`` — per-call latency histogram.
   NOTE async dispatch: jax returns before the device finishes, so by
   default this measures DISPATCH latency (the honest hot-path number —
   blocking here would serialize the coordinate-descent pipeline);
   ``block=True`` makes the timer wait for the result, for call sites that
   want device wall time;
-- ``photon_flops_total{fn}`` / ``photon_bytes_accessed_total{fn}`` — XLA
-  ``Compiled.cost_analysis()`` per-execution estimates, accumulated per
-  call, so ``rate(photon_flops_total)`` is an achieved-FLOPs/s estimate;
 - ``photon_peak_memory_bytes{fn}`` — ``Compiled.memory_analysis()``
   (arguments + outputs + temporaries) of the heaviest program compiled
   under the name.
@@ -67,6 +67,7 @@ from typing import Callable, Optional, Sequence
 import jax
 
 from photon_ml_tpu.telemetry import metrics as _metrics
+from photon_ml_tpu.telemetry import tracing as _tracing
 from photon_ml_tpu.telemetry.metrics import MetricsRegistry
 
 __all__ = [
@@ -102,14 +103,6 @@ def _families(registry: Optional[MetricsRegistry] = None):
             "Per-call latency of the compiled executable (dispatch-side "
             "unless the wrapper blocks; jax dispatch is async)",
             labels=("fn",)),
-        "flops": reg.counter(
-            "photon_flops_total",
-            "Estimated FLOPs executed (XLA cost analysis per-execution "
-            "estimate, accumulated per call)", labels=("fn",)),
-        "bytes": reg.counter(
-            "photon_bytes_accessed_total",
-            "Estimated bytes accessed (XLA cost analysis per-execution "
-            "estimate, accumulated per call)", labels=("fn",)),
         "peak_memory": reg.gauge(
             "photon_peak_memory_bytes",
             "Peak program memory (arguments+outputs+temporaries) of the "
@@ -249,8 +242,6 @@ class ProfiledFunction:
         self._compiles = fams["compiles"].labels(fn=name)
         self._compile_seconds = fams["compile_seconds"].labels(fn=name)
         self._execute = fams["execute"].labels(fn=name)
-        self._flops = fams["flops"].labels(fn=name)
-        self._bytes = fams["bytes"].labels(fn=name)
         self._peak_memory = fams["peak_memory"].labels(fn=name)
         self._lock = threading.Lock()
         self._cache: dict = {}
@@ -262,7 +253,7 @@ class ProfiledFunction:
         actually runs, e.g. for reading ``as_text()`` to see whether a
         Pallas kernel or the XLA closed form was compiled in."""
         with self._lock:
-            return [v[0] for v in self._cache.values()
+            return [v for v in self._cache.values()
                     if not isinstance(v, _Pending)]
 
     @property
@@ -295,28 +286,17 @@ class ProfiledFunction:
                 dynamics.append(value)
         return tuple(statics), tuple(dynamics), {}
 
-    def _analyze(self, compiled):
-        """(flops, bytes) per execution + peak memory from XLA's own cost
-        model; 0.0 where a backend declines to say (the counters then
-        simply stay flat for this fn)."""
-        flops = bytes_ = 0.0
-        try:
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            flops = max(float(ca.get("flops", 0.0)), 0.0)
-            bytes_ = max(float(ca.get("bytes accessed", 0.0)), 0.0)
-        except Exception:
-            pass
+    def _note_memory(self, compiled) -> None:
+        """Raise ``photon_peak_memory_bytes`` to this program's arguments +
+        outputs + temporaries, where the backend says."""
         try:
             ma = compiled.memory_analysis()
             peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                     + ma.temp_size_in_bytes)
-            if peak > self._peak_memory.value:
-                self._peak_memory.set(peak)
         except Exception:
-            pass
-        return flops, bytes_
+            return
+        if peak > self._peak_memory.value:
+            self._peak_memory.set(peak)
 
     def _compile(self, key, lower_args, lower_kwargs):
         """Lower+compile ``key``'s executable, once per signature across
@@ -337,17 +317,19 @@ class ProfiledFunction:
             return entry
         pending = entry
         try:
-            t0 = time.perf_counter()
-            lowered = self._jitted.lower(*lower_args, **lower_kwargs)
-            compiled = lowered.compile()
-            self._compile_seconds.inc(time.perf_counter() - t0)
+            with _tracing.span("jit.compile", fn=self.name) as sp:
+                t0 = time.perf_counter()
+                lowered = self._jitted.lower(*lower_args, **lower_kwargs)
+                t1 = time.perf_counter()
+                compiled = lowered.compile()
+                sp.set(lower_s=t1 - t0, compile_s=time.perf_counter() - t1)
+            self._compile_seconds.inc(sp.seconds)
             self._compiles.inc()
-            flops, bytes_ = self._analyze(compiled)
-            result = (compiled, flops, bytes_)
+            self._note_memory(compiled)
             with self._lock:
-                self._cache[key] = result
-            pending.result = result
-            return result
+                self._cache[key] = compiled
+            pending.result = compiled
+            return compiled
         except BaseException as e:
             pending.error = e
             with self._lock:
@@ -375,12 +357,7 @@ class ProfiledFunction:
             # jit resolves static_argnames positionally; pass the
             # normalized positional form so lowering sees what we keyed
             lower_args, lower_kwargs = self._ordered(statics, dyn_args), {}
-        compiled, flops, bytes_ = self._compile(key, lower_args,
-                                                lower_kwargs)
-        if flops:
-            self._flops.inc(flops)
-        if bytes_:
-            self._bytes.inc(bytes_)
+        compiled = self._compile(key, lower_args, lower_kwargs)
         with self._execute.time():
             out = compiled(*dyn_args, **dyn_kwargs)
             if self._block:

@@ -7,9 +7,18 @@ the wall-clock actually went. A span is one timed region with an id, its
 enclosing span's id (tracked per-thread via ``contextvars``, so concurrent
 serving requests each get their own stack), and arbitrary JSON attributes.
 
-- unconfigured (the default), spans cost two contextvar operations and a
-  ``perf_counter`` pair — cheap enough to leave permanently in hot-ish
-  paths like the coordinate-descent step loop;
+- unconfigured (the default), spans cost two contextvar operations, a
+  ``perf_counter`` pair and one ``TraceAnnotation.is_enabled()`` — cheap
+  enough to leave permanently in hot-ish paths like the coordinate-descent
+  step loop;
+- while a JAX profiler session runs (``jax.profiler.start_trace``; the
+  benchmark's ``--trace 1``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so it lies on ``/host:CPU``
+  of the same ``.xplane.pb`` as the device's ``XLA Ops``, on the profiler's
+  clock, and its record is kept;
+- such a span's record also goes to a bounded in-memory ring
+  (:func:`recorded` reads it): nothing is written at span exit for it, and
+  nothing is kept there when no profiler runs;
 - ``GLOBAL_TRACER.configure(path, bus=...)`` (done by the drivers'
   ``--telemetry-dir`` flag) appends one JSON line per completed span to
   ``<run_dir>/trace.jsonl`` and, when a bus is given, posts a
@@ -27,18 +36,41 @@ Record layout (one JSON object per line)::
 comparable within the process, so a child's interval provably nests inside
 its parent's (the property the telemetry tests assert); ``ts`` is the wall
 clock for humans correlating with ``photon.log``.
+
+An attribute may be a device scalar (``sp.set(iterations=result.iterations)``).
+It is held by reference: no span waits for the device. It becomes a Python
+number when the record is read (:func:`recorded`) or written. A record that
+holds one, and every record that completes behind it (so the file keeps its
+order, a child before its parent), goes to the file and the taps as soon as
+a later span completes and finds the value computed, at the latest at
+:func:`flush` (which the drivers call where they already block) or
+:func:`close`. At most ``PENDING_RECORDS`` wait so; one more, a value whose
+program failed, or ``flush(wait=False)`` (the flight recorder's dump) writes
+the record with the value ``None`` and its key under ``"unresolved"``. A span
+around a dispatch times the HOST in any case (``seconds`` is how long the
+call took to return), never the device: device time is the profiler's
+``XLA Modules`` event of the program.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Iterator, Optional
+
+#: completed records the in-memory ring keeps (the oldest fall out)
+RING_RECORDS = 4096
+
+#: records that may wait for a device value before the file and the taps see
+#: them (one more, and the oldest is written with its value unresolved)
+PENDING_RECORDS = 256
 
 #: the enclosing span's id on THIS thread/context (None = root)
 _CURRENT: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
@@ -52,7 +84,48 @@ _STACK: contextvars.ContextVar[tuple] = contextvars.ContextVar(
 
 #: reserved record keys — span attributes may not shadow them
 _RESERVED = frozenset(
-    {"name", "span_id", "parent_id", "ts", "t0", "t1", "seconds"})
+    {"name", "span_id", "parent_id", "ts", "t0", "t1", "seconds",
+     "unresolved"})
+
+
+def _profiler_running() -> bool:
+    """True exactly while a JAX profiler session runs. A process that has
+    not imported jax (the fleet router) has none, and is not made to."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+
+
+def _on_device(value) -> bool:
+    return hasattr(value, "block_until_ready")
+
+
+def _computed(record: dict) -> bool:
+    """True when no device value of ``record`` would be waited for."""
+    try:
+        return all(v.is_ready() for v in record.values() if _on_device(v))
+    except Exception:  # a failed program: nothing to wait for either
+        return True
+
+
+def _resolve(record: dict, wait: bool = True) -> dict:
+    """``record`` with every device value as a Python number (or list).
+    With ``wait``, waits for the device where a value is not computed yet.
+    Such a value without ``wait``, and in any case one whose program failed,
+    reads ``None`` and has its key listed under ``"unresolved"``."""
+    out, unresolved = {}, []
+    for key, value in record.items():
+        if _on_device(value):
+            try:
+                value = (value.tolist() if wait or value.is_ready()
+                         else None)
+            except Exception:
+                value = None
+            if value is None:
+                unresolved.append(key)
+        out[key] = value
+    if unresolved:
+        out["unresolved"] = unresolved
+    return out
 
 
 class Span:
@@ -85,7 +158,8 @@ class Span:
 
 
 class Tracer:
-    """Span factory + (optional) JSONL sink + (optional) EventBus bridge."""
+    """Span factory + in-memory ring + (optional) JSONL sink + (optional)
+    EventBus bridge."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -101,6 +175,12 @@ class Tracer:
         #: replaced wholesale on mutation so readers iterate an immutable
         #: snapshot without taking the lock on the span hot path
         self._taps: tuple = ()
+        #: the last RING_RECORDS records of spans that a profiler saw,
+        #: device values unresolved
+        self._ring: collections.deque = collections.deque(maxlen=RING_RECORDS)
+        #: records on their way to the file and the taps, oldest first: one
+        #: that holds a device value not computed yet, and those behind it
+        self._pending: collections.deque = collections.deque()
 
     @property
     def enabled(self) -> bool:
@@ -114,6 +194,7 @@ class Tracer:
     def configure(self, path: str, bus=None) -> "Tracer":
         """Start appending completed spans to ``path`` (parent dirs
         created). Reconfiguring closes the previous sink first."""
+        self.flush()
         with self._lock:
             if self._fh is not None:
                 self._fh.close()
@@ -124,14 +205,40 @@ class Tracer:
         return self
 
     def close(self) -> None:
-        """Stop exporting; spans keep working (and keep their parentage)
-        as no-ops."""
+        """Write what :meth:`flush` would, then stop exporting; spans keep
+        working (and keep their parentage) as no-ops."""
+        self.flush()
         with self._lock:
             if self._fh is not None:
                 self._fh.close()
             self._fh = None
             self._path = None
             self._bus = None
+
+    def flush(self, wait: bool = True) -> None:
+        """Hand the file and the taps every record that was held back for a
+        device value. With ``wait`` each value is waited for: for call
+        sites that already block on the work the spans were around. Without,
+        a value not computed yet is written as unresolved: for the flight
+        recorder's dump, which may be running because the device hangs."""
+        self._drain(lambda record, held: True, wait)
+
+    def _drain(self, due, wait: bool) -> None:
+        """Write the pending records, oldest first, for as long as
+        ``due(record, number pending)`` says so."""
+        while True:
+            with self._lock:
+                if not self._pending or not due(self._pending[0],
+                                                len(self._pending)):
+                    return
+                record = self._pending.popleft()
+            self._write(_resolve(record, wait))
+
+    def recorded(self) -> list[dict]:
+        """A snapshot of the ring, oldest first: the last ``RING_RECORDS``
+        records of spans that ran while a profiler did, device values as
+        Python numbers (waits for those not computed yet)."""
+        return [_resolve(r) for r in list(self._ring)]
 
     def add_tap(self, fn) -> "callable":
         """Call ``fn(record)`` for every completed span/annotation record
@@ -149,9 +256,28 @@ class Tracer:
 
     @property
     def _sinking(self) -> bool:
-        """True when a completed record goes anywhere (file or tap) —
-        the guard that keeps unconfigured spans dict-build-free."""
+        """True when a completed record goes to the file or a tap."""
         return self._fh is not None or bool(self._taps)
+
+    def _keep(self, record: dict, in_ring: bool) -> None:
+        """A completed record: into the ring if a profiler saw its span, and
+        to the file and the taps where there are any. No wait: a record with
+        a device value still being computed, and whatever completes behind
+        it, is written once a later call here finds the value computed (or
+        finds more than ``PENDING_RECORDS`` waiting)."""
+        if in_ring:
+            self._ring.append(record)
+        if not self._sinking:
+            return
+        with self._lock:
+            held = bool(self._pending) or not _computed(record)
+            if held:
+                self._pending.append(record)
+        if held:
+            self._drain(lambda head, n: n > PENDING_RECORDS
+                        or _computed(head), wait=False)
+        else:
+            self._write(_resolve(record, wait=False))
 
     def _write(self, record: dict) -> None:
         for tap in self._taps:
@@ -166,13 +292,28 @@ class Tracer:
                 self._fh.flush()
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Span]:
-        sp = Span(name, next(self._ids), _CURRENT.get(), attrs)
+    def _run(self, sp: Span, ancestors: tuple) -> Iterator[Span]:
+        """The one body of :meth:`span` and :meth:`span_under`, which differ
+        only in ``ancestors``: the ids, outermost first, among which a
+        parent that closed before this span did is re-found."""
         token = _CURRENT.set(sp.span_id)
-        ancestors = _STACK.get()
         stack_token = _STACK.set(ancestors + (sp.span_id,))
         with self._lock:
             self._open.add(sp.span_id)
+        # on the profiler's clock or not: decided once, at entry, so that a
+        # span is never half in the profiler's trace
+        annotation = None
+        if _profiler_running():
+            import jax
+
+            # span_id marks the event as one of the program's spans (what
+            # tools/perf_report.py --xplane tells them from JAX's own by)
+            # and joins it to its record
+            annotation = jax.profiler.TraceAnnotation(
+                sp.name, span_id=sp.span_id,
+                **{k: v for k, v in sp.attrs.items()
+                   if isinstance(v, (int, float, str))})
+            annotation.__enter__()
         sp.ts = time.time()
         sp.t0 = time.perf_counter()
         try:
@@ -186,26 +327,33 @@ class Tracer:
                 self._open.discard(sp.span_id)
             sp.t1 = time.perf_counter()
             sp.seconds = sp.t1 - sp.t0
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             _CURRENT.reset(token)
             _STACK.reset(stack_token)
             with self._lock:
                 if (sp.parent_id is not None
                         and sp.parent_id not in self._open):
-                    # async span outlived its lexical parent (background
-                    # writers inherit the submitting stage's context but
-                    # may finish after the stage closes): re-parent to the
-                    # nearest ancestor still open, so every recorded
-                    # interval provably nests inside its parent's — the
-                    # trace.jsonl enclosure contract
+                    # the span outlived its parent (background writers
+                    # inherit the submitting stage's context but may finish
+                    # after the stage closes; a fan-out leg may outlive its
+                    # request): re-parent to the nearest ancestor still
+                    # open, or to root, so every recorded interval provably
+                    # nests inside its parent's — the trace.jsonl enclosure
+                    # contract
                     sp.parent_id = next(
                         (a for a in reversed(ancestors) if a in self._open),
                         None)
-            if self._sinking:
-                self._write(sp.record())
+            if annotation is not None or self._sinking:
+                self._keep(sp.record(), in_ring=annotation is not None)
             bus = self._bus
             if bus is not None:
-                bus.post("span_finished", span=name, span_id=sp.span_id,
+                bus.post("span_finished", span=sp.name, span_id=sp.span_id,
                          parent_id=sp.parent_id, seconds=sp.seconds)
+
+    def span(self, name: str, **attrs):
+        return self._run(
+            Span(name, next(self._ids), _CURRENT.get(), attrs), _STACK.get())
 
     def annotate(self, name: str, **payload) -> None:
         """Write a non-span record (e.g. an optimizer iteration table) into
@@ -217,46 +365,20 @@ class Tracer:
                      "parent_id": _CURRENT.get(), "ts": time.time(),
                      **payload})
 
-    @contextlib.contextmanager
-    def span_under(self, parent_id: Optional[int], name: str,
-                   **attrs) -> Iterator[Span]:
+    def span_under(self, parent_id: Optional[int], name: str, **attrs):
         """A span with an EXPLICIT parent — for work handed to a pool
         thread where the submitting request's contextvars do not follow
         (the fleet router's fan-out legs). Inside the context, nested
         ``span()`` calls parent to this span as usual; at exit, a parent
         that already closed re-parents this span to root rather than
         recording an interval that leaks outside it."""
-        sp = Span(name, next(self._ids), parent_id, attrs)
-        token = _CURRENT.set(sp.span_id)
         # the explicit parent is the only known-open ancestor here: the
         # submitting thread's deeper ancestry is not visible to this pool
         # thread, and claiming it would let re-parenting resurrect spans
         # this leg never nested inside
-        ancestry = () if parent_id is None else (parent_id,)
-        stack_token = _STACK.set(ancestry + (sp.span_id,))
-        with self._lock:
-            self._open.add(sp.span_id)
-        sp.ts = time.time()
-        sp.t0 = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            with self._lock:
-                self._open.discard(sp.span_id)
-            sp.t1 = time.perf_counter()
-            sp.seconds = sp.t1 - sp.t0
-            _CURRENT.reset(token)
-            _STACK.reset(stack_token)
-            with self._lock:
-                if (sp.parent_id is not None
-                        and sp.parent_id not in self._open):
-                    sp.parent_id = None
-            if self._sinking:
-                self._write(sp.record())
-            bus = self._bus
-            if bus is not None:
-                bus.post("span_finished", span=name, span_id=sp.span_id,
-                         parent_id=sp.parent_id, seconds=sp.seconds)
+        return self._run(
+            Span(name, next(self._ids), parent_id, attrs),
+            () if parent_id is None else (parent_id,))
 
     def record_span(self, name: str, *, seconds: float,
                     parent_id: Optional[int] = None,
@@ -330,3 +452,11 @@ def configure(path: str, bus=None) -> Tracer:
 
 def close() -> None:
     GLOBAL_TRACER.close()
+
+
+def flush(wait: bool = True) -> None:
+    GLOBAL_TRACER.flush(wait)
+
+
+def recorded() -> list[dict]:
+    return GLOBAL_TRACER.recorded()

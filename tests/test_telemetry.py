@@ -557,25 +557,20 @@ class TestTrainGameTelemetry:
 
 class TestTrainGameProfiling:
     """The PR-5 acceptance contract: a --telemetry-dir train_game run
-    exposes the compile/cost accounting, the compile counter goes flat
+    exposes the compile accounting, the compile counter goes flat
     after sweep 1, and perf_report renders the run's artifacts."""
 
     def _parsed(self, telemetry_run):
         path = os.path.join(telemetry_run["telemetry_dir"], "metrics.prom")
         return tprom.parse_text(open(path).read())
 
-    def test_compile_and_cost_families_exposed(self, telemetry_run):
+    def test_compile_families_exposed(self, telemetry_run):
         parsed = self._parsed(telemetry_run)
         for fn in ("game.fixed_effect", "game.re.sweep_fused"):
             assert tprom.series_value(
                 parsed, "photon_compiles_total", {"fn": fn}) >= 1, fn
             assert tprom.series_value(
                 parsed, "photon_compile_seconds_total", {"fn": fn}) > 0, fn
-            # XLA's CPU cost model prices both solve programs
-            assert tprom.series_value(
-                parsed, "photon_flops_total", {"fn": fn}) > 0, fn
-            assert tprom.series_value(
-                parsed, "photon_bytes_accessed_total", {"fn": fn}) > 0, fn
         # the process-wide XLA pipeline listener saw the backend compiles
         assert tprom.series_value(
             parsed, "photon_xla_compile_seconds_total",

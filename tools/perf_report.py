@@ -14,7 +14,9 @@ This tool renders them into one deterministic text report:
   (``photon_compiles_total{fn}`` / ``photon_compile_seconds_total{fn}`` /
   ``photon_execute_latency_seconds{fn}`` — telemetry/profiling.py), per
   function and total, plus the process-wide XLA pipeline counters that
-  catch un-wrapped jits;
+  catch un-wrapped jits. The execute seconds are DISPATCH seconds unless
+  the wrapper blocks: they say where the host spent its time, and nothing
+  about the device's throughput;
 - **async I/O overlap** — how much of the ``io.save.*`` / ``io.read.*``
   span time (the background writer/prefetcher pipeline,
   ``io/pipeline.py``) lies hidden under training compute — the line that
@@ -29,8 +31,6 @@ This tool renders them into one deterministic text report:
   ``photon_serving_request_latency_seconds`` summary and the request-log
   budget counters — the serving counterpart of the training critical
   path (section present only when the snapshot carries serving series);
-- **FLOPs/s estimate** — ``photon_flops_total{fn}`` over the execute-sum
-  seconds (dispatch-side; a lower bound on device throughput).
 
 Usage::
 
@@ -38,6 +38,19 @@ Usage::
 
 where DIR is the run's ``--telemetry-dir``. Merged/aggregate artifacts are
 preferred automatically when present.
+
+A second report reads a profiler trace, not a run directory::
+
+    python tools/perf_report.py --xplane FILE.xplane.pb
+
+- **device idle gaps by program span** — while a profiler runs, every span of
+  ``telemetry/tracing.py`` is also an event on ``/host:CPU`` of the trace,
+  on the device events' clock. For every gap between the first chip's
+  ``XLA Ops`` inside the outermost program span, the innermost program span
+  that covers the gap's midpoint, summed by span name: what the host was
+  doing while the chip waited. A span's row is its own share, apart from
+  its children's. ``benchmark.run --trace 1 --keep-trace FILE`` writes such
+  a file; so does any run under ``--profile-dir``.
 """
 
 from __future__ import annotations
@@ -245,7 +258,7 @@ def _labeled(parsed: Mapping, series: str, label: str) -> dict[str, float]:
 
 
 def _fmt_count(v: float) -> str:
-    """Human scale for FLOP/byte totals (deterministic, 3 significant-ish
+    """Human scale for byte totals (deterministic, 3 significant-ish
     digits)."""
     for unit, div in (("T", 1e12), ("G", 1e9), ("M", 1e6), ("k", 1e3)):
         if abs(v) >= div:
@@ -302,34 +315,23 @@ def build_report(spans: Sequence[Mapping], prom_text: str,
     compile_s = _labeled(parsed, "photon_compile_seconds_total", "fn")
     exec_s = _labeled(parsed, "photon_execute_latency_seconds_sum", "fn")
     exec_n = _labeled(parsed, "photon_execute_latency_seconds_count", "fn")
-    flops = _labeled(parsed, "photon_flops_total", "fn")
-    bytes_ = _labeled(parsed, "photon_bytes_accessed_total", "fn")
     fns = sorted(set(compiles) | set(exec_n))
     if fns:
         lines.append(f"{'fn':<28} {'compiles':>8} {'compile_s':>10} "
-                     f"{'execs':>7} {'execute_s':>10} {'flops':>9} "
-                     f"{'GFLOP/s':>8}")
+                     f"{'execs':>7} {'execute_s':>10}")
         for fn in fns:
-            es = exec_s.get(fn, 0.0)
-            fl = flops.get(fn, 0.0)
-            rate = (fl / es / 1e9) if es > 0 else 0.0
             lines.append(
                 f"{fn:<28} {int(compiles.get(fn, 0)):>8d} "
                 f"{compile_s.get(fn, 0.0):>10.3f} "
-                f"{int(exec_n.get(fn, 0)):>7d} {es:>10.3f} "
-                f"{_fmt_count(fl):>9} {rate:>8.2f}")
+                f"{int(exec_n.get(fn, 0)):>7d} {exec_s.get(fn, 0.0):>10.3f}")
         tot_c, tot_e = sum(compile_s.values()), sum(exec_s.values())
-        tot_f = sum(flops.values())
-        rate = (tot_f / tot_e / 1e9) if tot_e > 0 else 0.0
         lines.append(
             f"{'TOTAL':<28} {int(sum(compiles.values())):>8d} "
             f"{tot_c:>10.3f} {int(sum(exec_n.values())):>7d} "
-            f"{tot_e:>10.3f} {_fmt_count(tot_f):>9} {rate:>8.2f}")
+            f"{tot_e:>10.3f}")
         if tot_c + tot_e > 0:
             share = 100.0 * tot_c / (tot_c + tot_e)
-            lines.append(f"compile share of (compile+execute): {share:.1f}%"
-                         f"  [bytes accessed: "
-                         f"{_fmt_count(sum(bytes_.values()))}B]")
+            lines.append(f"compile share of (compile+execute): {share:.1f}%")
     else:
         lines.append("  (no profiled-jit series in snapshot)")
     xla_n = _labeled(parsed, "photon_xla_compiles_total", "phase")
@@ -411,14 +413,112 @@ def resolve_inputs(run_dir: str) -> tuple[str, str]:
     return trace, prom
 
 
+# --- device idle gaps by program span (a profiler trace) -------------------
+
+#: gaps at most this long are summed in one row: between two operations of
+#: one program the chip is idle for microseconds, and no host span is why
+MIN_GAP_NS = 1_000_000
+NO_SPAN = "(no program span)"
+
+
+def load_program_spans(path: str) -> list[tuple[int, int, str]]:
+    """``(start_ns, end_ns, name)`` of the program's spans on the host planes
+    of a trace: the events that carry a ``span_id`` (``telemetry/tracing.py``
+    gives every span's ``TraceAnnotation`` one) and the benchmark's own
+    ``bench.*``. JAX's and the runtime's own host events are left out."""
+    import jax
+
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if (e.name.startswith("bench.")
+                        or any(k == "span_id" for k, _ in e.stats)):
+                    spans.append((int(e.start_ns),
+                                  int(e.start_ns + e.duration_ns), e.name))
+    return spans
+
+
+def gaps_by_span(ops: Sequence[tuple], spans: Sequence[tuple],
+                 min_gap_ns: int = MIN_GAP_NS) -> dict:
+    """The idle gaps of ``ops`` (device operations' intervals) inside the
+    extent of ``spans``, each put under the innermost (shortest) span that
+    covers its midpoint. Returns ``{"window_ns", "idle_ns", "short_ns",
+    "rows": {name: [gaps, ns]}}``; ``short_ns`` is the sum of the gaps of at
+    most ``min_gap_ns``, which no row holds."""
+    from benchmark import trace
+
+    lo = min(s for s, _, _ in spans)
+    hi = max(e for _, e, _ in spans)
+    rows: dict[str, list] = {}
+    idle = short = 0
+    for g0, g1 in trace.gaps(trace._clip(list(ops), lo, hi), lo, hi):
+        idle += g1 - g0
+        if g1 - g0 <= min_gap_ns:
+            short += g1 - g0
+            continue
+        mid = (g0 + g1) // 2
+        covering = [(e - s, n) for s, e, n in spans if s <= mid < e]
+        row = rows.setdefault(min(covering)[1] if covering else NO_SPAN,
+                              [0, 0])
+        row[0] += 1
+        row[1] += g1 - g0
+    return {"window_ns": hi - lo, "idle_ns": idle, "short_ns": short,
+            "rows": rows}
+
+
+def build_gap_report(path: str) -> str:
+    """The ``--xplane`` report text."""
+    from benchmark import trace
+
+    chips, _ = trace.load(path)
+    spans = load_program_spans(path)
+    if not chips or not chips[0].ops:
+        raise ValueError(f"{path} holds no device operation")
+    if not spans:
+        raise ValueError(f"{path} holds no program span: was the program "
+                         f"running under the profiler?")
+    table = gaps_by_span(chips[0].ops, spans)
+    window, idle = table["window_ns"], table["idle_ns"]
+    lines = ["== device idle gaps by program span ==",
+             f"window {window / 1e9:.3f} s (the program spans' extent), "
+             f"chip {chips[0].index} idle {idle / 1e9:.3f} s "
+             f"({100.0 * idle / window:.2f}%)",
+             f"{'idle_s':>10} {'% of idle':>10} {'gaps':>6}  innermost span "
+             f"at the gap's midpoint"]
+    ranked = sorted(table["rows"].items(), key=lambda kv: (-kv[1][1], kv[0]))
+    for name, (n, ns) in ranked:
+        lines.append(f"{ns / 1e9:>10.4f} {100.0 * ns / max(idle, 1):>10.1f} "
+                     f"{n:>6d}  {name}")
+    lines.append(f"{table['short_ns'] / 1e9:>10.4f} "
+                 f"{100.0 * table['short_ns'] / max(idle, 1):>10.1f} "
+                 f"{'':>6}  (gaps of at most {MIN_GAP_NS / 1e6:g} ms)")
+    return "\n".join(lines) + "\n"
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     p = argparse.ArgumentParser(
         description="Render a critical-path report from a --telemetry-dir "
-                    "run (trace.jsonl + metrics.prom)")
-    p.add_argument("run_dir", help="the run's --telemetry-dir")
+                    "run (trace.jsonl + metrics.prom), or the device's idle "
+                    "gaps by program span from a profiler trace")
+    p.add_argument("run_dir", nargs="?", help="the run's --telemetry-dir")
     p.add_argument("--top", type=int, default=10,
                    help="span groups to show in the critical path")
+    p.add_argument("--xplane", metavar="FILE",
+                   help="a profiler trace (.xplane.pb): report the device's "
+                        "idle gaps by program span instead")
     args = p.parse_args(argv)
+    if args.xplane:
+        try:
+            sys.stdout.write(build_gap_report(args.xplane))
+        except (OSError, ValueError) as e:
+            print(f"perf_report: {e}", file=sys.stderr)
+            return 1
+        return 0
+    if not args.run_dir:
+        p.error("give a run directory, or --xplane FILE")
     trace_path, prom_path = resolve_inputs(args.run_dir)
     if not os.path.exists(trace_path):
         print(f"no trace file under {args.run_dir} "
