@@ -5,6 +5,7 @@ elastic-net via OWLQN (sparsity + loss sanity), TRON vs L-BFGS solution
 agreement, warm-start sweep semantics, variance computation closed forms.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,9 +17,11 @@ from photon_ml_tpu.glm import (
     train_glm_sweep,
     validate_and_select,
 )
+from photon_ml_tpu.glm import training
 from photon_ml_tpu.models import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops.design import DenseDesign
 from photon_ml_tpu.ops.losses import loss_for_task
+from photon_ml_tpu.ops.normalization import NormalizationContext, NoNormalization
 from photon_ml_tpu.ops.objective import GLMData, GLMObjective
 from photon_ml_tpu.ops.regularization import (
     L2Regularization,
@@ -26,6 +29,7 @@ from photon_ml_tpu.ops.regularization import (
 )
 from photon_ml_tpu.optimize import OptimizerConfig
 from photon_ml_tpu.evaluation import parse_evaluators
+from photon_ml_tpu.telemetry import metrics, tracing
 from photon_ml_tpu.types import OptimizerType, TaskType, VarianceComputationType
 
 
@@ -337,3 +341,193 @@ class TestA1aShapedAucParity:
         auc_sk = roc_auc_score(yv, xv @ sk.coef_[0] + sk.intercept_[0])
         assert abs(auc_ours - auc_sk) < 1e-4, (auc_ours, auc_sk)
         assert auc_ours > 0.7, auc_ours  # the model actually learned
+
+
+# --- the compiled solve outlives the call -----------------------------------
+
+LOGISTIC = TaskType.LOGISTIC_REGRESSION
+HELD = GLMOptimizationConfiguration(
+    optimizer=OptimizerType.LBFGS, regularization=L2Regularization,
+    optimizer_config=OptimizerConfig(max_iterations=40, tolerance=1e-9))
+D = 9  # make_classification's eight features and the intercept
+
+
+def _compiles(fn="glm.sweep_solve"):
+    """``photon_compiles_total{fn}`` as the process stands."""
+    family = metrics.default_registry().get("photon_compiles_total")
+    return 0.0 if family is None else family.labels(fn=fn).value
+
+
+def _scaling(seed, shifts=False):
+    rng = np.random.default_rng(seed)
+    factors = jnp.asarray(rng.uniform(0.5, 2.0, size=D)).at[-1].set(1.0)
+    if not shifts:
+        return NormalizationContext(factors=factors)
+    return NormalizationContext(
+        factors=factors,
+        shifts=jnp.asarray(rng.normal(size=D) * 0.1).at[-1].set(0.0),
+        intercept_index=D - 1)
+
+
+def _mask(*free):
+    """A 0/1 selector that leaves the coefficients ``free`` unregularized."""
+    return jnp.ones(D).at[jnp.asarray(free)].set(0.0)
+
+
+def _means(trained):
+    return [np.asarray(t.model.coefficients.means) for t in trained]
+
+
+def _fresh_means(data, weights, normalization, reg_mask):
+    """The sweep by a fresh ``jax.jit`` of ``build_problem(...).run`` that
+    closes over its own normalization and mask: what the held program must
+    give bit for bit."""
+    problem = training.build_problem(LOGISTIC, HELD, normalization, reg_mask)
+    run = jax.jit(problem.run)
+    w, out = jnp.zeros((D,)), []
+    for lam in sorted(weights, reverse=True):
+        w = run(data, w, jnp.asarray(lam, w.dtype)).w
+        out.append(np.asarray(training.to_original_space(
+            Coefficients(means=w), normalization).means))
+    return out
+
+
+@pytest.fixture
+def cold():
+    """No solve held: the test's first call is its signature's first."""
+    training._sweep_solve_fn.cache_clear()
+
+
+class TestHeldSolve:
+    def test_second_call_compiles_nothing(self, cold):
+        """(a) two calls on one shape: one compile, and the second call
+        emits no ``jit.compile`` span."""
+        data, _, _ = make_classification(seed=11)
+        before = _compiles()
+        first = train_glm_sweep(LOGISTIC, data, [1.0, 0.1], HELD)
+        assert _compiles() - before == 1
+        seen = []
+        remove = tracing.GLOBAL_TRACER.add_tap(seen.append)
+        try:
+            second = train_glm_sweep(LOGISTIC, data, [1.0, 0.1], HELD)
+            tracing.flush()
+        finally:
+            remove()
+        assert _compiles() - before == 1
+        names = [r["name"] for r in seen]
+        assert names.count("glm.sweep") == 1 and names.count("glm.solve") == 2
+        assert "jit.compile" not in names
+        for a, b in zip(_means(first), _means(second)):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("varied", ["factors", "shifts", "reg_mask"])
+    def test_values_are_arguments_not_constants(self, cold, varied):
+        """(b) two calls that differ in the VALUES of the normalization or
+        of the mask share one program, and each answers for its own: a
+        closed-over constant gone stale would give the second call the
+        first's."""
+        data, _, _ = make_classification(seed=12)
+        if varied == "factors":
+            cases = [(_scaling(1), None), (_scaling(2), None)]
+        elif varied == "shifts":
+            cases = [(_scaling(1, shifts=True), None),
+                     (_scaling(2, shifts=True), None)]
+        else:
+            cases = [(NoNormalization, _mask(D - 1)),
+                     (NoNormalization, _mask(0, 3))]
+        before = _compiles()
+        got = [_means(train_glm_sweep(LOGISTIC, data, [2.0, 0.5], HELD,
+                                      normalization=n, reg_mask=m))
+               for n, m in cases]
+        assert _compiles() - before == 1
+        assert not np.array_equal(got[0][-1], got[1][-1])
+        # constant shifts let XLA fold factors * shifts ahead of time in
+        # the fresh program: the last digit there, every bit elsewhere
+        atol = 1e-7 if varied == "shifts" else 0.0
+        for (n, m), means in zip(cases, got):
+            for a, b in zip(means, _fresh_means(data, [2.0, 0.5], n, m)):
+                np.testing.assert_allclose(a, b, rtol=0.0, atol=atol)
+
+    def test_one_executable_per_structure_under_one_wrapper(self, cold):
+        """(c) identity, scaling, scaling with shifts, each with a mask and
+        without: six executables, one wrapper, chosen by the input."""
+        data, _, _ = make_classification(seed=13)
+        run = training._sweep_solve_fn(LOGISTIC, HELD, None, False)
+        structures = [(n, m)
+                      for n in (NoNormalization, _scaling(3),
+                                _scaling(3, shifts=True))
+                      for m in (None, _mask(D - 1))]
+        for k, (n, m) in enumerate(structures, start=1):
+            for _ in range(2):  # the second call of a structure adds none
+                train_glm_sweep(LOGISTIC, data, [1.0], HELD,
+                                normalization=n, reg_mask=m)
+                assert run.compiles == k
+        assert training._sweep_solve_fn(LOGISTIC, HELD, None, False) is run
+        assert training._sweep_solve_fn.cache_info().currsize == 1
+
+    @pytest.mark.parametrize("batched", [False, True])
+    def test_the_solve_lowers_as_jit_run(self, batched):
+        """(d) the benchmark reads the solve's device time from the programs
+        named ``jit_run``."""
+        data, _, _ = make_classification(seed=14)
+        run = training._sweep_solve_fn(LOGISTIC, HELD, None, batched)
+        lam = jnp.ones((2,)) if batched else jnp.asarray(1.0)
+        text = run._jitted.lower(data, jnp.zeros((D,)), lam,
+                                 NoNormalization, None).as_text()
+        assert "module @jit_run " in text
+
+    @pytest.mark.parametrize("sweep", ["sequential", "batched"])
+    def test_a_mask_that_is_not_zero_one_still_raises(self, sweep):
+        """(e) the traced mask inside the program cannot be checked; the
+        concrete one is, at the call's entry."""
+        data, _, _ = make_classification(seed=15)
+        fn = train_glm_sweep if sweep == "sequential" \
+            else training.train_glm_sweep_batched
+        with pytest.raises(ValueError, match="0/1 selector"):
+            fn(LOGISTIC, data, [1.0], HELD,
+               reg_mask=jnp.ones(D).at[-1].set(0.5))
+
+    def test_batched_twice_compiles_once(self, cold):
+        """(f)"""
+        data, _, _ = make_classification(seed=16)
+        before = _compiles("glm.sweep_solve_batched")
+        first = training.train_glm_sweep_batched(
+            LOGISTIC, data, [1.0, 0.1], HELD, normalization=_scaling(4))
+        second = training.train_glm_sweep_batched(
+            LOGISTIC, data, [1.0, 0.1], HELD, normalization=_scaling(5))
+        assert _compiles("glm.sweep_solve_batched") - before == 1
+        assert not np.array_equal(_means(first)[-1], _means(second)[-1])
+        sequential = train_glm_sweep(
+            LOGISTIC, data, [1.0, 0.1], HELD, normalization=_scaling(5),
+            warm_start=False)
+        for a, b in zip(_means(second), _means(sequential)):
+            np.testing.assert_allclose(a, b, atol=1e-6)
+
+    @pytest.mark.parametrize("shifts", [False, True])
+    def test_mesh_path_holds_its_solve_too(self, cold, shifts):
+        """(g) under a mesh the ``shard_map`` bodies close over the outer
+        trace's normalization and mask: two calls with different factors
+        compile once (cold and warm starts under one placement), and agree
+        with one device."""
+        from photon_ml_tpu.parallel import make_mesh, shard_glm_data
+
+        assert jax.device_count() >= 8
+        mesh = make_mesh({"data": 8})
+        data, _, _ = make_classification(n=203, seed=17)
+        sharded = shard_glm_data(data, 8, device_put_mesh=mesh)
+        mask = _mask(D - 1)
+        tight = GLMOptimizationConfiguration(
+            optimizer=OptimizerType.LBFGS, regularization=L2Regularization,
+            optimizer_config=OptimizerConfig(max_iterations=200,
+                                             tolerance=1e-10))
+        for seed in (6, 7):
+            n = _scaling(seed, shifts=shifts)
+            dist = train_glm_sweep(LOGISTIC, sharded, [2.0, 0.5], tight,
+                                   normalization=n, reg_mask=mask,
+                                   mesh=mesh, dim=D)
+            local = train_glm_sweep(LOGISTIC, data, [2.0, 0.5], tight,
+                                    normalization=n, reg_mask=mask)
+            for a, b in zip(_means(dist), _means(local)):
+                np.testing.assert_allclose(a, b, atol=1e-8)
+        assert training._sweep_solve_fn(LOGISTIC, tight, mesh, False).compiles == 1
+        assert training._sweep_solve_fn(LOGISTIC, tight, None, False).compiles == 1

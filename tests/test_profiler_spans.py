@@ -14,7 +14,9 @@ The contracts:
   waits for the device, the number appears when the record is read or
   flushed;
 - ``train_glm_sweep`` under a profiler yields ``glm.sweep`` > ``glm.solve``
-  (one a weight) > ``jit.compile`` (at the first solve's call).
+  (one a weight) > ``jit.compile`` (at the first solve's call, on the
+  process's first call with a signature: the fixtures here clear the held
+  solve, ``tests/test_glm_training.py`` holds the second call to none).
 """
 
 import glob
@@ -145,6 +147,14 @@ def _sweep(data):
         GLMOptimizationConfiguration(regularization=L2Regularization))
 
 
+@pytest.fixture(autouse=True)
+def no_solve_held():
+    """``train_glm_sweep`` keeps its compiled solve across calls; the tests
+    here read the span tree of a signature's FIRST call, ``jit.compile``
+    and all, whatever ran before them in the process."""
+    training._sweep_solve_fn.cache_clear()
+
+
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """One profiler session over the global tracer: a span with a device
@@ -153,6 +163,7 @@ def traced(tmp_path_factory):
     session, and the trace file."""
     trace_dir = str(tmp_path_factory.mktemp("xplane"))
     data = _glm_data()
+    training._sweep_solve_fn.cache_clear()  # set up before no_solve_held
     tracing.GLOBAL_TRACER._ring.clear()
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
