@@ -5,6 +5,13 @@ the program dispatches the path's solves back to back without a barrier of its
 own, so the call's end (a barrier on every result) is the only unit boundary
 the host can see. Set-up builds the data and the optimisation configuration
 once; the warm unit and every unit of the window go through :meth:`Cell.unit`.
+
+On one chip the design is ``(rows, dim)`` and the call is the plain one. On
+several the design is the stacked layout ``(chips, rows_per_chip, dim)``, block
+``i`` on chip ``i`` of a mesh with the one axis ``data``, and the call passes
+``mesh=`` and ``dim=``: the program's ``shard_map``/``psum`` objective. The
+plain reference follows the layout (``reference/glm.py``,
+``reference/glm_stacked.py``); everything below is the same for both.
 """
 
 from __future__ import annotations
@@ -17,6 +24,7 @@ import numpy as np
 
 from benchmark.families.common import Comparison, rel_gap
 from benchmark.reference import glm as reference
+from benchmark.reference import glm_stacked as reference_stacked
 from benchmark.reference.lbfgs import options as lbfgs_options
 from benchmark.work import glm as work
 
@@ -38,9 +46,15 @@ def _program():
     from photon_ml_tpu.ops.objective import GLMData
     from photon_ml_tpu.ops.regularization import L2Regularization
     from photon_ml_tpu.optimize import OptimizerConfig
+    from photon_ml_tpu.parallel.mesh import DATA_AXIS, make_mesh
     from photon_ml_tpu.types import OptimizerType, TaskType
 
     return locals()
+
+
+def _reference_for(x):
+    """The plain reference that reads ``x``'s layout."""
+    return reference_stacked if x.ndim == 3 else reference
 
 
 class Cell:
@@ -48,15 +62,25 @@ class Cell:
         p = _program()
         self._train = p["training"]
         self.config, self.workload = config, workload
-        if int(workload["chips"]) != 1:
-            raise ValueError("the glm family drives one chip")
+        if len(devices) != int(workload["chips"]):
+            raise ValueError(f"the cell drives {workload['chips']} chips, "
+                             f"not {len(devices)}")
         gen = importlib.import_module(f"benchmark.gen.{workload['generator']}")
-        arrays = gen.generate(seed, workload, config)
+        self.dim = int(config["dim"])
+        self.chips = len(devices)
+        if self.chips == 1:
+            self.mesh, self._mesh_args = None, {}
+            arrays = gen.generate(seed, workload, config)
+        else:
+            self.mesh = p["make_mesh"]({p["DATA_AXIS"]: self.chips},
+                                       devices=devices)
+            self._mesh_args = {"mesh": self.mesh, "dim": self.dim}
+            arrays = gen.generate(seed, workload, config, self.mesh)
         self.x, self.y = arrays["x"], arrays["y"]
         if config["design_dtype"] != "float32":
             self.x = self.x.astype(config["design_dtype"])
-        self.dim = int(config["dim"])
-        self.rows = int(self.y.shape[0])
+        self.rows = int(self.y.size)
+        # like the labels: on the mesh, block by block on the labels' chips
         self.data = p["GLMData"](
             design=p["DenseDesign"](x=self.x), labels=self.y,
             offsets=jnp.zeros_like(self.y), weights=jnp.ones_like(self.y))
@@ -77,7 +101,8 @@ class Cell:
     # --- the timed path ----------------------------------------------------
     def unit(self) -> None:
         trained = self._train.train_glm_sweep(
-            self.task, self.data, self.weights, self.opt_config)
+            self.task, self.data, self.weights, self.opt_config,
+            **self._mesh_args)
         self.last = [t.result for t in trained]
         jax.block_until_ready(self.last)
         self.iterations.append([int(r.iterations) for r in self.last])
@@ -93,14 +118,16 @@ class Cell:
     def required_work(self) -> dict:
         """Least device seconds for the window's solves, by pass counts."""
         flops, bytes_ = work.pass_work(
-            self.rows, self.dim, jnp.dtype(self.x.dtype).itemsize)
+            self.rows // self.chips, self.dim,
+            jnp.dtype(self.x.dtype).itemsize)
         passes = sum(work.solve_passes(i) for u in self.iterations for i in u)
         return {"flops_per_chip": flops * passes,
                 "bytes_per_chip": bytes_ * passes, "passes": passes}
 
     def describe(self) -> dict:
         """Which path the compiled solve holds, read from its text."""
-        problem = self._train.build_problem(self.task, self.opt_config)
+        problem = self._train.build_problem(self.task, self.opt_config,
+                                            mesh=self.mesh)
         w = jnp.zeros((self.dim,), jnp.float32)
         text = jax.jit(problem.run).lower(
             self.data, w, jnp.float32(1.0)).compile().as_text()
@@ -149,7 +176,8 @@ def solve_path(x, y, config: dict, workload: dict, *, round_to=None,
         if iterations is not None:
             opts = {**opts, "max_iterations": int(iterations[k])}
         r = reference.lbfgs(
-            reference.objective(x, y, lam, chunk=chunk, round_to=round_to),
+            _reference_for(x).objective(x, y, lam, chunk=chunk,
+                                        round_to=round_to),
             w, **opts)
         w = r["w"]
         outputs.append({"w": w, "value": r["values"][-1],
@@ -216,7 +244,7 @@ def compare(outputs: list[dict], x, y, config: dict, workload: dict,
         numbers["later_move_gap"] = max(move[1:])
     loss_gaps, kkt_gaps = [], []
     for out, lam in zip(outputs, weights):
-        f, g = reference.value_and_grad(
+        f, g = _reference_for(x).value_and_grad(
             x, y, jnp.asarray(out["w"], jnp.float32), jnp.float32(lam),
             chunk=chunk)
         loss_gaps.append(rel_gap(out["value"], float(f)))
@@ -232,22 +260,34 @@ def setup(seed: int, config: dict, workload: dict, devices) -> Cell:
     return Cell(seed, config, workload, devices)
 
 
-FAULTS = ("half_batch", "stall_after_3", "warm_start_returned")
+# --- what the selfcheck and the readings ask besides (families/common.py) ---
+def reference_outputs(cell: Cell) -> list[dict]:
+    return solve_path(cell.x, cell.y, cell.config, cell.workload)
+
+
+def compare_outputs(cell: Cell, outputs: list[dict],
+                    ref: list[dict] | None = None) -> list[Comparison]:
+    return compare(outputs, cell.x, cell.y, cell.config, cell.workload, ref)
+
+
+FAULTS = ("half_batch", "stall_after_3", "warm_start_returned", "no_exchange")
 
 
 def fault_outputs(kind: str, x, y, config: dict, workload: dict,
                   ref: list[dict]) -> list[dict]:
     """A fault planted in the reference put in the program's place, at full
     precision, every report consistent with where it stopped: ``half_batch``
-    trains on the first half of the rows; ``stall_after_3`` leaves every
-    solve's state unchanged after its third iteration;
+    trains on the first half of every chip's rows; ``stall_after_3`` leaves
+    every solve's state unchanged after its third iteration;
     ``warm_start_returned`` solves the first weight soundly (``ref``'s own
-    answer) and hands the later solves' warm start back unmoved."""
+    answer) and hands the later solves' warm start back unmoved;
+    ``no_exchange`` leaves the chips' losses and gradients un-summed: the
+    whole path solved and reported on one chip's rows as if they were all."""
     n = len(_weights(config))
     if kind == "half_batch":
         chunk = int(workload["row_chunk"])
-        half = max(y.shape[0] // 2 // chunk, 1) * chunk
-        return solve_path(x[:half], y[:half], config, workload)
+        half = max(y.shape[-1] // 2 // chunk, 1) * chunk
+        return solve_path(x[..., :half, :], y[..., :half], config, workload)
     if kind == "stall_after_3":
         return solve_path(x, y, config, workload, iterations=[STEPS] * n)
     if kind == "warm_start_returned":
@@ -255,7 +295,7 @@ def fault_outputs(kind: str, x, y, config: dict, workload: dict,
         w = ref[0]["w"]
         out = [ref[0]]
         for lam in _weights(config)[1:]:
-            f, g = reference.value_and_grad(
+            f, g = _reference_for(x).value_and_grad(
                 x, y, jnp.asarray(w, jnp.float32), jnp.float32(lam),
                 chunk=chunk)
             gn = float(jnp.linalg.norm(g))
@@ -263,15 +303,20 @@ def fault_outputs(kind: str, x, y, config: dict, workload: dict,
                         "values": np.asarray([float(f)]),
                         "grad_norms": np.asarray([gn])})
         return out
+    if kind == "no_exchange":
+        return solve_path(*reference_stacked.chip_blocks(x, y)[0], config,
+                          workload)
     raise ValueError(f"unknown fault {kind!r}")
 
 
 def stand_ins(cell: Cell, faults, ref: list[dict]):
     """``(name, outputs)`` of the lower-precision control (the reference, its
-    design rounded to bfloat16, in the program's place) and of each planted
-    fault, for the readings that the limits are set from."""
+    design rounded to bfloat16, in the program's place) and of each of
+    ``faults`` that the cell can have, for the readings that the limits are
+    set from: ``no_exchange`` only where chips exchange."""
     yield "control_bfloat16", solve_path(
         cell.x, cell.y, cell.config, cell.workload, round_to="bfloat16")
     for kind in faults:
-        yield f"fault_{kind}", fault_outputs(
-            kind, cell.x, cell.y, cell.config, cell.workload, ref)
+        if kind != "no_exchange" or cell.chips > 1:
+            yield f"fault_{kind}", fault_outputs(
+                kind, cell.x, cell.y, cell.config, cell.workload, ref)

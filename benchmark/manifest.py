@@ -7,6 +7,30 @@ metric is a file of its own: ``configs/<config>.json``,
 in ``families/``, ``work/`` and ``reference/``, the generator in ``gen/``. A
 new cell, configuration, metric or reader is new files and new entries;
 nothing that is there is edited.
+
+Adding a cell (all new files, then the entries in ``BENCHMARK.json``):
+
+- ``workloads/<cell>.json``: ``name``, ``config``, ``traffic``, ``chips`` and
+  ``why`` as the manifest's entry has them, the traffic's parameters as the
+  family's generator reads them, ``trace_seconds``, and ``limits``: one per
+  number the family compares, set from chip readings
+  (``selfcheck/readings.py``; PERF.md, section 4);
+- ``selfcheck/tiny/<cell>.json``: the keys of that file which a CPU test run
+  replaces (sizes, ``trace_seconds``, and ``limits`` at those sizes);
+- the cell's name in the ``workloads`` list of every per-layer metric whose
+  reader finds something to read in it;
+- for a new traffic shape of a family that is there, at most a generator
+  ``gen/<generator>.py`` (the workload file names it).
+
+Adding a family (a configuration whose ``family`` no module serves) is
+besides: ``configs/<config>.json``, ``families/<family>.py`` to the contract
+in ``families/common.py``'s docstring, its plain reference under
+``reference/`` (nothing of the program imported), its work model under
+``work/``, its generator under ``gen/``, metric and reader files for its
+layers, and tests of its own beside the selfcheck's. ``pytest
+benchmark/selfcheck -q`` then runs every test that is parametrised over cells
+on the new one, through the contract alone; no file that is there names a cell
+or a family's data.
 """
 
 from __future__ import annotations

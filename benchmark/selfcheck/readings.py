@@ -3,10 +3,11 @@
 --control-seeds 4 [--raw FILE] [--set key=value ...]``.
 
 For each seed: the data from the seed, one unit of the program through the
-cell's own call, the reference's own path, and every number of
-:func:`compare` (the lower readings). For the first ``--control-seeds`` seeds
-also the lower-precision control and each planted fault against the same
-reference path (the upper readings). One JSON line per reading on standard
+cell's own call, the reference's own outputs, and every number of the family's
+comparison (the lower readings). For the first ``--control-seeds`` seeds also
+the lower-precision control and each planted fault against the same reference
+outputs (the upper readings). Every call is one of the family's contract
+(``families/common.py``), so any family's cell can be read. One JSON line per reading on standard
 output; ``--raw`` keeps every path's answers and histories, one JSON line
 each, so that another number can be tried without the chip.
 """
@@ -67,16 +68,16 @@ def main(argv=None) -> int:
         t1 = time.perf_counter()
         outputs = cell.outputs()
         cell.release()
-        ref = family.solve_path(cell.x, cell.y, config, workload)
+        ref = family.reference_outputs(cell)
         t2 = time.perf_counter()
         say(seed, "reference", ref, [])
         say(seed, "program", outputs,
-            family.compare(outputs, cell.x, cell.y, config, workload, ref),
+            family.compare_outputs(cell, outputs, ref),
             setup_and_unit_s=t1 - t0, reference_path_s=t2 - t1)
         if i < args.control_seeds:
             for who, stood in family.stand_ins(cell, family.FAULTS, ref):
-                say(seed, who, stood, family.compare(
-                    stood, cell.x, cell.y, config, workload, ref))
+                say(seed, who, stood,
+                    family.compare_outputs(cell, stood, ref))
         del cell
         gc.collect()
     return 0

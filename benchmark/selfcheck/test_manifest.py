@@ -8,6 +8,7 @@ import re
 import pytest
 
 from benchmark import manifest
+from benchmark.selfcheck.conftest import ROOT, tiny_cell, tiny_file
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -86,6 +87,27 @@ def test_workloads():
         manifest.cell(w["name"])  # its files exist and agree
     four = sum(w["chips"] == 4 for w in BENCH["workloads"])
     assert four <= max(1, len(BENCH["workloads"]) // 4)
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_every_cell_has_its_selfcheck_sizes(name):
+    """``selfcheck/tiny/<cell>.json``: keys of the cell's workload file, at
+    sizes a test run can hold, with a limit for every number compared."""
+    assert os.path.exists(tiny_file(name)), os.path.relpath(
+        tiny_file(name), ROOT)
+    _, whole, _ = manifest.cell(name)
+    _, tiny, _ = tiny_cell(name)
+    with open(tiny_file(name)) as f:
+        assert set(json.load(f)) <= set(whole)
+    assert set(tiny["limits"]) == set(whole["limits"])
+
+
+def test_a_cell_without_its_selfcheck_sizes_fails_with_the_files_name():
+    entry = dict(BENCH["workloads"][0], name="a_config.a_cell_to_come")
+    with pytest.raises(FileNotFoundError) as e:
+        tiny_cell(entry["name"], whole=lambda name: (entry, {}, {}))
+    assert "benchmark/selfcheck/tiny/a_config.a_cell_to_come.json" \
+        in str(e.value)
 
 
 def _cells_of(metric):

@@ -148,9 +148,14 @@ def test_a_kernel_at_the_peak_reads_100_and_never_more(program, solves):
         == pytest.approx(50.0)
 
 
-def test_the_manifest_names_the_three_and_their_readers():
-    listed = {m["name"] for m in manifest.metrics_of(
-        "glm_dense_1024.lambda_path", "per_layer")}
+#: the GLM family's cells, by their configuration's ``family``
+GLM_CELLS = sorted(w["name"] for w in manifest.benchmark()["workloads"]
+                   if manifest.cell(w["name"])[2]["family"] == "glm")
+
+
+@pytest.mark.parametrize("cell", GLM_CELLS)
+def test_the_manifest_names_the_three_and_their_readers(cell):
+    listed = {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
     for name, reader in (("lbfgs_evals_per_iter", evals_per_iter),
                          ("glm_kernel_roofline_pct", kernel_roofline),
                          ("retrace_s_per_unit", span_seconds_per)):
@@ -159,30 +164,47 @@ def test_the_manifest_names_the_three_and_their_readers():
     assert manifest.metric_file("glm_kernel_roofline_pct")["params"] == KERNEL
 
 
-def test_a_traced_run_carries_the_three(tiny_cells, monkeypatch, capsys):
+@pytest.mark.parametrize("cell", GLM_CELLS)
+def test_a_traced_run_carries_the_three(tiny_cells, monkeypatch, capsys, cell):
     """A whole ``--trace 1`` run here, where the profiler runs but no chip is
-    in its trace: with the reduction put in by hand, the result line holds the
-    three metrics, each from what the program itself recorded in the window
-    (and from nothing before it: the warm unit's solves are not counted)."""
+    in its trace: with the reduction put in by hand, the result line holds
+    every per-layer metric the manifest lists for the cell, the three each
+    from what the program itself recorded in the window (and from nothing
+    before it: the warm unit's solves are not counted, and its compile is
+    not the window's: the compiled solve outlives the call)."""
     import json
+
+    import jax
 
     from benchmark import run, trace
 
+    from photon_ml_tpu.telemetry import tracing
+
+    chips = manifest.cell(cell)[0]["chips"]
+    if len(jax.devices()) < chips:
+        pytest.skip(f"needs {chips} (virtual) devices")
+    # a run is a process of its own, and the ring its window's: here one
+    # process makes a traced run per cell
+    tracing.GLOBAL_TRACER._ring.clear()
     kernel_s = 1e-9  # far under any least time: what is read is the count
     monkeypatch.setattr(trace, "reduce", lambda path, chips: {
         "window_s": 1.0, "busy_s": 0.5, "device_ops": [], "idle_gaps": [],
         "per_chip": [{"busy_s": 0.5, "modules_s": {"jit_run": 0.5},
                       "ops_self_s": {"fused_value_and_grad": kernel_s},
-                      "collective_s": 0.0}]})
+                      "collective_s": 0.001}] * chips})
     monkeypatch.setattr(manifest, "peaks", lambda kind: PEAKS)
-    code = run.main(["--workload", "glm_dense_1024.lambda_path", "--seed",
-                     "11", "--seconds", "0.5", "--trace", "1"],
-                    require_tpu=False)
+    code = run.main(["--workload", cell, "--seed", "11", "--seconds", "0.5",
+                     "--trace", "1"], require_tpu=False)
     lines = capsys.readouterr().out.strip().splitlines()
     assert code == 0
     got = json.loads(lines[-1])["metrics"]
+    listed = {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
+    # the CPU keeps no count of its memory's peak: that one reads nothing
+    assert listed - {"hbm_peak_gib"} <= set(got) <= listed
     assert got["lbfgs_evals_per_iter"]["value"] >= 1.0
-    assert got["retrace_s_per_unit"]["value"] > 0
+    # the guard that PR 25's gain stays: no re-trace, no compile in a window
+    assert got["retrace_s_per_unit"]["value"] == 0.0
+    assert got["compiles_in_window"]["value"] == 0
     # the scale is evaluations over passes, so the two shares of one run
     # stand as evaluations to passes too
     work = next(json.loads(l.split(": ", 1)[1])["work"] for l in lines

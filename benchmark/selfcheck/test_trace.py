@@ -36,7 +36,24 @@ def _recorded(name):
     return path
 
 
-@pytest.mark.parametrize("stem", ["glm_one_chip"])
+def test_collective_share_by_hand():
+    """Two chips: 0.2 of 4 s and 0.1 of 1 s busy are collectives: 5% and
+    10%, 7.5% in the mean; a chip that was never busy has no share."""
+    from benchmark.readers import collective_share
+
+    chip = lambda busy, coll: {"busy_s": busy, "collective_s": coll}
+    run = {"trace": {"per_chip": [chip(4.0, 0.2), chip(1.0, 0.1)]}}
+    assert collective_share.read(run, {}) == pytest.approx(7.5)
+    run["trace"]["per_chip"].append(chip(0.0, 0.0))
+    assert collective_share.read(run, {}) == pytest.approx(7.5)
+    assert collective_share.read(
+        {"trace": {"per_chip": [chip(2.0, 0.0)]}}, {}) == 0.0
+    assert collective_share.read({"trace": {"per_chip": []}}, {}) is None
+    assert collective_share.read(
+        {"trace": {"per_chip": [chip(0.0, 0.0)]}}, {}) is None
+
+
+@pytest.mark.parametrize("stem", ["glm_one_chip", "glm_four_chip"])
 def test_recorded_trace_reduces_to_the_recorded_numbers(stem):
     """No later PR can move the reduction unseen: the numbers below were read
     from this trace when it was recorded."""
@@ -44,6 +61,12 @@ def test_recorded_trace_reduces_to_the_recorded_numbers(stem):
     with open(_recorded(stem + ".expected.json")) as f:
         want = json.load(f)
     got = trace.reduce(path, want["chips"])
+    assert len(got["per_chip"]) == want["chips"] == len(want["per_chip"])
+    if want["chips"] > 1:
+        # every chip ran the program, and its psum is in its trace
+        assert all(c["collective_s"] > 0 for c in got["per_chip"])
+        assert all(0 < c["busy_s"] < got["window_s"]
+                   for c in got["per_chip"])
     assert got["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
     assert got["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
     assert 0 < got["busy_s"] < got["window_s"]
