@@ -165,10 +165,20 @@ class FixedEffectCoordinate:
                                             self.dataset.mesh)
         else:
             train_fn = _fixed_train_fn(self.task, self.config)
-        result, variances, scores = train_fn(
-            data, w0, jnp.asarray(self.lam, jnp.float32))
         from photon_ml_tpu.telemetry import tracing
 
+        # the counts ride the span as device values (resolved when the
+        # record is read or written): the step dispatches without a wait
+        with tracing.span("glm.solve", coordinate=self.coordinate_id,
+                          regularization_weight=float(self.lam),
+                          rows=self.dataset.n_samples,
+                          dim=self.dataset.dim) as solve:
+            result, variances, scores = train_fn(
+                data, w0, jnp.asarray(self.lam, jnp.float32))
+            solve.set(iterations=result.iterations,
+                      evaluations=result.evaluations,
+                      converged=result.converged)
+        tracing.set_on_enclosing("cd.step", evaluations=result.evaluations)
         if tracing.enabled():
             # the reference's OptimizationStatesTracker table, folded into
             # trace.jsonl + the metrics registry. Gated: reading the trace

@@ -288,8 +288,8 @@ class CoordinateDescent:
                         # its new regularization may well not diverge.
                         continue
                     heartbeat("cd.step")
-                    with tracing.span("cd.step", coordinate=cid,
-                                      sweep=sweep) as step_span, \
+                    with tracing.span("cd.step", coordinate=cid, sweep=sweep,
+                                      rows=data.n_samples) as step_span, \
                             _STEP_DISPATCH.labels(
                                 coordinate=cid).time() as dispatch_timer:
                         while True:

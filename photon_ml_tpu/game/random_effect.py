@@ -42,7 +42,7 @@ from photon_ml_tpu.ops.design import DenseDesign
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.objective import GLMData, GLMObjective
 from photon_ml_tpu.parallel.mesh import ENTITY_AXIS, replicated
-from photon_ml_tpu.telemetry import profiling
+from photon_ml_tpu.telemetry import profiling, tracing
 from photon_ml_tpu.types import TaskType, VarianceComputationType
 
 
@@ -57,6 +57,10 @@ from photon_ml_tpu.types import TaskType, VarianceComputationType
 # themselves would retain solvers/meshes forever in a long sweep process; a
 # hash collision merely skips one warm-up (jit compiles at first real call).
 _PRECOMPILED: set[int] = set()
+
+#: the span a bucket's solve records (one per bucket per coordinate step),
+#: by which the benchmark's readers find the per-lane counts
+SOLVE_SPAN = "game.re.solve"
 
 
 def _bucket_keys(bucket: REBucket, shard_dim: int) -> np.ndarray:
@@ -294,6 +298,30 @@ class RandomEffectSolver:
                           pad_value=-1))
             dataset._device_cache[key] = cached
         return cached
+
+    def _record_solve(self, dataset: RandomEffectDataset, i: int,
+                      bucket: REBucket, counts: dict) -> None:
+        """One ``game.re.solve`` span for bucket ``i``'s solve. ``counts``
+        (:func:`_solve_bucket_impl`'s) are attached as device values, which
+        ``telemetry/tracing.py`` resolves when the record is read or
+        written: nothing here waits for the device, and where no sink or
+        profiler listens nothing is kept. What the host knows of the bucket
+        (its real rows, whether the entity kernel serves its lanes) is
+        worked out once per dataset."""
+        key = ("solve_span", i, self.design_dtype)
+        static = dataset._device_cache.get(key)
+        if static is None:
+            _, s, d = bucket.tensor_shape
+            lane = DenseDesign(x=jax.ShapeDtypeStruct((s, d), self._x_dtype))
+            static = {
+                "rows": int(np.count_nonzero(bucket.sample_idx >= 0)),
+                "s_max": s, "dim": d,
+                "kernel": "pallas" if self._problem().objective
+                ._entity_kernel_serves(lane, s, d) else "closed_form"}
+            dataset._device_cache[key] = static
+        with tracing.span(SOLVE_SPAN, coordinate=dataset.coordinate_id,
+                          bucket=i, **static) as span:
+            span.set(**counts)
 
     @partial(jax.jit, static_argnames=("self",))
     def _margins_bucket(self, x, w):
@@ -576,6 +604,7 @@ class RandomEffectSolver:
         streaming = not cfg.cache_device_buckets
         lam_dev = jnp.asarray(lam, jnp.float32)
         pending = []
+        bucket_evaluations = []  # for the enclosing cd.step
         dev_coeff_parts: list[jnp.ndarray] = []
         fused = (not streaming and dataset.projector is None
                  and len(dataset.buckets) > 0)
@@ -629,9 +658,13 @@ class RandomEffectSolver:
             off_sharding = getattr(offsets_dev, "sharding", None)
             out_sharding = (off_sharding if isinstance(off_sharding, _NS)
                             and tuple(off_sharding.spec) else None)
-            scores, batched_dev, coeffs_unsorted = self._sweep_fused(
-                offsets_dev, lam_dev, statics, warm_ctxs, coeffs_warm,
-                cidxs, e_reals, out_sharding=out_sharding)
+            scores, batched_dev, coeffs_unsorted, counts, evaluations = \
+                self._sweep_fused(
+                    offsets_dev, lam_dev, statics, warm_ctxs, coeffs_warm,
+                    cidxs, e_reals, out_sharding=out_sharding)
+            for i, (bucket, counts_k) in enumerate(zip(buckets, counts)):
+                self._record_solve(dataset, i, bucket, counts_k)
+            tracing.set_on_enclosing("cd.step", evaluations=evaluations)
             d_of = [b.tensor_shape[2] for b in buckets]
             w_sizes = [b.n_entities * d for b, d in zip(buckets, d_of)]
             v_sizes = [b.n_entities * (d if want_var else 0)
@@ -706,8 +739,10 @@ class RandomEffectSolver:
             if w0_d is None:
                 w0_d = self._put(
                     _gather_warm_start(bucket, warm_start, shard_dim))
-            w_dev, variances, _conv = self._solve_bucket(
+            w_dev, variances, _conv, counts_k = self._solve_bucket(
                 x_d, lab_d, boff, wt_d, w0_d, lam_dev)
+            self._record_solve(dataset, i, bucket, counts_k)
+            bucket_evaluations.append(counts_k["evaluations"])
             # margins from the already-placed design (x is the dominant
             # payload; avoid a second host→device copy of it), scattered
             # into the device score vector — dead rows carry index n, which
@@ -736,6 +771,9 @@ class RandomEffectSolver:
             else:
                 pending.append((bucket, e_real, w_dev, variances))
 
+        if bucket_evaluations:
+            tracing.set_on_enclosing("cd.step",
+                                     evaluations=sum(bucket_evaluations))
         # Phase 2 — collect (cached-bucket mode): every pending bucket's
         # coefficient (and variance) table rides ONE concatenated
         # device→host transfer, split on host — per-bucket D2H syncs
@@ -787,7 +825,12 @@ class RandomEffectSolver:
 
 def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     """Batched bucket solve body (the traced program behind
-    :meth:`RandomEffectSolver._solve_bucket`)."""
+    :meth:`RandomEffectSolver._solve_bucket`): ``(w, variances, converged,
+    counts)``, the first three per lane, ``counts`` the bucket's device
+    scalars for its ``game.re.solve`` span (lanes that weigh something, the
+    sums of their iterations and evaluations, plain and weighted by each
+    lane's real rows, how many converged, and the most evaluations any lane
+    made)."""
     problem = solver._problem()
     objective = problem.objective
 
@@ -798,7 +841,8 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
         variances = problem.compute_variances(result.w, data, lam_)
         if variances is None:
             variances = jnp.zeros((0,), xe.dtype)
-        return result.w, variances, result.converged
+        return (result.w, variances, result.converged, result.iterations,
+                result.evaluations)
 
     def batch(x, labels, offsets, weights, w0, lam):
         # Pre-pad the entity batch to the Pallas kernel's block plan with
@@ -818,16 +862,30 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
             offsets = jnp.pad(offsets, ((0, pad), (0, 0)))
             weights = jnp.pad(weights, ((0, pad), (0, 0)))
             w0 = jnp.pad(w0, ((0, pad), (0, 0)))
-        w_out, variances, conv = jax.vmap(
-            solve_one, in_axes=(0, 0, 0, 0, 0, None))(
-                x, labels, offsets, weights, w0, lam)
-        if pad:
-            w_out, variances, conv = (w_out[:e_real], variances[:e_real],
-                                      conv[:e_real])
-        return w_out, variances, conv
+        per_lane = jax.vmap(solve_one, in_axes=(0, 0, 0, 0, 0, None))(
+            x, labels, offsets, weights, w0, lam)
+        return tuple(a[:e_real] for a in per_lane) if pad else per_lane
+
+    def counted(w_out, variances, conv, iterations, evaluations):
+        # a lane that weighs nothing (the mesh's pad lanes) solved nothing
+        rows = jnp.sum(weights > 0, axis=1)
+        real = rows > 0
+        total = lambda a: jnp.sum(jnp.where(real, a, 0).astype(jnp.int32))
+        # float32: rows x evaluations summed over a bucket can pass 2**31
+        by_rows = lambda a: jnp.sum(rows.astype(jnp.float32) * a)
+        return w_out, variances, conv, {
+            "lanes": total(real), "iterations": total(iterations),
+            "evaluations": total(evaluations),
+            # the passes the bucket's program had to run at the least
+            "max_lane_evaluations": jnp.max(evaluations),
+            "converged": total(conv),
+            # each lane's count weighted by its real rows: the bucket's
+            # required passes and its evaluations in rows read
+            "row_iterations": by_rows(iterations),
+            "row_evaluations": by_rows(evaluations)}
 
     if solver.mesh is None:
-        return batch(x, labels, offsets, weights, w0, lam)
+        return counted(*batch(x, labels, offsets, weights, w0, lam))
     # Entity-parallel: each device solves its contiguous slice of lanes.
     # No collectives in the body — independence is the whole point. The
     # lane specs mention EVERY mesh axis (solver._lane_axes): with
@@ -838,11 +896,11 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     # check_vma off: the body is collective-free by construction, and the
     # optimizers' constant-initialized while_loop carries would otherwise
     # trip the varying-axis check against lane-varying outputs.
-    return shard_map(
+    return counted(*shard_map(
         batch, mesh=solver.mesh,
         in_specs=(s, s, s, s, s, P()),
-        out_specs=(s, s, s), check_vma=False,
-    )(x, labels, offsets, weights, w0, lam)
+        out_specs=(s, s, s, s, s), check_vma=False,
+    )(x, labels, offsets, weights, w0, lam))
 
 
 def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
@@ -853,6 +911,7 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
     flat_w: list[jnp.ndarray] = []
     flat_v: list[jnp.ndarray] = []
     coef_parts: list[jnp.ndarray] = []
+    counts: list[dict] = []
     for statics_k, (pos_d, found_d), cidx, \
             e_real in zip(statics, warm_ctxs, cidxs, e_reals):
         x_d, lab_d, wt_d, idx_d, store_d = statics_k
@@ -863,8 +922,9 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
             jnp.take(coeffs_warm, pos_d.reshape(-1),
                      mode="clip").reshape(pos_d.shape),
             0.0).astype(jnp.float32)
-        w_dev, variances, _conv = solver._solve_bucket(
+        w_dev, variances, _conv, counts_k = solver._solve_bucket(
             x_d, lab_d, boff, wt_d, w0, lam)
+        counts.append(counts_k)
         margins = solver._margins_bucket(x_d, w_dev)[:e_real]
         scores = scores.at[store_d].set(margins, mode="drop")
         flat_w.append(w_dev[:e_real].reshape(-1))
@@ -878,7 +938,9 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
         # (tests/test_sharded_scores.py — ROADMAP item 5 prototype)
         scores = jax.lax.with_sharding_constraint(scores, out_sharding)
     batched = jnp.concatenate(flat_w + flat_v)
-    return scores, batched, jnp.concatenate(coef_parts)
+    evaluations = sum(c["evaluations"] for c in counts)
+    return (scores, batched, jnp.concatenate(coef_parts), tuple(counts),
+            evaluations)
 
 
 #: the profiled executables behind the solver methods: module-level so the

@@ -106,7 +106,8 @@ def record_trace(values: Array, gnorms: Array, it: Array, f: Array, gnorm: Array
         gnorm.astype(jnp.float32))
 
 
-def armijo_backtracking(trial, sufficient, alpha0: Array, max_steps: int):
+def armijo_backtracking(trial, sufficient, alpha0: Array, max_steps: int,
+                        active: Array):
     """Generic halving backtracking search shared by L-BFGS and OWL-QN.
 
     ``trial(alpha) -> (w_t, f_t, g_t)`` evaluates a candidate step (OWL-QN's
@@ -115,12 +116,19 @@ def armijo_backtracking(trial, sufficient, alpha0: Array, max_steps: int):
     return False (e.g. ``f_t <= bound``), which makes overflowing trial steps
     shrink instead of exiting the loop.
 
+    ``active`` is the caller's own loop condition for this solve. Under
+    ``vmap`` the loop below runs while ANY lane's condition holds, and a lane
+    whose solve has ended (gradient ~ 0, so ``f_t <= f + c1*alpha*g.d`` fails
+    by rounding) would hold every other lane of the batch for ``max_steps``
+    halvings in every outer iteration: an inactive lane makes the one trial
+    at ``alpha0`` that the loop's shape requires and asks for no more.
+
     Returns ``(alpha, w_t, f_t, g_t, ok, trials)``; ``trials`` (int32) is the
     number of calls of ``trial`` made, the first at ``alpha0`` included.
     """
     def cond(st):
         alpha, w_t, f_t, _, ls = st
-        return (~sufficient(alpha, w_t, f_t)) & (ls < max_steps)
+        return active & (~sufficient(alpha, w_t, f_t)) & (ls < max_steps)
 
     def body(st):
         alpha = st[0] * 0.5

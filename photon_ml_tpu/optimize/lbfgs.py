@@ -103,8 +103,10 @@ def two_loop_direction(g: Array, s_hist: Array, y_hist: Array, rho: Array,
 
 
 def backtracking_line_search(fun: ValueAndGrad, w: Array, f: Array, g: Array,
-                             d: Array, alpha0: Array, max_steps: int):
-    """Armijo backtracking: shrink alpha until sufficient decrease.
+                             d: Array, alpha0: Array, max_steps: int,
+                             active: Array):
+    """Armijo backtracking: shrink alpha until sufficient decrease, for a
+    solve that is still ``active`` (see :func:`armijo_backtracking`).
 
     Returns ``(alpha, f_new, g_new, w_new, ok, trials)``, ``trials`` the
     number of calls of ``fun``. On total failure returns the
@@ -123,7 +125,7 @@ def backtracking_line_search(fun: ValueAndGrad, w: Array, f: Array, g: Array,
         return f_t <= f + _ARMIJO_C1 * alpha * gd
 
     alpha, w_new, f_new, g_new, ok, trials = armijo_backtracking(
-        trial, sufficient, alpha0, max_steps)
+        trial, sufficient, alpha0, max_steps, active)
     return alpha, f_new, g_new, w_new, ok, trials
 
 
@@ -154,6 +156,12 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
         return (~s.converged) & (~s.failed) & (s.it < config.max_iterations)
 
     def body(s: _State):
+        # Unbatched this is always true. Under vmap the batched while_loop
+        # runs body for every lane while any lane's cond holds and keeps the
+        # carry of a lane whose cond is false as it was, so a finished lane's
+        # state, iterations and evaluations stand; what the lanes SHARE is
+        # the line search's trip count, and a finished lane adds nothing to it.
+        active = cond(s)
         d_dir = two_loop_direction(s.g, s.s_hist, s.y_hist, s.rho, s.n_pairs, m)
         # Safeguard: fall back to steepest descent on a non-descent direction.
         descent = jnp.vdot(s.g, d_dir) < 0
@@ -162,7 +170,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
         alpha0 = jnp.where(s.n_pairs > 0, 1.0,
                            1.0 / jnp.maximum(jnp.linalg.norm(d_dir), 1.0))
         alpha, f_new, g_new, w_new, ok, trials = backtracking_line_search(
-            fun, s.w, s.f, s.g, d_dir, alpha0, config.max_line_search)
+            fun, s.w, s.f, s.g, d_dir, alpha0, config.max_line_search, active)
 
         s_hist, y_hist, rho, n_pairs = update_history(
             s.s_hist, s.y_hist, s.rho, s.n_pairs, w_new - s.w, g_new - s.g, ok,

@@ -88,6 +88,7 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
         return (~s.converged) & (~s.failed) & (s.it < config.max_iterations)
 
     def body(s):
+        active = cond(s)  # a finished lane leaves the line search: see lbfgs
         d_dir = two_loop_direction(s.pg, s.s_hist, s.y_hist, s.rho, s.n_pairs, m)
         # Align with the pseudo-gradient descent orthant (A&G constraint):
         # keep components where d and -pg agree in sign.
@@ -113,7 +114,7 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
             return f_t <= s.f + _ARMIJO_C1 * jnp.vdot(s.pg, w_t - s.w)
 
         alpha, w_new, f_new, g_new, ok, trials = armijo_backtracking(
-            trial, sufficient, alpha0, config.max_line_search)
+            trial, sufficient, alpha0, config.max_line_search, active)
 
         # Curvature pairs from smooth-gradient differences (A&G).
         s_hist, y_hist, rho, n_pairs = update_history(

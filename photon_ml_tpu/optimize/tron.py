@@ -40,7 +40,7 @@ _SIGMA1, _SIGMA2, _SIGMA3 = 0.25, 0.5, 4.0
 _CG_TOL = 0.1  # inner CG stops at ||r|| <= 0.1 * ||g||
 
 
-def _trcg(hvp, g: Array, delta: Array, max_cg: int):
+def _trcg(hvp, g: Array, delta: Array, max_cg: int, active: Array):
     """Steihaug truncated CG: approximately solve H s = -g within ||s||<=delta.
 
     Returns ``(s, at_boundary, prered)`` where ``prered = -(g.s + 0.5 s.Hs)``
@@ -49,7 +49,10 @@ def _trcg(hvp, g: Array, delta: Array, max_cg: int):
     0.5*tau^2*p.Hp, using the invariant r.p = r.r) so the outer loop never
     pays an extra Hessian-vector product — on a sharded mesh that is one
     avoided collective per Newton iteration. Fixed iteration cap with
-    tolerance masking keeps the loop shape static for XLA.
+    tolerance masking keeps the loop shape static for XLA. ``active`` is the
+    outer loop's condition for this solve: under ``vmap`` the CG loop runs
+    while any lane's is unfinished, and a lane whose solve has ended starts
+    it done (its step is discarded with the rest of its iteration).
     """
     cg_tol = _CG_TOL * jnp.linalg.norm(g)
 
@@ -90,7 +93,7 @@ def _trcg(hvp, g: Array, delta: Array, max_cg: int):
     s0 = jnp.zeros_like(g)
     r0 = -g
     init = (s0, r0, r0, jnp.vdot(r0, r0), jnp.zeros_like(jnp.vdot(r0, r0)),
-            jnp.int32(0), jnp.linalg.norm(r0) <= cg_tol)
+            jnp.int32(0), (~active) | (jnp.linalg.norm(r0) <= cg_tol))
     s, r, p, rr, q, i, done = lax.while_loop(cond, body, init)
     at_boundary = jnp.linalg.norm(s) >= delta * (1.0 - 1e-6)
     return s, at_boundary, -q
@@ -127,7 +130,7 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
     def body(s):
         op = hvp_at(s.w) if hvp_at is not None else (lambda v: hvp(s.w, v))
         step, at_boundary, prered = _trcg(op, s.g, s.delta,
-                                          config.cg_max_iterations)
+                                          config.cg_max_iterations, cond(s))
         snorm = jnp.linalg.norm(step)
         w_new = s.w + step
         f_new, g_new = fun(w_new)

@@ -82,6 +82,12 @@ _CURRENT: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
 _STACK: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "photon_span_stack", default=())
 
+#: the open Span OBJECTS on THIS thread/context, outermost first — what lets
+#: a callee hand a value to the span of the caller that asked for the work
+#: (:func:`set_on_enclosing`)
+_SPANS: contextvars.ContextVar[tuple] = contextvars.ContextVar(
+    "photon_open_spans", default=())
+
 #: reserved record keys — span attributes may not shadow them
 _RESERVED = frozenset(
     {"name", "span_id", "parent_id", "ts", "t0", "t1", "seconds",
@@ -298,6 +304,7 @@ class Tracer:
         parent that closed before this span did is re-found."""
         token = _CURRENT.set(sp.span_id)
         stack_token = _STACK.set(ancestors + (sp.span_id,))
+        spans_token = _SPANS.set(_SPANS.get() + (sp,))
         with self._lock:
             self._open.add(sp.span_id)
         # on the profiler's clock or not: decided once, at entry, so that a
@@ -331,6 +338,7 @@ class Tracer:
                 annotation.__exit__(None, None, None)
             _CURRENT.reset(token)
             _STACK.reset(stack_token)
+            _SPANS.reset(spans_token)
             with self._lock:
                 if (sp.parent_id is not None
                         and sp.parent_id not in self._open):
@@ -422,6 +430,18 @@ def span(name: str, **attrs):
 
 def annotate(name: str, **payload) -> None:
     GLOBAL_TRACER.annotate(name, **payload)
+
+
+def set_on_enclosing(name: str, **attrs) -> None:
+    """Attach ``attrs`` to the nearest open span named ``name`` around the
+    caller on this thread/context; nothing where there is none. How a solve
+    hands its counts to the ``cd.step`` that asked for it without the step's
+    callees returning them through every signature between. Values may be
+    device scalars (held by reference, as :meth:`Span.set`'s are)."""
+    for sp in reversed(_SPANS.get()):
+        if sp.name == name:
+            sp.set(**attrs)
+            return
 
 
 def current_span_id() -> Optional[int]:
