@@ -5,11 +5,19 @@ TPU-native replacement for the reference's per-entity training
 ``optimization/game/{RandomEffectOptimizationProblem,
 SingleNodeOptimizationProblem}.scala``): where the reference zips an RDD of
 per-entity breeze problems with per-entity local datasets and runs millions of
-scalar-loop solves inside executors, here every size bucket is ONE
-``vmap``-batched compiled solve — entities are lanes of a batched L-BFGS /
-OWLQN / TRON ``lax.while_loop`` (convergence is per-lane masked inside the
-optimizers; a converged lane simply stops changing). One compilation serves
-every bucket of the same (samples, features) shape across all CD sweeps.
+scalar-loop solves inside executors, here every size bucket is ONE batched
+compiled solve whose lanes are the bucket's entities. An L-BFGS bucket (every
+cell's) runs ``optimize/lbfgs.py::minimize_lbfgs_lanes``: one ``while_loop``
+over the batched state, one evaluation a lane a trip, every lane at its own
+place in its own solve, so the bucket runs what its slowest lane evaluates
+(``passes`` on the ``game.re.solve`` span). OWL-QN and TRON buckets are still
+the single solve's nested loops under ``vmap`` (``minimize_owlqn``,
+``minimize_tron``: convergence is per-lane masked inside them, a finished
+lane stops changing), which share the inner loop's trips among the running
+lanes; the fixed effect's single solve keeps the nested ``minimize_lbfgs``,
+which works a direction out once an iteration (``lbfgs.py`` says why the
+loops are two). One compilation serves every bucket of the same
+(samples, features) shape across all CD sweeps.
 
 Padding correctness: padded sample rows carry weight 0 (contribute nothing);
 padded feature columns are all-zero in x, so with zero init their gradient
@@ -834,16 +842,6 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     problem = solver._problem()
     objective = problem.objective
 
-    def solve_one(xe, ye, oe, we, w0e, lam_):
-        data = GLMData(design=DenseDesign(x=xe), labels=ye,
-                       offsets=oe, weights=we)
-        result = problem.run(data, w0e, lam_)
-        variances = problem.compute_variances(result.w, data, lam_)
-        if variances is None:
-            variances = jnp.zeros((0,), xe.dtype)
-        return (result.w, variances, result.converged, result.iterations,
-                result.evaluations)
-
     def batch(x, labels, offsets, weights, w0, lam):
         # Pre-pad the entity batch to the Pallas kernel's block plan with
         # weight-0 lanes (zero data ⇒ gradient = L2 at w0=0 = 0: they
@@ -862,27 +860,47 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
             offsets = jnp.pad(offsets, ((0, pad), (0, 0)))
             weights = jnp.pad(weights, ((0, pad), (0, 0)))
             w0 = jnp.pad(w0, ((0, pad), (0, 0)))
-        per_lane = jax.vmap(solve_one, in_axes=(0, 0, 0, 0, 0, None))(
-            x, labels, offsets, weights, w0, lam)
-        return tuple(a[:e_real] for a in per_lane) if pad else per_lane
+        data = GLMData(design=DenseDesign(x=x), labels=labels,
+                       offsets=offsets, weights=weights)
+        # the one place that knows its solves are a batch: an L-BFGS
+        # bucket runs one evaluation a lane a trip (optimize/lbfgs.py)
+        result, passes = problem.run_lanes(data, w0, lam)
+        variances = jax.vmap(
+            lambda w, lane: problem.compute_variances(w, lane, lam))(
+                result.w, data)
+        if variances is None:
+            variances = jnp.zeros((x.shape[0], 0), x.dtype)
+        per_lane = (result.w, variances, result.converged,
+                    result.iterations, result.evaluations)
+        if pad:
+            per_lane = tuple(a[:e_real] for a in per_lane)
+        # one count a shard; none where the solve's loops count no passes
+        passes = (jnp.zeros((0,), jnp.int32) if passes is None
+                  else passes[None])
+        return (*per_lane, passes)
 
-    def counted(w_out, variances, conv, iterations, evaluations):
+    def counted(w_out, variances, conv, iterations, evaluations, passes):
         # a lane that weighs nothing (the mesh's pad lanes) solved nothing
         rows = jnp.sum(weights > 0, axis=1)
         real = rows > 0
         total = lambda a: jnp.sum(jnp.where(real, a, 0).astype(jnp.int32))
         # float32: rows x evaluations summed over a bucket can pass 2**31
         by_rows = lambda a: jnp.sum(rows.astype(jnp.float32) * a)
-        return w_out, variances, conv, {
+        counts = {
             "lanes": total(real), "iterations": total(iterations),
             "evaluations": total(evaluations),
-            # the passes the bucket's program had to run at the least
+            # the passes the bucket's program has to run at the least
             "max_lane_evaluations": jnp.max(evaluations),
             "converged": total(conv),
             # each lane's count weighted by its real rows: the bucket's
             # required passes and its evaluations in rows read
             "row_iterations": by_rows(iterations),
             "row_evaluations": by_rows(evaluations)}
+        if passes.size:
+            # the passes it did run, counted by the loop itself (under a
+            # mesh the shards run side by side: the longest shard's)
+            counts["passes"] = jnp.max(passes)
+        return w_out, variances, conv, counts
 
     if solver.mesh is None:
         return counted(*batch(x, labels, offsets, weights, w0, lam))
@@ -899,7 +917,7 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     return counted(*shard_map(
         batch, mesh=solver.mesh,
         in_specs=(s, s, s, s, s, P()),
-        out_specs=(s, s, s, s, s), check_vma=False,
+        out_specs=(s, s, s, s, s, s), check_vma=False,
     )(x, labels, offsets, weights, w0, lam))
 
 

@@ -38,6 +38,7 @@ from photon_ml_tpu.optimize import (
     minimize_owlqn,
     minimize_tron,
 )
+from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs_lanes
 from photon_ml_tpu.types import OptimizerType, VarianceComputationType
 
 Array = jax.Array
@@ -100,6 +101,29 @@ class OptimizationProblem:
         if self.config.regularization.has_l1:
             return minimize_owlqn(fun, w0, l1, cfg)
         return minimize_lbfgs(fun, w0, cfg)
+
+    def run_lanes(self, data: GLMData, w0: Array, lam=0.0
+                  ) -> tuple[OptimizerResult, Optional[Array]]:
+        """:meth:`run` for every lane of a batch: ``data``'s leaves and
+        ``w0`` lead with the lane axis, and so do the result's fields; a
+        lane's result is what :meth:`run` gives it (an L-BFGS lane's up to
+        the order in which a dot product over ``d`` is summed).
+
+        A caller that knows its solves are a batch (a random-effect bucket)
+        calls this, not ``vmap(run)``: an L-BFGS batch then runs the flat
+        loop (:func:`minimize_lbfgs_lanes`: one evaluation a lane a trip)
+        and the second value is its ``passes``, the batched evaluations it
+        made. OWL-QN and TRON batches are ``vmap(run)``, whose nested loops
+        count no passes: ``None``.
+        """
+        if (self.config.optimizer == OptimizerType.TRON
+                or self.config.regularization.has_l1):
+            return jax.vmap(self.run, in_axes=(0, 0, None))(
+                data, w0, lam), None
+        _, l2 = self._split(lam)
+        fun = lambda lane, w: self.objective.value_and_grad(w, lane, l2)
+        return minimize_lbfgs_lanes(fun, data, w0,
+                                    self.config.optimizer_config)
 
     # --- variance (reference VarianceComputationType SIMPLE / FULL) -------
     def compute_variances(self, w: Array, data: GLMData, lam=0.0) -> Optional[Array]:

@@ -222,6 +222,8 @@ def test_a_traced_fit_records_one_solve_span_a_bucket(problem, records):
             assert 0 <= s["converged"] <= s["lanes"]
             assert s["lanes"] <= s["evaluations"] \
                 <= s["lanes"] * s["max_lane_evaluations"]
+            # the bucket ran what its slowest lane evaluated, and no more
+            assert s["passes"] == s["max_lane_evaluations"]
             assert s["iterations"] <= s["evaluations"] - s["lanes"]
             # every lane has a row at least and s_max at most
             assert s["evaluations"] <= s["row_evaluations"] \
@@ -250,10 +252,16 @@ def test_no_span_is_kept_with_tracing_off(problem):
     assert not tracing.GLOBAL_TRACER._pending
 
 
-def test_a_buckets_counts_are_its_lanes_results_summed():
+@pytest.mark.parametrize("kernel", ["closed_form", "pallas_interpreter"])
+def test_a_buckets_counts_are_its_lanes_results_summed(kernel):
     """``_solve_bucket_impl``'s counts against the same lanes' own results
-    (the same vmapped solve, its per-lane ``OptimizerResult`` kept): weight-0
-    lanes, which solve nothing, are not counted."""
+    (``problem.run_lanes``, the flat loop the bucket runs, its per-lane
+    ``OptimizerResult`` kept): weight-0 lanes, which solve nothing, are not
+    counted, and ``passes``, the loop's own count, is the slowest lane's
+    evaluations. The coefficients are those of ``problem.run`` under
+    ``vmap`` (the nested loops a bucket ran before it had a loop of its own)
+    to the solve's tolerance: the same rules, a dot product summed in another
+    order."""
     from photon_ml_tpu.game.random_effect import (
         RandomEffectSolver,
         _solve_bucket_impl,
@@ -274,16 +282,29 @@ def test_a_buckets_counts_are_its_lanes_results_summed():
         config=GLMOptimizationConfiguration(
             regularization=L2Regularization,
             optimizer_config=OptimizerConfig(max_iterations=25,
-                                             track_states=False)))
+                                             track_states=False)),
+        fused_interpret=kernel == "pallas_interpreter")
     args = tuple(jnp.asarray(a) for a in (x, y, offsets, weights))
     w0, lam = jnp.zeros((lanes, d), jnp.float32), jnp.float32(1.0)
     w, _, converged, counts = jax.jit(
         _solve_bucket_impl, static_argnames="solver")(solver, *args, w0, lam)
     problem_ = solver._problem()
-    per_lane = jax.jit(jax.vmap(lambda xe, ye, oe, we, w0e: problem_.run(
-        GLMData(design=DenseDesign(x=xe), labels=ye, offsets=oe, weights=we),
-        w0e, lam)))(*args, w0)
+    # the kernel's block plan pads this bucket by one lane; the closed form
+    # pads nothing
+    assert problem_.objective.entity_pad(args[0]) \
+        == (kernel == "pallas_interpreter")
+    data = GLMData(design=DenseDesign(x=args[0]), labels=args[1],
+                   offsets=args[2], weights=args[3])
+    per_lane, passes = jax.jit(problem_.run_lanes)(data, w0, lam)
     np.testing.assert_array_equal(w, per_lane.w)
+    np.testing.assert_array_equal(converged, per_lane.converged)
+    assert int(counts["passes"]) == int(passes) \
+        == int(counts["max_lane_evaluations"])
+    nested = jax.jit(jax.vmap(problem_.run, in_axes=(0, 0, None)))(
+        data, w0, lam)
+    np.testing.assert_allclose(w, nested.w, rtol=1e-3, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(nested.iterations) == 0,
+                                  np.asarray(per_lane.iterations) == 0)
     real = slice(0, 5)
     rows = weights.sum(axis=1)[real]
     assert int(counts["lanes"]) == 5
