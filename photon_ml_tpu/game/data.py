@@ -596,6 +596,23 @@ class RandomEffectDatasetConfig:
                 "max_sample_buckets and max_feature_buckets must be ≥ 1 "
                 f"(got {self.max_sample_buckets}/{self.max_feature_buckets})")
 
+    @property
+    def resident(self) -> bool:
+        """Whether the coordinate's bucket tensors stay on the device across
+        sweeps, so a sweep is one program over all of them. Otherwise the
+        coordinate streams: a bucket is uploaded, solved and dropped, and
+        peak HBM is one bucket."""
+        return self.cache_device_buckets
+
+    @property
+    def reads_shared_image(self) -> bool:
+        """Whether the solver may gather the buckets on the device from
+        ``GameData``'s dense image of the shard. A streaming coordinate
+        must not (it would pin the image for the dataset's lifetime), and a
+        projected one's buckets hold projected features."""
+        return (self.resident
+                and self.projector_type is not ProjectorType.RANDOM)
+
 
 
 
@@ -991,9 +1008,7 @@ def _index_map_buckets_native(data, shard, all_active, ent_of_active,
     # nothing ever calls. Conservative gate — mirrors _compact_shared's
     # densify bound; a config that later needs the fat path just pays the
     # fill at first access.
-    # (RANDOM-projected configs never reach this builder, so projector-free
-    # is already guaranteed here)
-    indices_only = (config.cache_device_buckets
+    indices_only = (config.reads_shared_image
                     and shard.n_samples * shard.dim * 4
                     <= DENSE_DESIGN_MAX_BYTES)
     # one scratch shared by every deferred fill of this build (created on

@@ -163,7 +163,6 @@ class GameEstimator:
             choose_dense_design,
             design_dtype_of,
         )
-        from photon_ml_tpu.game.projector import ProjectorType
 
         if self.mesh is not None:
             return  # sharded paths build their own per-device feeds
@@ -175,10 +174,8 @@ class GameEstimator:
             if isinstance(cfg, FixedEffectCoordinateConfig):
                 sid, dt = cfg.feature_shard_id, cfg.design_dtype
             elif isinstance(cfg, RandomEffectCoordinateConfig):
-                if (not cfg.dataset.cache_device_buckets
-                        or cfg.dataset.projector_type
-                        is ProjectorType.RANDOM):
-                    continue  # solver won't use the shared image
+                if not cfg.dataset.reads_shared_image:
+                    continue
                 sid, dt = cfg.dataset.feature_shard_id, cfg.design_dtype
             else:
                 continue
@@ -253,8 +250,7 @@ class GameEstimator:
         resident = [
             (cid, ds, resident_fat_bytes(ds.buckets) // ep)
             for cid, ds in datasets.items()
-            if isinstance(ds, RandomEffectDataset)
-            and ds.config.cache_device_buckets]
+            if isinstance(ds, RandomEffectDataset) and ds.config.resident]
         total = sum(f for _, _, f in resident)
         for cid, ds, f in sorted(resident, key=lambda t: -t[2]):
             if total <= RE_FAT_CACHE_MAX_BYTES:
@@ -277,7 +273,7 @@ class GameEstimator:
             elif isinstance(cfg, RandomEffectCoordinateConfig):
                 ds = datasets.get(cid)
                 if (isinstance(ds, RandomEffectDataset)
-                        and ds.config.cache_device_buckets):
+                        and ds.config.reads_shared_image):
                     keep.add(cfg.dataset.feature_shard_id)
         for key in list(data._device_cache):
             if (isinstance(key, tuple) and key
@@ -285,13 +281,13 @@ class GameEstimator:
                 del data._device_cache[key]
 
     def _start_warm_compile(self, dataset, cfg, n: int) -> None:
-        """Kick off the coordinate's bucket-shape compiles in the background
-        so they overlap the fixed-effect stage (a warm driver run measured
-        ~2.8 s of compile-cache loading serialized inside the first RE
-        sweep). The solver hash (task, optimization config, mesh) matches
-        the one RandomEffectCoordinate builds, so train() hits the same jit
-        cache; RandomEffectSolver._warm_compile joins this thread before
-        checking the done flag."""
+        """Kick off a resident coordinate's uploads and the compile of its
+        sweep program in the background so they overlap the fixed-effect
+        stage (a warm driver run measured ~2.8 s of compile-cache loading
+        serialized inside the first RE sweep). The solver hash (task,
+        optimization config, mesh) matches the one RandomEffectCoordinate
+        builds, so train() hits the same program cache, and joins this
+        thread first."""
         import contextvars
         import threading
 

@@ -16,8 +16,16 @@ the single solve's nested loops under ``vmap`` (``minimize_owlqn``,
 lane stops changing), which share the inner loop's trips among the running
 lanes; the fixed effect's single solve keeps the nested ``minimize_lbfgs``,
 which works a direction out once an iteration (``lbfgs.py`` says why the
-loops are two). One compilation serves every bucket of the same
-(samples, features) shape across all CD sweeps.
+loops are two).
+
+A coordinate's sweep is one traced body, :func:`_sweep_fused_impl`: a bucket
+at a time it gathers the residual offsets, joins the warm start out of the
+previous sweep's coefficient table, solves, takes margins, scatters them into
+the score vector and flattens the coefficients. A resident dataset
+(``RandomEffectDatasetConfig.resident``) runs it over all its buckets as one
+program a sweep; a streaming one runs the same body a bucket a program, and
+waits for each before the next bucket uploads, so peak HBM stays one bucket.
+One compilation serves every sweep, cold and warm.
 
 Padding correctness: padded sample rows carry weight 0 (contribute nothing);
 padded feature columns are all-zero in x, so with zero init their gradient
@@ -101,10 +109,10 @@ class RandomEffectSolver:
     #: engage the single-pass Pallas entity kernel inside the bucket solves
     #: (ops/pallas_re.py): each L-BFGS evaluation then reads the (E, S, D)
     #: design ONCE instead of XLA's margins-then-gradient double pass.
-    #: Inert off-TPU (without ``fused_interpret``) and for projected /
-    #: streaming datasets and VMEM-oversized lanes — those keep the XLA
-    #: closed form transparently, same gate discipline as the fixed
-    #: effect's ``GLMObjective(fused=True)``.
+    #: Inert off-TPU (without ``fused_interpret``) and for lanes whose
+    #: ``(S, D)`` the kernel's gate declines (VMEM-oversized ones) — those
+    #: keep the XLA closed form transparently, same gate discipline as the
+    #: fixed effect's ``GLMObjective(fused=True)``.
     fused: bool = True
     #: testing only: run the entity kernel through the Pallas interpreter
     #: on non-TPU backends (orders of magnitude slower than XLA)
@@ -149,14 +157,6 @@ class RandomEffectSolver:
         names = [a for a in self.mesh.axis_names if a != self.entity_axis]
         return tuple(names) + (self.entity_axis,)
 
-    def _solve_bucket(self, x, labels, offsets, weights, w0, lam):
-        """Batched solve: x (E,S,D), labels/offsets/weights (E,S), w0 (E,D).
-
-        Dispatches the module-level profiled jit (compile/execute
-        accounting under ``fn="game.re.solve_bucket"``); inside the fused
-        sweep trace it inlines instead (tracer passthrough)."""
-        return _solve_bucket_jit(self, x, labels, offsets, weights, w0, lam)
-
     def _put(self, a, pad_value=0):
         """Pad the entity dim to the mesh axis size and shard lanes over it.
 
@@ -190,8 +190,8 @@ class RandomEffectSolver:
         are weight-0) for the residual-offset gather, and the scatter index
         (dead rows → ``n``, dropped by the ``mode="drop"`` scatter;
         deliberately NOT entity-padded, since zero-padding a scatter index
-        would alias sample 0). With ``config.cache_device_buckets`` off,
-        reverts to upload-and-drop (peak HBM = one bucket instead of all).
+        would alias sample 0). A streaming dataset caches nothing: upload
+        and drop (peak HBM = one bucket instead of all).
 
         When the dataset carries source data and the shard densifies
         (:meth:`_compact_shared`), the fat tensors are materialized ON
@@ -223,7 +223,7 @@ class RandomEffectSolver:
                     jnp.asarray(np.where(bucket.sample_idx >= 0,
                                          bucket.sample_idx, n)))
 
-        if not dataset.config.cache_device_buckets:
+        if not dataset.config.resident:
             return build()
         # n (the dead-row scatter sentinel) is baked into the built index,
         # so it must key the cache: the same dataset reused with a
@@ -250,13 +250,7 @@ class RandomEffectSolver:
         uploads with one compact CSR upload shared by every coordinate on
         the same shard — fewer bytes moved on any hardware."""
         data = dataset.source_data
-        if data is None or dataset.projector is not None:
-            return None
-        if not dataset.config.cache_device_buckets:
-            # upload-and-drop mode exists to BOUND peak HBM at ~one bucket;
-            # the materialize path would pin the dense shard image (+ index
-            # maps) on device for the dataset's lifetime — keep streaming
-            # on the host-upload path
+        if data is None or not dataset.config.reads_shared_image:
             return None
         if self.mesh is not None:
             # entity-mesh runs keep the fat path: its per-bucket tensors
@@ -270,16 +264,19 @@ class RandomEffectSolver:
             return None
         return shard_x, data.device_labels(), data.device_weights()
 
-    def _sweep_statics(self, dataset: RandomEffectDataset, n: int):
-        """Fat statics for the fused sweep (single home, shared by train()
-        and _warm_compile() so they can never pre-compile different
-        layouts). :meth:`_static_arrays` materializes them ON DEVICE from
-        the compact uploads when the dataset allows — the sweep program
-        itself always consumes the fat layout (gathering inside the
-        program instead re-paid the gather every solve: 3x on the 10M-row
-        RE bench)."""
-        return tuple(self._static_arrays(dataset, i, b, n)
-                     for i, b in enumerate(dataset.buckets))
+    def _sweep_inputs(self, dataset: RandomEffectDataset, ks, n: int,
+                      warm: Optional[RandomEffectModel], shard_dim: int):
+        """The sweep body's per-bucket inputs for buckets ``ks``:
+        ``(statics, warm_ctxs, cidxs, e_reals)`` (single home, shared by
+        train() and _warm_compile() so they can never pre-compile different
+        layouts)."""
+        buckets = [(k, dataset.buckets[k]) for k in ks]
+        return (tuple(self._static_arrays(dataset, k, b, n)
+                      for k, b in buckets),
+                tuple(self._warm_ctx(dataset, k, b, warm, shard_dim)
+                      for k, b in buckets),
+                tuple(self._coef_idx(dataset, k, b) for k, b in buckets),
+                tuple(b.n_entities for _, b in buckets))
 
     def _compact_arrays(self, dataset: RandomEffectDataset, i: int,
                         bucket: REBucket):
@@ -331,51 +328,16 @@ class RandomEffectSolver:
                           bucket=i, **static) as span:
             span.set(**counts)
 
-    @partial(jax.jit, static_argnames=("self",))
-    def _margins_bucket(self, x, w):
-        return jnp.einsum("esd,ed->es", x, w,
-                          preferred_element_type=jnp.float32)
-
-    def _sweep_fused(self, offsets_dev, lam, statics, warm_ctxs, coeffs_warm,
-                     cidxs, e_reals, out_sharding=None):
-        """One program for the WHOLE coordinate sweep (dispatched through
-        the module-level profiled jit, ``fn="game.re.sweep_fused"`` — the
-        per-coordinate compile counter the flat-recompile contract watches):
-        per bucket, gather
-        residual offsets, gather warm starts from the previous sweep's
-        coefficient table, solve, compute margins, scatter into the score
-        vector; plus the flat coefficient/variance table for the single
-        model D2H and the device coefficient mirror (passive scoring).
-
-        The per-bucket formulation dispatched ~6 programs per bucket per
-        sweep; one fused program pays the per-program launch+sync cost once
-        (that cost on the present chip: not measured).
-        ``coeffs_warm`` is sized to the dataset's full key-table length from
-        sweep 0 (zeros — every ``found`` is False), so a single compilation
-        serves the cold sweep and every warm sweep.
-
-        Statics are the fat 5-tuple per bucket — ``(x, labels, weights,
-        gather_idx, scatter_idx)`` — either uploaded from host fills or
-        materialized on device from the compact index maps
-        (:func:`_materialize_fat`); the sweep program is identical either
-        way, and gathering inside the program instead re-paid the gather
-        every solve (measured 3x on the 10M-row RE bench).
-        """
-        return _sweep_fused_jit(self, offsets_dev, lam, statics, warm_ctxs,
-                                coeffs_warm, cidxs, e_reals,
-                                out_sharding=out_sharding)
-
     def _warm_ctx(self, dataset: RandomEffectDataset, i: int,
                   bucket: REBucket, warm: Optional[RandomEffectModel],
                   shard_dim: int):
-        """(pos, found) join of bucket slots into the model key table — the
-        single home of the warm-join cache (used by the fused sweep's
-        in-program gather AND the per-bucket _warm_start_device path).
-        With no usable warm model the cached zero-join (found all-False)
-        keeps the program signature — and so the compilation — identical to
-        warm sweeps."""
-        if (warm is not None and len(warm.keys) and warm.dim == shard_dim
-                and warm.projector is None):
+        """(pos, found) join of bucket slots into the model key table, for
+        the sweep body's in-program gather: the one warm-start route. A
+        projected model's keys are ``entity * projected_dim + slot``, joined
+        like any other. With no warm model (``train`` passes None for one it
+        cannot use) the cached zero-join (found all-False) keeps the program
+        signature — and so the compilation — identical to warm sweeps."""
+        if warm is not None:
             key = ("warmidx", i, self.mesh, self.entity_axis)
             ctx = dataset._device_cache.get(key)
             # validate against the cached key TABLE, not just its shape: a
@@ -445,123 +407,43 @@ class RandomEffectSolver:
         if th is not None and th is not threading.current_thread():
             th.join()
 
-    def _warm_start_device(self, dataset: RandomEffectDataset, i: int,
-                           bucket: REBucket,
-                           warm: Optional[RandomEffectModel],
-                           shard_dim: int):
-        """Warm-start coefficients gathered ON DEVICE from the previous
-        sweep's coefficient table, or None for the host fallback.
-
-        Symmetric with the passive-scoring join: the (bucket slot →
-        model-table position) map is static across sweeps (both the bucket's
-        feature layout and the model's key set are dataset-determined), so
-        it's computed once; each sweep is then one device gather — no host
-        lookup and no (entities × local-dim) H2D per bucket per sweep."""
-        if (warm is None or warm.coeffs_device is None
-                or warm.projector is not None or not len(warm.keys)
-                or warm.dim != shard_dim):
-            return None
-        pos_d, found_d = self._warm_ctx(dataset, i, bucket, warm, shard_dim)
-        return _warm_gather(warm.coeffs_device, pos_d, found_d)
-
-    def _warm_compile(self, dataset: RandomEffectDataset,
-                      n: Optional[int] = None) -> None:
-        """Pre-compile the dataset's solver programs.
-
-        With ``n`` (the sample count) and a fused-eligible dataset
-        (device-cached buckets, no projector) this compiles THE fused sweep
-        program itself on the real static arrays — which also performs the
-        bucket uploads and join builds train() will reuse — against an
-        all-zero offsets/warm signature that matches every later sweep.
-        Otherwise falls back to per-bucket-shape compiles (streaming and
-        projected datasets keep the per-bucket dispatch path).
-
-        Each distinct (entities, samples, features) bucket shape is its own
-        XLA program; compiling lazily inside the bucket loop serializes the
-        compiles because the model-table D2H after each solve blocks until
-        that bucket finishes. XLA compilation releases the GIL, so a thread
-        pool can overlap the compiles up to the backend compiler's own
-        concurrency (the gain with libtpu's host-local compiler: not
-        measured). Keyed per dataset; later sweeps hit jit's own cache and
-        skip this entirely.
+    def _warm_compile(self, dataset: RandomEffectDataset, n: int) -> None:
+        """Pre-compile a resident dataset's sweep program, for ``n``
+        samples, on the real static arrays — which also performs the bucket
+        uploads and join builds train() will reuse — against an all-zero
+        offsets/warm signature that matches every later sweep. Keyed per
+        dataset; later sweeps hit the program's own cache. A streaming
+        dataset compiles a program a bucket at its first sweep: uploading
+        here would hold what streaming exists to drop.
         """
-        import threading
-
-        # a background pre-compile started at estimator prepare() time (so
-        # cache loads overlap the fixed-effect stage) finishes first; train
-        # then finds the flag set and skips
-        th = getattr(dataset, "_warm_thread", None)
-        if th is not None and th is not threading.current_thread():
-            th.join()
+        # a background pre-compile started at estimator prepare() time
+        # finishes first; train then finds the flag set and skips
+        self._join_warm(dataset)
         if getattr(dataset, "_warm_compiled", None) == (self.mesh,):
             return
-        if (n is not None and dataset.config.cache_device_buckets
-                and dataset.projector is None and dataset.buckets):
-            buckets = dataset.buckets
-            # the uploads/joins below are per-DATASET work train() reuses —
-            # always worth doing here (overlapped with the fixed-effect
-            # stage); only the zero-data execution is skippable when this
-            # process already compiled the program
-            statics = self._sweep_statics(dataset, n)
-            warm_ctxs = tuple(self._warm_ctx(dataset, i, b, None, 0)
-                              for i, b in enumerate(buckets))
-            cidxs = tuple(self._coef_idx(dataset, i, b)
-                          for i, b in enumerate(buckets))
-            sig = hash((self, n,
-                        tuple((b.tensor_shape, b.n_entities)
-                              for b in buckets),
-                        self._key_table_len(dataset)))
-            # under a mesh the program's signature includes the placement
-            # of the caller's residual vector (train() keeps a data-sharded
-            # score layout), which is not known here: compiling against a
-            # one-device stand-in would build a program no sweep ever runs
-            if sig not in _PRECOMPILED and self.mesh is None:
-                out = self._sweep_fused(
-                    jnp.zeros((n,), jnp.float32), jnp.zeros((), jnp.float32),
-                    statics, warm_ctxs, self._zero_coeffs(dataset), cidxs,
-                    tuple(b.n_entities for b in buckets))
-                np.asarray(out[1][:1])  # D2H pull: waits for the program
-                _PRECOMPILED.add(sig)
-            object.__setattr__(dataset, "_warm_compiled", (self.mesh,))
+        if not (dataset.config.resident and dataset.buckets):
             return
-        shapes = sorted({(bucket.tensor_shape, bucket.tensor_shape[:2])
-                         for bucket in dataset.buckets})
-        shapes = [s for s in shapes if hash((self, s)) not in _PRECOMPILED]
-        if not shapes:
-            object.__setattr__(dataset, "_warm_compiled", (self.mesh,))
-            return
-
-        def compile_one(shape_pair):
-            # the NORMAL call path on all-zero dummies: lower().compile()
-            # would build an AOT executable that the jit dispatch cache never
-            # sees (it would recompile on first real call). Dummies go
-            # through the same _put placement as the real arguments — the
-            # jit cache keys on sharding, so a differently-placed dummy
-            # would compile a program the real call never uses. Zero data
-            # makes the wasted execution converge immediately (gradient =
-            # L2 at w=0 = 0 for every lane).
-            xs, ls = shape_pair
-            f32 = np.float32
-            args = (self._put(np.zeros(xs, f32)), self._put(np.zeros(ls, f32)),
-                    self._put(np.zeros(ls, f32)), self._put(np.zeros(ls, f32)),
-                    self._put(np.zeros((xs[0], xs[2]), f32)),
-                    jnp.zeros((), jnp.float32))
-            jax.block_until_ready(self._solve_bucket(*args))
-            _PRECOMPILED.add(hash((self, shape_pair)))
-
-        import concurrent.futures as cf
-        import contextvars
-
-        # upload-and-drop mode bounds peak HBM to ~one bucket; concurrent
-        # dummy placements would hold one design per worker, so serialize
-        workers = (1 if not dataset.config.cache_device_buckets
-                   else min(8, len(shapes)))
-        # each compile under a copy of this thread's span context, so its
-        # jit.compile span is a child of the stage that waits for it here
-        ctxs = [contextvars.copy_context() for _ in shapes]
-        with cf.ThreadPoolExecutor(max_workers=workers) as pool:
-            list(pool.map(lambda ctx, s: ctx.run(compile_one, s),
-                          ctxs, shapes))
+        buckets = dataset.buckets
+        # the uploads/joins below are per-DATASET work train() reuses —
+        # always worth doing here (overlapped with the fixed-effect
+        # stage); only the zero-data execution is skippable when this
+        # process already compiled the program
+        statics, warm_ctxs, cidxs, e_reals = self._sweep_inputs(
+            dataset, range(len(buckets)), n, None, 0)
+        sig = hash((self, n,
+                    tuple((b.tensor_shape, b.n_entities) for b in buckets),
+                    self._key_table_len(dataset)))
+        # under a mesh the program's signature includes the placement
+        # of the caller's residual vector (train() keeps a data-sharded
+        # score layout), which is not known here: compiling against a
+        # one-device stand-in would build a program no sweep ever runs
+        if sig not in _PRECOMPILED and self.mesh is None:
+            out = _sweep_fused_jit(
+                self, jnp.zeros((n,), jnp.float32),
+                jnp.zeros((), jnp.float32), statics, warm_ctxs,
+                self._zero_coeffs(dataset), cidxs, e_reals)
+            np.asarray(out[1][:1])  # D2H pull: waits for the program
+            _PRECOMPILED.add(sig)
         object.__setattr__(dataset, "_warm_compiled", (self.mesh,))
 
     def train(
@@ -581,259 +463,161 @@ class RandomEffectSolver:
         vector of this coordinate's margin on every active sample
         (0 elsewhere — passive scoring is the model's job).
         """
-        cfg = dataset.config
         if dataset.projector is not None:
             # projected space: keys/coefficients live in projected_dim
             shard_dim = dataset.projector.projected_dim
         else:
             shard_dim = dim if dim is not None else _shard_dim(dataset)
-        keys_parts: list[np.ndarray] = []
-        coef_parts: list[np.ndarray] = []
-        var_parts: list[np.ndarray] = []
         n = offsets.shape[0]
         offsets_dev = jnp.asarray(offsets, jnp.float32)
-        scores = jnp.zeros(n, jnp.float32)
-        want_var = self.config.variance_type != VarianceComputationType.NONE
-        self._join_warm(dataset)
-        if not cfg.cache_device_buckets or dataset.projector is not None:
-            # per-bucket dispatch path: overlap the per-shape compiles
-            # (the fused path is one program — compiling it right before
-            # calling it would gain nothing)
-            self._warm_compile(dataset)
-
-        # Phase 1 — dispatch every bucket's solve/margins/scatter without a
-        # single device sync: jax dispatch is async, so all bucket programs
-        # queue back-to-back on the device while the host runs ahead. A D2H
-        # inside the loop (the old structure) would block bucket i+1's
-        # dispatch on bucket i's completion. EXCEPT in upload-and-drop mode
-        # (cache_device_buckets=False): queued programs pin every bucket's
-        # design in HBM, which is exactly what that flag bounds — there the
-        # loop syncs per bucket so bucket i's x frees before i+1 uploads.
-        streaming = not cfg.cache_device_buckets
         lam_dev = jnp.asarray(lam, jnp.float32)
-        pending = []
-        bucket_evaluations = []  # for the enclosing cd.step
-        dev_coeff_parts: list[jnp.ndarray] = []
-        fused = (not streaming and dataset.projector is None
-                 and len(dataset.buckets) > 0)
+        self._join_warm(dataset)
+        nb = len(dataset.buckets)
+        if not nb:
+            return (self._model(dataset, shard_dim,
+                                np.zeros((0,), np.float32), None),
+                    jnp.zeros(n, jnp.float32))
+        if (warm_start is None or not len(warm_start.keys)
+                or warm_start.dim != shard_dim):
+            # a table keyed by another modulus would join another entity's
+            # slots: start cold
+            warm_start = None
+            coeffs_warm = self._zero_coeffs(dataset)
+        elif warm_start.coeffs_device is not None:
+            coeffs_warm = warm_start.coeffs_device
+        else:
+            coeffs_warm = jnp.asarray(np.asarray(warm_start.coeffs,
+                                                 np.float32))
+        if self.mesh is not None:
+            # the zero table (one device) and the previous sweep's table
+            # (replicated over the mesh) must reach the program under
+            # ONE placement, or the first warm sweep recompiles it
+            coeffs_warm = jax.device_put(coeffs_warm, replicated(self.mesh))
+        # preserve a caller-supplied data sharding on the score vector
+        # (sharded-score prototype; None = default single-layout path)
+        off_sharding = getattr(offsets_dev, "sharding", None)
+        out_sharding = (off_sharding
+                        if isinstance(off_sharding, NamedSharding)
+                        and tuple(off_sharding.spec) else None)
 
-        def collect(bucket, e_real, w_dev, variances):
-            # one D2H of the (entities, local-dim) coefficients — the model
-            # itself — then host table assembly (streaming mode only; the
-            # cached-bucket path batches all buckets into a single D2H)
-            collect_host(bucket, np.asarray(w_dev)[:e_real],
-                         np.asarray(variances)[:e_real])
+        def sweep(ks):
+            statics, warm_ctxs, cidxs, e_reals = self._sweep_inputs(
+                dataset, ks, n, warm_start, shard_dim)
+            scores, payload, coeffs_unsorted, counts, evaluations = \
+                _sweep_fused_jit(
+                    self, offsets_dev, lam_dev, statics, warm_ctxs,
+                    coeffs_warm, cidxs, e_reals, out_sharding=out_sharding)
+            for k, counts_k in zip(ks, counts):
+                self._record_solve(dataset, k, dataset.buckets[k], counts_k)
+            return scores, payload, coeffs_unsorted, evaluations
 
-        def collect_host(bucket, w, variances):
-            fmask = bucket.feature_index >= 0
-            keys_parts.append(_bucket_keys(bucket, shard_dim))
-            coef_parts.append(w[fmask].astype(np.float32))
-            if want_var and np.asarray(variances).size:
-                var_parts.append(np.asarray(variances)[fmask].astype(np.float32))
+        if dataset.config.resident:
+            scores, payload, coeffs_unsorted, evaluations = sweep(range(nb))
+        else:
+            # a bucket a program, and the host waits for it and pulls its
+            # flat coefficients before the next bucket uploads: queued
+            # programs would pin every bucket's design in HBM, which is
+            # what streaming bounds. A bucket's rows are its own, so the
+            # buckets' score vectors add up to the sweep's.
+            scores, payload, coef_parts, evaluations = None, [], [], 0
+            for k in range(nb):
+                scores_k, payload_k, coeffs_k, evaluations_k = sweep((k,))
+                payload.append(np.asarray(payload_k))
+                scores = scores_k if scores is None else scores + scores_k
+                coef_parts.append(coeffs_k)
+                evaluations = evaluations + evaluations_k
+            coeffs_unsorted = jnp.concatenate(coef_parts)
+        tracing.set_on_enclosing("cd.step", evaluations=evaluations)
+        return (self._model(dataset, shard_dim, payload, coeffs_unsorted),
+                scores)
 
-        if fused:
-            # One program for the whole sweep + one D2H for the model table
-            # (see _sweep_fused). The per-bucket path below survives for the
-            # streaming (upload-and-drop) and projected modes.
-            buckets = dataset.buckets
-            statics = self._sweep_statics(dataset, n)
-            warm_ctxs = tuple(
-                self._warm_ctx(dataset, i, b, warm_start, shard_dim)
-                for i, b in enumerate(buckets))
-            usable_warm = (warm_start is not None and len(warm_start.keys)
-                           and warm_start.dim == shard_dim
-                           and warm_start.projector is None)
-            if usable_warm:
-                coeffs_warm = (warm_start.coeffs_device
-                               if warm_start.coeffs_device is not None
-                               else jnp.asarray(
-                                   np.asarray(warm_start.coeffs, np.float32)))
-            else:
-                coeffs_warm = self._zero_coeffs(dataset)
-            if self.mesh is not None:
-                # the zero table (one device) and the previous sweep's table
-                # (replicated over the mesh) must reach the program under
-                # ONE placement, or the first warm sweep recompiles it
-                coeffs_warm = jax.device_put(coeffs_warm,
-                                             replicated(self.mesh))
-            cidxs = tuple(self._coef_idx(dataset, i, b)
-                          for i, b in enumerate(buckets))
-            e_reals = tuple(b.n_entities for b in buckets)
-            # preserve a caller-supplied data sharding on the score vector
-            # (sharded-score prototype; None = default single-layout path)
-            from jax.sharding import NamedSharding as _NS
+    def _model(self, dataset: RandomEffectDataset, shard_dim: int, payload,
+               coeffs_unsorted) -> RandomEffectModel:
+        """The model of one sweep, from the sweep body's flat ``payload``
+        (every bucket's ``(entities, local-dim)`` coefficients, then every
+        bucket's variances) and its kept coefficients in bucket slot order
+        (``coeffs_unsorted``, for the device mirror). A device payload is
+        pulled at the first access of ``coeffs``: coordinate descent can
+        dispatch the NEXT coordinate while this one's program is still
+        executing (the eager pull was a full pipeline barrier per
+        coordinate). A streaming sweep's payload, the list of its buckets'
+        pulls, is on the host already and is split at once."""
+        cfg = dataset.config
+        buckets = dataset.buckets
+        want_var = self.config.variance_type != VarianceComputationType.NONE
+        d_of = [b.tensor_shape[2] for b in buckets]
+        w_sizes = [b.n_entities * d for b, d in zip(buckets, d_of)]
+        v_sizes = [b.n_entities * (d if want_var else 0)
+                   for b, d in zip(buckets, d_of)]
+        bounds = np.cumsum([0] + w_sizes + v_sizes)
+        nb = len(buckets)
+        if isinstance(payload, list):
+            payload = np.concatenate(
+                [p[:w] for p, w in zip(payload, w_sizes)]
+                + [p[w:] for p, w in zip(payload, w_sizes)])
+        # the key table and its sort order are DATASET-static (derived
+        # from bucket entity/feature indexes, not coefficients) — cached
+        hk_key = ("hostkeys", shard_dim)
+        hk = dataset._device_cache.get(hk_key)
+        if hk is None:
+            kp = [_bucket_keys(b, shard_dim) for b in buckets]
+            keys_all = (np.concatenate(kp) if kp
+                        else np.zeros((0,), np.int64))
+            order0 = np.argsort(keys_all, kind="stable")
+            hk = (keys_all[order0], order0)
+            dataset._device_cache[hk_key] = hk
+        keys_sorted, order = hk
 
-            off_sharding = getattr(offsets_dev, "sharding", None)
-            out_sharding = (off_sharding if isinstance(off_sharding, _NS)
-                            and tuple(off_sharding.spec) else None)
-            scores, batched_dev, coeffs_unsorted, counts, evaluations = \
-                self._sweep_fused(
-                    offsets_dev, lam_dev, statics, warm_ctxs, coeffs_warm,
-                    cidxs, e_reals, out_sharding=out_sharding)
-            for i, (bucket, counts_k) in enumerate(zip(buckets, counts)):
-                self._record_solve(dataset, i, bucket, counts_k)
-            tracing.set_on_enclosing("cd.step", evaluations=evaluations)
-            d_of = [b.tensor_shape[2] for b in buckets]
-            w_sizes = [b.n_entities * d for b, d in zip(buckets, d_of)]
-            v_sizes = [b.n_entities * (d if want_var else 0)
-                       for b, d in zip(buckets, d_of)]
-            bounds = np.cumsum([0] + w_sizes + v_sizes)
-            nb = len(buckets)
-            # the key table and its sort order are DATASET-static (derived
-            # from bucket entity/feature indexes, not coefficients) — cached
-            hk_key = ("hostkeys", shard_dim)
-            hk = dataset._device_cache.get(hk_key)
-            if hk is None:
-                kp = [_bucket_keys(b, shard_dim) for b in buckets]
-                keys_all = (np.concatenate(kp) if kp
-                            else np.zeros((0,), np.int64))
-                order0 = np.argsort(keys_all, kind="stable")
-                hk = (keys_all[order0], order0)
-                dataset._device_cache[hk_key] = hk
-            keys_sorted, order = hk
+        def host_tables(injected=None):
+            # ``injected`` lets GameModel.materialize batch this pull
+            # with every other coordinate's into one transfer.
+            batched = np.asarray(payload if injected is None else injected)
+            cp, vp = [], []
+            for k, bucket in enumerate(buckets):
+                fmask = bucket.feature_index >= 0
+                w_np = batched[bounds[k]:bounds[k + 1]].reshape(
+                    bucket.n_entities, -1)
+                cp.append(w_np[fmask].astype(np.float32))
+                if want_var:
+                    v_np = batched[bounds[nb + k]:bounds[nb + k + 1]
+                                   ].reshape(bucket.n_entities, -1)
+                    if v_np.size:
+                        vp.append(v_np[fmask].astype(np.float32))
+            coeffs = (np.concatenate(cp) if cp
+                      else np.zeros((0,), np.float32))
+            variances = (np.concatenate(vp)[order]
+                         if want_var and vp else None)
+            return coeffs[order], variances
 
-            def host_tables(injected=None, batched_dev=batched_dev,
-                            buckets=buckets, bounds=bounds, nb=nb,
-                            order=order, want_var=want_var):
-                # the sweep's single D2H, deferred to first coeffs access:
-                # coordinate descent can dispatch the NEXT coordinate while
-                # this one's programs are still executing (the eager pull
-                # was a full pipeline barrier per coordinate).
-                # ``injected`` lets GameModel.materialize batch this pull
-                # with every other coordinate's into one transfer.
-                batched = (np.asarray(batched_dev) if injected is None
-                           else np.asarray(injected))
-                cp, vp = [], []
-                for k, bucket in enumerate(buckets):
-                    fmask = bucket.feature_index >= 0
-                    w_np = batched[bounds[k]:bounds[k + 1]].reshape(
-                        bucket.n_entities, -1)
-                    cp.append(w_np[fmask].astype(np.float32))
-                    if want_var:
-                        v_np = batched[bounds[nb + k]:bounds[nb + k + 1]
-                                       ].reshape(bucket.n_entities, -1)
-                        if v_np.size:
-                            vp.append(v_np[fmask].astype(np.float32))
-                coeffs = (np.concatenate(cp) if cp
-                          else np.zeros((0,), np.float32))
-                variances = (np.concatenate(vp)[order]
-                             if want_var and vp else None)
-                return coeffs[order], variances
-
-            host_tables.device_payload = batched_dev
+        if isinstance(payload, np.ndarray):
+            coeffs, variances = host_tables()
+        else:
+            host_tables.device_payload = payload
+            coeffs = host_tables
+            variances = host_tables if want_var else None
+        coeffs_device = None
+        if coeffs_unsorted is not None:
+            # device mirror of the sorted coefficient table (static
+            # permutation, cached) — consumed by the coordinate's
+            # on-device passive scoring and the next sweep's warm start
             ok = ("order",)
             order_dev = dataset._device_cache.get(ok)
             if order_dev is None:
                 order_dev = jnp.asarray(np.asarray(order, np.int32))
                 dataset._device_cache[ok] = order_dev
             coeffs_device = coeffs_unsorted[order_dev]
-            model = RandomEffectModel(
-                random_effect_type=cfg.random_effect_type,
-                feature_shard_id=cfg.feature_shard_id,
-                task=self.task, dim=shard_dim, keys=keys_sorted,
-                coeffs=host_tables,
-                variances=host_tables if want_var else None,
-                projector=dataset.projector,
-                coeffs_device=coeffs_device)
-            return model, scores
-
-        for i, bucket in enumerate(dataset.buckets):  # non-fused modes only
-            e_real = bucket.n_entities
-            x_d, lab_d, wt_d, idx_d, store_d = self._static_arrays(
-                dataset, i, bucket, n)
-            boff = _bucket_offsets(offsets_dev, idx_d, wt_d)
-            w0_d = self._warm_start_device(dataset, i, bucket, warm_start,
-                                           shard_dim)
-            if w0_d is None:
-                w0_d = self._put(
-                    _gather_warm_start(bucket, warm_start, shard_dim))
-            w_dev, variances, _conv, counts_k = self._solve_bucket(
-                x_d, lab_d, boff, wt_d, w0_d, lam_dev)
-            self._record_solve(dataset, i, bucket, counts_k)
-            bucket_evaluations.append(counts_k["evaluations"])
-            # margins from the already-placed design (x is the dominant
-            # payload; avoid a second host→device copy of it), scattered
-            # into the device score vector — dead rows carry index n, which
-            # mode="drop" discards (negative indices would WRAP, not drop)
-            margins = self._margins_bucket(x_d, w_dev)[:e_real]
-            scores = scores.at[store_d].set(margins, mode="drop")
-            # device copy of this bucket's model coefficients, in the same
-            # host-table order (the flat kept-feature index is static):
-            # feeds the model's coeffs_device for on-device passive scoring.
-            # Projected datasets never consume it (their passive scoring
-            # projects through the host path) — skip the work.
-            if dataset.projector is not None:
-                if streaming:
-                    jax.block_until_ready(scores)
-                    collect(bucket, e_real, w_dev, variances)
-                else:
-                    pending.append((bucket, e_real, w_dev, variances))
-                continue
-            dev_coeff_parts.append(
-                w_dev[:e_real].reshape(-1)[self._coef_idx(dataset, i, bucket)]
-                .astype(jnp.float32))
-            if streaming:
-                # force completion so this bucket's buffers can be dropped
-                jax.block_until_ready(scores)
-                collect(bucket, e_real, w_dev, variances)
-            else:
-                pending.append((bucket, e_real, w_dev, variances))
-
-        if bucket_evaluations:
-            tracing.set_on_enclosing("cd.step",
-                                     evaluations=sum(bucket_evaluations))
-        # Phase 2 — collect (cached-bucket mode): every pending bucket's
-        # coefficient (and variance) table rides ONE concatenated
-        # device→host transfer, split on host — per-bucket D2H syncs
-        # serialized the tail of the sweep (each one's cost on the present
-        # chip: not measured)
-        if pending:
-            flat_w = [w_dev[:e_real].reshape(-1)
-                      for (_b, e_real, w_dev, _v) in pending]
-            flat_v = [jnp.asarray(v)[:e_real].reshape(-1)
-                      for (_b, e_real, _w, v) in pending]
-            w_sizes = [int(a.shape[0]) for a in flat_w]
-            v_sizes = [int(a.shape[0]) for a in flat_v]
-            batched = np.asarray(jnp.concatenate(flat_w + flat_v))
-            bounds = np.cumsum([0] + w_sizes + v_sizes)
-            nb = len(pending)
-            for k, (bucket, e_real, _w, _v) in enumerate(pending):
-                w_np = batched[bounds[k]:bounds[k + 1]].reshape(e_real, -1)
-                v_np = batched[bounds[nb + k]:bounds[nb + k + 1]].reshape(
-                    e_real, -1)
-                collect_host(bucket, w_np, v_np)
-
-        keys = (np.concatenate(keys_parts) if keys_parts
-                else np.zeros((0,), np.int64))
-        coeffs = (np.concatenate(coef_parts) if coef_parts
-                  else np.zeros((0,), np.float32))
-        variances = (np.concatenate(var_parts)
-                     if want_var and var_parts else None)
-        order = np.argsort(keys, kind="stable")
-        # device mirror of the sorted coefficient table (static permutation,
-        # cached) — consumed by the coordinate's on-device passive scoring
-        coeffs_device = None
-        if dev_coeff_parts:
-            ok = ("order",)
-            order_dev = dataset._device_cache.get(ok)
-            if order_dev is None:
-                order_dev = jnp.asarray(np.asarray(order, np.int32))
-                dataset._device_cache[ok] = order_dev
-            coeffs_device = jnp.concatenate(dev_coeff_parts)[order_dev]
-        model = RandomEffectModel(
+        return RandomEffectModel(
             random_effect_type=cfg.random_effect_type,
             feature_shard_id=cfg.feature_shard_id,
-            task=self.task, dim=shard_dim, keys=keys[order],
-            coeffs=coeffs[order],
-            variances=None if variances is None else variances[order],
+            task=self.task, dim=shard_dim, keys=keys_sorted,
+            coeffs=coeffs, variances=variances,
             projector=dataset.projector,
             coeffs_device=coeffs_device)
-        return model, scores
 
 
 def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
-    """Batched bucket solve body (the traced program behind
-    :meth:`RandomEffectSolver._solve_bucket`): ``(w, variances, converged,
+    """Batched bucket solve, traced inside the sweep body: x (E,S,D),
+    labels/offsets/weights (E,S), w0 (E,D) give ``(w, variances, converged,
     counts)``, the first three per lane, ``counts`` the bucket's device
     scalars for its ``game.re.solve`` span (lanes that weigh something, the
     sums of their iterations and evaluations, plain and weighted by each
@@ -923,8 +707,26 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
 
 def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
                       coeffs_warm, cidxs, e_reals, out_sharding=None):
-    """Fused whole-coordinate sweep body (the traced program behind
-    :meth:`RandomEffectSolver._sweep_fused`; semantics documented there)."""
+    """The sweep body: one program for the sweep of the buckets it is
+    given — all of a resident dataset's, one of a streaming dataset's —
+    and the only code that, per bucket, gathers residual offsets, gathers
+    warm starts from the previous sweep's coefficient table, solves,
+    computes margins and scatters them into the score vector (zero on every
+    other bucket's rows); plus the flat coefficient/variance payload for
+    the model's D2H, the device coefficient mirror (passive scoring), and
+    each bucket's counts for its ``game.re.solve`` span.
+
+    ``coeffs_warm`` is sized to the dataset's full key-table length from
+    sweep 0 (zeros — every ``found`` is False), so a single compilation
+    serves the cold sweep and every warm sweep.
+
+    Statics are the fat 5-tuple per bucket — ``(x, labels, weights,
+    gather_idx, scatter_idx)`` — either uploaded from host fills or
+    materialized on device from the compact index maps
+    (:func:`_materialize_fat`); the sweep program is identical either
+    way, and gathering inside the program instead re-paid the gather
+    every solve (measured 3x on the 10M-row RE bench).
+    """
     scores = jnp.zeros_like(offsets_dev)
     flat_w: list[jnp.ndarray] = []
     flat_v: list[jnp.ndarray] = []
@@ -933,6 +735,8 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
     for statics_k, (pos_d, found_d), cidx, \
             e_real in zip(statics, warm_ctxs, cidxs, e_reals):
         x_d, lab_d, wt_d, idx_d, store_d = statics_k
+        # zero for padded rows: their weight is 0, and the margin must
+        # stay finite
         boff = jnp.take(offsets_dev, idx_d.reshape(-1),
                         mode="clip").reshape(idx_d.shape) * (wt_d > 0)
         w0 = jnp.where(
@@ -940,10 +744,12 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
             jnp.take(coeffs_warm, pos_d.reshape(-1),
                      mode="clip").reshape(pos_d.shape),
             0.0).astype(jnp.float32)
-        w_dev, variances, _conv, counts_k = solver._solve_bucket(
-            x_d, lab_d, boff, wt_d, w0, lam)
+        w_dev, variances, _conv, counts_k = _solve_bucket_jit(
+            solver, x_d, lab_d, boff, wt_d, w0, lam)
         counts.append(counts_k)
-        margins = solver._margins_bucket(x_d, w_dev)[:e_real]
+        margins = _margins_bucket(x_d, w_dev)[:e_real]
+        # dead rows carry index n, which mode="drop" discards (negative
+        # indices would WRAP, not drop)
         scores = scores.at[store_d].set(margins, mode="drop")
         flat_w.append(w_dev[:e_real].reshape(-1))
         flat_v.append(jnp.asarray(variances)[:e_real].reshape(-1))
@@ -961,13 +767,25 @@ def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
             evaluations)
 
 
-#: the profiled executables behind the solver methods: module-level so the
-#: per-signature compiled cache (and its compile accounting) is shared by
-#: every solver instance of a process — RandomEffectSolver is a frozen
-#: value-equal dataclass, so the ``solver`` static keys by configuration,
-#: exactly like the old per-method jit cache
-_solve_bucket_jit = profiling.profile_jit(
-    _solve_bucket_impl, "game.re.solve_bucket", static_argnames=("solver",))
+@jax.jit
+def _margins_bucket(x, w):
+    return jnp.einsum("esd,ed->es", x, w,
+                      preferred_element_type=jnp.float32)
+
+
+#: plain jits, only ever traced inside the sweep body, where all they do is
+#: keep a ``func.call`` around the solve and the margins in the lowered
+#: program. Inlined, the same operations compiled to a module whose entry and
+#: loop bodies listed their instructions in another order (CHANGES.md, PR 30),
+#: and a PR that may not move the cells' program cannot take that: they go
+#: with the first PR that may.
+_solve_bucket_jit = jax.jit(_solve_bucket_impl, static_argnames=("solver",))
+
+#: the sweep's one profiled executable (``fn="game.re.sweep_fused"``: the
+#: per-coordinate compile counter the flat-recompile contract watches),
+#: module-level so the per-signature compiled cache is shared by every
+#: solver instance of a process — RandomEffectSolver is a frozen
+#: value-equal dataclass, so the ``solver`` static keys by configuration
 _sweep_fused_jit = profiling.profile_jit(
     _sweep_fused_impl, "game.re.sweep_fused",
     static_argnames=("solver", "e_reals", "out_sharding"))
@@ -1010,37 +828,9 @@ def _materialize_fat(shard_x, labels_g, weights_g, perm_d, counts_d, fi_d,
     return x, labels, weights, clip, store
 
 
-@jax.jit
-def _warm_gather(coeffs_device, pos_d, found_d):
-    flat = jnp.take(coeffs_device, pos_d.reshape(-1), mode="clip")
-    return jnp.where(found_d, flat.reshape(pos_d.shape), 0.0
-                     ).astype(jnp.float32)
-
-
-@jax.jit
-def _bucket_offsets(offsets_dev, idx_d, wt_d):
-    """Gather each bucket row's residual offset on device (zero for padded
-    rows — their weight is 0, and the margin must stay finite)."""
-    flat = jnp.take(offsets_dev, idx_d.reshape(-1), mode="clip")
-    return flat.reshape(idx_d.shape) * (wt_d > 0)
-
-
 def _shard_dim(dataset: RandomEffectDataset) -> int:
     top = 0
     for b in dataset.buckets:
         if b.feature_index.size:
             top = max(top, int(b.feature_index.max()) + 1)
     return top
-
-
-def _gather_warm_start(bucket: REBucket, warm: Optional[RandomEffectModel],
-                       shard_dim: int) -> np.ndarray:
-    """Previous sweep's coefficients for each (entity, local feature) slot."""
-    w0 = np.zeros(bucket.feature_index.shape, np.float32)
-    if warm is None or not len(warm.keys):
-        return w0
-    fmask = bucket.feature_index >= 0
-    ent = np.broadcast_to(bucket.entity_ids[:, None],
-                          bucket.feature_index.shape)
-    w0[fmask] = warm.lookup(ent[fmask], bucket.feature_index[fmask])
-    return w0

@@ -125,14 +125,24 @@ class TestCoordinatedOmission:
         assert bench_serving._percentile(run["uncorrected_ms"], 50) < 100.0
 
     def test_unstalled_schedule_keeps_pace(self, stub_server):
+        """A healthy server builds no backlog: every request leaves when
+        its slot comes, so its corrected latency (from the slot) and its
+        uncorrected one (from the send) agree to within a slot. No absolute
+        time: how long an answer takes is the machine's business (the
+        suite runs under six workers), and at 10 requests/s eight senders
+        ride out a stall of most of a second before one is late for that."""
+        qps, requests = 10.0, 20
         run = bench_serving.open_loop_run(
             _base(stub_server), POOL, [1],
-            target_qps=400.0, requests=80, concurrency=8)
+            target_qps=qps, requests=requests, concurrency=8)
         assert not run["errors"]
-        # a healthy server keeps corrected ≈ uncorrected (no backlog)
-        corrected_p99 = bench_serving._percentile(run["corrected_ms"], 99)
-        assert corrected_p99 < 250.0, corrected_p99
-        assert run["achieved_qps"] > 100.0
+        assert len(run["corrected_ms"]) == requests
+        late_ms = [c - u for c, u in zip(run["corrected_ms"],
+                                         run["uncorrected_ms"])]
+        assert min(late_ms) >= 0.0  # nothing leaves before its slot
+        assert max(late_ms) < 1e3 / qps, sorted(late_ms)[-3:]
+        # and the schedule was kept: the run took its slots, not twice them
+        assert run["achieved_qps"] > qps / 2
 
 
 class TestShedClassification:

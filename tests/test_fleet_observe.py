@@ -274,23 +274,55 @@ class TestFleetFold:
 class TestTraceStitching:
     def test_one_request_yields_one_stitched_tree(self, env, tmp_path):
         path = str(tmp_path / "trace.jsonl")
+        closed: list = []  # every record the tracer completes, as it does
+        remove = tracing.GLOBAL_TRACER.add_tap(closed.append)
         tracing.GLOBAL_TRACER.configure(path)
+
+        def siblings_in_tree(rid: str) -> bool:
+            # the request's root closes last: wait for it, however long the
+            # machine takes (the limit is for a root that never closes)
+            limit = time.monotonic() + 300.0
+            root = None
+            while root is None and time.monotonic() < limit:
+                root = next((r for r in closed
+                             if r.get("name") == "fleet.request"
+                             and r.get("request_id") == rid), None)
+                time.sleep(0.01)
+            assert root is not None, "the request's span never closed"
+            fan_out = {r["span_id"] for r in closed
+                       if r.get("name") == "fleet.score"
+                       and r.get("parent_id") == root["span_id"]}
+            return {"primary", "hedge"} <= {
+                r.get("kind") for r in closed
+                if r.get("name") == "fleet.leg"
+                and r.get("parent_id") in fan_out}
+
         try:
-            _post(env["fleet"].url + "/score",
-                  {"records": env["requests"][:16]},
-                  headers={"X-Photon-Request-Id": "obs-rid-1"})
-            # the response returns as soon as the winning leg lands;
-            # give the losing hedge legs a beat to close their spans
-            # before tearing the tracer down
-            time.sleep(0.5)
+            # The 0.05 ms hedge delay fires a backup beside every primary,
+            # and the first answer wins. A leg that answers after the
+            # fan-out has closed is re-parented to the root's level
+            # (tracing.span_under), so which legs the tree shows is the
+            # hosts' race: ask until a primary and a backup both landed
+            # inside the fan-out.
+            for attempt in range(20):
+                rid = f"obs-rid-{attempt}"
+                _post(env["fleet"].url + "/score",
+                      {"records": env["requests"][:16]},
+                      headers={"X-Photon-Request-Id": rid})
+                if siblings_in_tree(rid):
+                    break
+            else:
+                pytest.fail("in none of twenty requests did a primary and "
+                            "a backup both answer inside the fan-out")
         finally:
+            remove()
             tracing.GLOBAL_TRACER.close()
         spans = [json.loads(line) for line in open(path)]
         by_id = {s["span_id"]: s for s in spans
                  if s.get("span_id") is not None}
 
         roots = [s for s in spans if s.get("name") == "fleet.request"
-                 and s.get("request_id") == "obs-rid-1"]
+                 and s.get("request_id") == rid]
         assert len(roots) == 1
         root = roots[0]
         # the ONE request-id-tagged tree: everything reachable from the
@@ -319,7 +351,6 @@ class TestTraceStitching:
                             for s in legs)
         kinds = {s["kind"] for s in legs}
         assert "primary" in kinds
-        # the 0.05 ms hedge delay guarantees the backup fired
         assert "hedge" in kinds
         assert {s["shard"] for s in legs} == {"0", "1"}
         # stitching: winning legs carry the HOST-side span id

@@ -1200,6 +1200,145 @@ class TestDevicePassiveScoring:
                                    rtol=1e-5, atol=1e-6)
 
 
+class TestOneSweepBody:
+    """Resident, streaming and projected datasets run the one sweep body
+    (``random_effect._sweep_fused_impl``): all buckets a program, or a
+    bucket a program."""
+
+    @staticmethod
+    def _solver(variance, **optimizer):
+        from photon_ml_tpu.types import VarianceComputationType
+
+        return RandomEffectSolver(
+            task=TaskType.LOGISTIC_REGRESSION,
+            config=GLMOptimizationConfiguration(
+                optimizer_config=OptimizerConfig(
+                    **{"max_iterations": 40, **optimizer}),
+                regularization=L2Regularization,
+                variance_type=VarianceComputationType[variance]))
+
+    @staticmethod
+    def _datasets(data):
+        resident = RandomEffectDataset.build(
+            "re", data, RandomEffectDatasetConfig("entityId", "re"))
+        streaming = RandomEffectDataset.build(
+            "re", data, RandomEffectDatasetConfig(
+                "entityId", "re", cache_device_buckets=False))
+        assert resident.config.resident and not streaming.config.resident
+        assert len({b.tensor_shape[1:] for b in resident.buckets}) >= 2
+        return resident, streaming
+
+    @pytest.mark.parametrize("variance", ["NONE", "SIMPLE"])
+    @pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+    def test_streaming_sweep_equals_resident_sweep(self, warm, variance):
+        data, _ = make_mixed_data(n=900, n_entities=17)
+        resident, streaming = self._datasets(data)
+        solver = self._solver(variance)
+        offsets = np.random.default_rng(5).normal(size=900).astype(
+            np.float32)
+        start = None
+        if warm:
+            start, _ = solver.train(resident, np.zeros(900, np.float32),
+                                    lam=0.5, dim=4)
+        m_res, s_res = solver.train(resident, offsets, lam=0.5,
+                                    warm_start=start, dim=4)
+        m_str, s_str = solver.train(streaming, offsets, lam=0.5,
+                                    warm_start=start, dim=4)
+        # a streaming sweep has pulled its buckets; a resident one has not
+        assert isinstance(object.__getattribute__(m_str, "coeffs"),
+                          np.ndarray)
+        assert callable(object.__getattribute__(m_res, "coeffs"))
+        np.testing.assert_array_equal(m_str.keys, m_res.keys)
+        np.testing.assert_array_equal(m_str.coeffs, m_res.coeffs)
+        np.testing.assert_array_equal(np.asarray(s_str), np.asarray(s_res))
+        np.testing.assert_array_equal(np.asarray(m_str.coeffs_device),
+                                      np.asarray(m_res.coeffs_device))
+        np.testing.assert_array_equal(np.asarray(m_str.coeffs_device),
+                                      m_str.coeffs)
+        if variance == "NONE":
+            assert m_str.variances is None and m_res.variances is None
+        else:
+            assert (m_res.variances > 0).all()
+            np.testing.assert_array_equal(m_str.variances, m_res.variances)
+
+    @pytest.mark.parametrize("resident", [True, False],
+                             ids=["resident", "streaming"])
+    def test_projected_warm_sweep_starts_from_the_models_lookup(
+            self, resident, monkeypatch):
+        """The solve is faked to hand its start back, so the sweep's model
+        IS what every bucket started from: the warm model's own join, for
+        the entities it has (and zero for the others)."""
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.game import random_effect
+        from photon_ml_tpu.game.model import RandomEffectModel
+        from photon_ml_tpu.game.projector import ProjectorType
+
+        def solve(solver, x, labels, offsets, weights, w0, lam):
+            zero = jnp.zeros((), jnp.int32)
+            return (w0, jnp.zeros((x.shape[0], 0), x.dtype), zero,
+                    {"evaluations": zero})
+
+        monkeypatch.setattr(random_effect, "_solve_bucket_jit", solve)
+        data, _ = make_mixed_data(n=900, n_entities=17)
+        ds = RandomEffectDataset.build(
+            "re", data, RandomEffectDatasetConfig(
+                "entityId", "re", projector_type=ProjectorType.RANDOM,
+                projected_dim=3, cache_device_buckets=resident))
+        assert ds.projector is not None and len(ds.buckets) >= 2
+        assert ds.config.resident == resident
+        assert not ds.config.reads_shared_image
+        entities = np.array([0, 2, 3, 5, 11, 16])
+        keys = (entities[:, None] * 3 + np.arange(3)[None, :]).ravel()
+        warm = RandomEffectModel(
+            "entityId", "re", TaskType.LOGISTIC_REGRESSION, 3, keys,
+            np.random.default_rng(1).normal(size=len(keys)).astype(
+                np.float32), projector=ds.projector)
+        # a configuration of this test's own: the sweep's compiled programs
+        # are kept by solver, and no other test may meet the faked one
+        solver = self._solver("NONE", max_iterations=39, tolerance=3e-7)
+        model, _ = solver.train(ds, np.zeros(900, np.float32), lam=0.5,
+                                warm_start=warm)
+        started = 0
+        for bucket in ds.buckets:
+            ent = np.broadcast_to(bucket.entity_ids[:, None],
+                                  bucket.feature_index.shape)
+            want = warm.lookup(ent, bucket.feature_index)
+            np.testing.assert_array_equal(
+                model.lookup(ent, bucket.feature_index), want)
+            started += np.count_nonzero(want)
+        assert started == len(keys)
+
+    def test_streaming_sweep_records_a_solve_span_a_bucket(self):
+        from photon_ml_tpu.telemetry import tracing
+
+        data, _ = make_mixed_data(n=900, n_entities=17)
+        solver = self._solver("NONE")
+        records = []
+        remove = tracing.GLOBAL_TRACER.add_tap(records.append)
+        try:
+            for ds in self._datasets(data):
+                with tracing.span("cd.step", coordinate=ds.coordinate_id,
+                                  resident=ds.config.resident):
+                    solver.train(ds, np.zeros(900, np.float32), lam=0.5,
+                                 dim=4)
+            tracing.flush()
+        finally:
+            remove()
+        steps = {r["resident"]: r for r in records if r["name"] == "cd.step"}
+        spans = [r for r in records if r["name"] == "game.re.solve"]
+        n_buckets = len(ds.buckets)
+        assert [s["bucket"] for s in spans] == 2 * list(range(n_buckets))
+        per_mode = (spans[:n_buckets], spans[n_buckets:])
+        for resident, mine in zip((True, False), per_mode):
+            assert steps[resident]["evaluations"] == sum(
+                s["evaluations"] for s in mine) > 0
+        clock = ("t0", "t1", "ts", "seconds", "span_id", "parent_id")
+        strip = lambda r: {k: v for k, v in r.items() if k not in clock}
+        assert [strip(s) for s in per_mode[1]] \
+            == [strip(s) for s in per_mode[0]]
+
+
 class TestMidRunResume:
     def test_resume_from_intermediate_checkpoint_matches_uninterrupted(
             self, tmp_path):
