@@ -162,11 +162,20 @@ def path_table(out: str, seen: dict) -> tuple[dict, list]:
         lanes = sum(x.shape[0] for x in designs)
         cid = min(entities, key=lambda c: abs(entities[c] - lanes))
         check(cid not in table, f"two sweep programs matched {cid}")
-        kernels = {s[1:] for s in mosaic_design_shapes(compiled)
+        # the entity kernel takes a bucket entities-last, (D, S', E'), S'
+        # the rows padded to the design's sublane tile
+        # (ops/pallas_re.py::entity_layout); a coordinate's buckets differ
+        # in S by far more than a tile
+        kernels = {s[:2] for s in mosaic_design_shapes(compiled)
                    if len(s) == 3}
+
+        def laid(x):
+            tile = 32 // x.dtype.itemsize
+            return x.shape[2], -(-x.shape[1] // tile) * tile
+
         table[cid] = {
             f"{x.dtype.name}[{x.shape[1]},{x.shape[2]}]":
-                "pallas" if tuple(x.shape[1:]) in kernels else "xla"
+                "pallas" if laid(x) in kernels else "xla"
             for x in designs}
         buckets += [tuple(x.shape) for x in designs]
     return table, buckets

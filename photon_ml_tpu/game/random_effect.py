@@ -107,8 +107,9 @@ class RandomEffectSolver:
     #: f32 via preferred_element_type)
     design_dtype: str = "float32"
     #: engage the single-pass Pallas entity kernel inside the bucket solves
-    #: (ops/pallas_re.py): each L-BFGS evaluation then reads the (E, S, D)
-    #: design ONCE instead of XLA's margins-then-gradient double pass.
+    #: (ops/pallas_re.py): each L-BFGS evaluation then reads the bucket's
+    #: design ONCE, laid entities-last once a solve, instead of XLA's
+    #: margins-then-gradient double pass.
     #: Inert off-TPU (without ``fused_interpret``) and for lanes whose
     #: ``(S, D)`` the kernel's gate declines (VMEM-oversized ones) — those
     #: keep the XLA closed form transparently, same gate discipline as the
@@ -629,13 +630,14 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     def batch(x, labels, offsets, weights, w0, lam):
         # Pre-pad the entity batch to the Pallas kernel's block plan with
         # weight-0 lanes (zero data ⇒ gradient = L2 at w0=0 = 0: they
-        # converge immediately, exactly like _put's mesh padding). Padding
-        # INSIDE the traced objective instead would copy the full
-        # (E, S, D) design on every L-BFGS evaluation — the measured
-        # regression pallas_glm's auto mode exists to avoid. Zero when the
+        # converge immediately, exactly like _put's mesh padding), so the
+        # flat loop's (d, E) arrays are as wide as the kernel's operands
+        # and the kernel takes its iterate as it is. run_lanes lays the
+        # padded bucket entities-last, once, before its loop (the pad
+        # fuses into that one copy of the design). Zero when the
         # objective's gate keeps the XLA closed form or the plan already
         # divides; under shard_map this runs per shard, so each device
-        # pads its own slice.
+        # pads and lays out its own slice.
         e_real = x.shape[0]
         pad = objective.entity_pad(x)
         if pad:

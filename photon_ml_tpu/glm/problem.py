@@ -38,7 +38,10 @@ from photon_ml_tpu.optimize import (
     minimize_owlqn,
     minimize_tron,
 )
-from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs_lanes
+from photon_ml_tpu.optimize.lbfgs import (
+    minimize_lbfgs_lanes,
+    vmapped_evaluation,
+)
 from photon_ml_tpu.types import OptimizerType, VarianceComputationType
 
 Array = jax.Array
@@ -113,16 +116,23 @@ class OptimizationProblem:
         calls this, not ``vmap(run)``: an L-BFGS batch then runs the flat
         loop (:func:`minimize_lbfgs_lanes`: one evaluation a lane a trip)
         and the second value is its ``passes``, the batched evaluations it
-        made. OWL-QN and TRON batches are ``vmap(run)``, whose nested loops
-        count no passes: ``None``.
+        made. The loop is given the batch's evaluation, lanes last as it
+        holds them: the entity kernel's where the objective's gate says it
+        serves such lanes (by their ``(S, D)`` and dtype), else the
+        objective under ``vmap``. OWL-QN and TRON batches are ``vmap(run)``,
+        whose nested loops count no passes: ``None``.
         """
         if (self.config.optimizer == OptimizerType.TRON
                 or self.config.regularization.has_l1):
             return jax.vmap(self.run, in_axes=(0, 0, None))(
                 data, w0, lam), None
         _, l2 = self._split(lam)
-        fun = lambda lane, w: self.objective.value_and_grad(w, lane, l2)
-        return minimize_lbfgs_lanes(fun, data, w0,
+        evaluate = self.objective.entity_kernel_evaluation(data, l2)
+        if evaluate is None:
+            evaluate = vmapped_evaluation(
+                lambda lane, w: self.objective.value_and_grad(w, lane, l2),
+                data)
+        return minimize_lbfgs_lanes(evaluate, w0,
                                     self.config.optimizer_config)
 
     # --- variance (reference VarianceComputationType SIMPLE / FULL) -------

@@ -114,10 +114,11 @@ class GLMObjective:
     #: autodiff transparently). See photon_ml_tpu/ops/pallas_glm.py.
     fused: bool = False
     #: entity-batched variant of ``fused`` (the random-effect bucket solve):
-    #: under a vmap carrying the batch axis on every operand, dispatch the
-    #: single-pass (E, S, D) Pallas kernel (ops/pallas_re.py). A separate
-    #: switch because eligibility differs — per-entity designs are small, so
-    #: the gate is the ENTITY block plan (lane_fits_vmem), not
+    #: a batch of lanes runs the single-pass entities-last Pallas kernel
+    #: (ops/pallas_re.py), through :meth:`entity_kernel_evaluation` (an
+    #: L-BFGS bucket) or a vmap carrying the batch axis on every operand. A
+    #: separate switch because eligibility differs — per-entity designs are
+    #: small, so the gate is the ENTITY block plan (lane_fits_vmem), not
     #: auto_block_rows over the sample dim. Set by RandomEffectSolver; the
     #: two flags are not meant to be combined.
     fused_entity: bool = False
@@ -224,8 +225,9 @@ class GLMObjective:
 
         reason = self._kernel_decline(design)
         if reason is None and not lane_fits_vmem(s, d, design.x.dtype):
-            reason = (f"an 8-entity block of {jnp.dtype(design.x.dtype).name}"
-                      f" ({s}, {d}) lanes exceeds the kernel's VMEM budget")
+            reason = (f"a 128-entity block of "
+                      f"{jnp.dtype(design.x.dtype).name} ({s}, {d}) lanes "
+                      f"exceeds the kernel's VMEM budget")
         if reason is not None:
             _log_declined("pallas_re", reason)
         return reason is None
@@ -242,6 +244,41 @@ class GLMObjective:
 
         return entity_pad(e, s, d, x.dtype)
 
+    def entity_kernel_evaluation(self, data: GLMData, l2=0.0):
+        """The value and gradient of every lane of a batch in one call,
+        ``w (d, E) -> (values (E,), grads (d, E))``, lanes last as the flat
+        L-BFGS loop holds them (optimize/lbfgs.py), through the entity
+        kernel; ``None`` where the gate keeps the closed form for such
+        lanes. ``data``'s leaves lead with the lane axis. The kernel's
+        operands are laid out here, once: the returned function closes over
+        them, so a loop that calls it reads the design once an evaluation
+        and copies nothing. A batch the solver has not padded to the block
+        plan (:meth:`entity_pad`) is served too, at a pad of ``w`` a call.
+        """
+        design = data.design
+        if not self._entity_kernel_serves(design, data.labels.shape[-1],
+                                          design.dim):
+            return None
+        from photon_ml_tpu.ops.pallas_re import (
+            entity_layout,
+            entity_value_and_grad_lanes,
+        )
+
+        laid = entity_layout(design.x, data.labels, data.offsets,
+                             data.weights)
+        interpret = jax.default_backend() != "tpu"
+        mask = None if self.reg_mask is None else self.reg_mask[:, None]
+
+        def evaluate(w: Array) -> tuple[Array, Array]:
+            values, grads = entity_value_and_grad_lanes(
+                self.loss, *laid, w, interpret=interpret)
+            wr = w if mask is None else w * mask
+            reg = jnp.asarray(l2, values.dtype)
+            return (values + 0.5 * reg * jnp.sum(wr * wr, axis=0),
+                    grads + reg * wr)
+
+        return evaluate
+
     def value_and_grad(self, w: Array, data: GLMData, l2=0.0) -> tuple[Array, Array]:
         if self._entity_kernel_serves(data.design, data.n_samples,
                                       data.dim):
@@ -249,9 +286,9 @@ class GLMObjective:
                 vmappable_entity_value_and_grad,
             )
 
-            # custom-vmap wrapper: the bucket solve's all-operands vmap
-            # dispatches the single-pass entity kernel; called unbatched it
-            # is the closed form (identical math, one lane)
+            # custom-vmap wrapper: an all-operands vmap (an OWL-QN or TRON
+            # bucket's) dispatches the single-pass entity kernel; called
+            # unbatched it is the closed form (identical math, one lane)
             vag = vmappable_entity_value_and_grad(
                 self.loss, jax.default_backend() != "tpu")
             value, grad = vag(data.design.x, w, data.labels, data.offsets,

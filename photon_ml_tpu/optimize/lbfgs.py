@@ -378,13 +378,41 @@ def _trip(fun, lanes: _Lanes, tol: Array, config: OptimizerConfig) -> _Lanes:
     )
 
 
-def minimize_lbfgs_lanes(fun: Callable[[Any, Array], tuple[Array, Array]],
-                         lanes: Any, w0: Array,
+def lanes_last(a: Array) -> Array:
+    """``(E, d) -> (d, E)``, column by column, not ``.T``: the TPU compiler
+    folds a transpose into the layouts on either side, and an ``(E, d)``
+    layout (d in the 128-lane dimension) then spreads to every array of the
+    loop."""
+    return jnp.stack([a[:, k] for k in range(a.shape[1])])
+
+
+def lanes_first(a: Array) -> Array:
+    """``(d, E) -> (E, d)``: :func:`lanes_last` back."""
+    return jnp.stack([a[k] for k in range(a.shape[0])], axis=1)
+
+
+def vmapped_evaluation(fun: Callable[[Any, Array], tuple[Array, Array]],
+                       lanes: Any) -> Callable[[Array], tuple[Array, Array]]:
+    """The evaluation :func:`minimize_lbfgs_lanes` takes, from a per-lane
+    ``fun(lane, w (d,)) -> (value, grad (d,))`` and ``lanes``, a pytree whose
+    leaves lead with the lane axis: ``vmap(fun)`` between two column stacks,
+    paid at every trip. (A batch whose objective evaluates lanes-last
+    arrays itself hands that over and pays neither.)"""
+    def evaluate(w):  # (d, E) -> (E,), (d, E)
+        f, g = jax.vmap(fun)(lanes, lanes_first(w))
+        return f, lanes_last(g)
+    return evaluate
+
+
+def minimize_lbfgs_lanes(evaluate: Callable[[Array], tuple[Array, Array]],
+                         w0: Array,
                          config: OptimizerConfig = OptimizerConfig()
                          ) -> tuple[OptimizerResult, Array]:
-    """Minimize ``fun(lane, .)`` from ``w0[e]`` for every lane ``e`` of a
-    batch (``lanes``: a pytree whose leaves lead with the lane axis, as
-    ``w0`` ``(E, d)`` does): the flat form (module docstring).
+    """Minimize every lane of a batch from ``w0[e]`` (``w0``: ``(E, d)``),
+    given the batch's evaluation ``w (d, E) -> (values (E,), grads (d, E))``
+    on arrays that carry the lanes last, in which a lane's value and
+    gradient depend on its own column alone: the flat form (module
+    docstring).
 
     Returns the lanes' results (every field leads with the lane axis), each
     what the loop gives that lane alone, bit for bit, and what
@@ -395,17 +423,6 @@ def minimize_lbfgs_lanes(fun: Callable[[Any, Array], tuple[Array, Array]],
     lane.
     """
     m, (n_lanes, d) = config.history, w0.shape
-
-    # Column by column, not ``.T``: the TPU compiler folds a transpose into
-    # the layouts on either side, and the objective's ``(E, d)`` layout (d in
-    # the 128-lane dimension) then spreads to every array of the loop.
-    lanes_last = lambda a: jnp.stack([a[:, k] for k in range(d)])
-    lanes_first = lambda a: jnp.stack([a[k] for k in range(d)], axis=1)
-
-    def evaluate(w):  # (d, E) -> (E,), (d, E)
-        f, g = jax.vmap(fun)(lanes, lanes_first(w))
-        return f, lanes_last(g)
-
     w = lanes_last(w0)
     f0, g0 = evaluate(w)
     gnorm0 = _norm(g0)

@@ -78,27 +78,34 @@ class TestCompileCache:
 @pytest.mark.parametrize("s,d", [(8, 4), (16, 8), (95, 7), (174, 8),
                                  (600, 8), (33, 200)])
 def test_entity_plan_counts_what_the_kernel_allocates(s, d, dtype):
-    """The plan's bytes per entity cover the kernel's double-buffered
-    operands plus its slab-sized f32 temporaries (two rank-3 products, and
-    the upcast of a bf16 slab), by Mosaic's tile rules — re-derived here —
-    and the planned block stays inside the 16 MiB scoped limit."""
+    """The plan's bytes per entity are the kernel's double-buffered operand
+    blocks with the entities in the lane dimension, by Mosaic's tile rules
+    (re-derived here for a block of 128 lanes: every tile is full), and the
+    planned block, with what the body keeps live, stays inside the 16 MiB
+    scoped limit."""
     from photon_ml_tpu.ops import pallas_re
 
-    def tiles(rows, cols, itemsize):
+    def tiles(rows, itemsize):  # a (rows, 128 lanes) array's bytes
         sublane = 8 * 4 // itemsize
-        return (-(-rows // sublane) * sublane) * (-(-cols // 128) * 128) \
-            * itemsize
+        return (-(-rows // sublane) * sublane) * 128 * itemsize
 
     itemsize = jnp.dtype(dtype).itemsize
-    # per entity: x (S, D) stored; labels/offsets/weights (1, S) f32 rows of
-    # a (BE, S) block; w, grad (1, D) and value (1, 1) rows of (BE, .) blocks
-    operands = (tiles(s, d, itemsize) + 3 * tiles(8, s, 4) // 8
-                + 2 * tiles(8, d, 4) // 8 + tiles(8, 1, 4) // 8)
-    temporaries = (2 if itemsize == 4 else 3) * tiles(s, d, 4)
+    # the layout pads a lane's rows to the stored design's row tile
+    rows = -(-s // (32 // itemsize)) * (32 // itemsize)
+    # per 128 entities: x (D, S, 128) stored; labels/offsets/weights
+    # (S, 128) f32; w and grad (D, 128) f32; the value (1, 128) f32
+    operands = (d * tiles(rows, itemsize) + 3 * tiles(rows, 4)
+                + 2 * tiles(d, 4) + tiles(1, 4))
     per_entity = pallas_re._entity_bytes(s, d, dtype)
-    assert per_entity >= 2 * operands + temporaries
+    assert per_entity * 128 == 2 * operands
     block, _ = pallas_re.entity_plan(10**6, s, d, dtype)
-    assert block * per_entity <= pallas_re.VMEM_BUDGET_BYTES < 16 << 20
+    assert block % 128 == 0
+    # the body's tiles: D upcast columns, D coefficients, D + 1 sums at the
+    # least, each a row tile by 128 lanes of float32
+    body = pallas_re._body_bytes(d, dtype)
+    assert body >= (3 * d + 1) * tiles(32 // itemsize, 4)
+    assert block * per_entity + body \
+        <= pallas_re.VMEM_BUDGET_BYTES < 16 << 20
 
 
 def test_a_declining_gate_says_which_predicate(caplog):
@@ -121,7 +128,7 @@ def test_a_declining_gate_says_which_predicate(caplog):
     said = [r.getMessage() for r in caplog.records]
     assert said == [
         "pallas_glm declined: backend is 'cpu', not 'tpu' — XLA closed form",
-        "pallas_re declined: an 8-entity block of float32 (4096, 256) lanes "
+        "pallas_re declined: a 128-entity block of float32 (4096, 256) lanes "
         "exceeds the kernel's VMEM budget — XLA closed form"]
 
 

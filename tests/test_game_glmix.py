@@ -289,10 +289,10 @@ def test_a_buckets_counts_are_its_lanes_results_summed(kernel):
     w, _, converged, counts = jax.jit(
         _solve_bucket_impl, static_argnames="solver")(solver, *args, w0, lam)
     problem_ = solver._problem()
-    # the kernel's block plan pads this bucket by one lane; the closed form
-    # pads nothing
+    # the kernel's block plan pads this bucket to its 128 lanes; the closed
+    # form pads nothing
     assert problem_.objective.entity_pad(args[0]) \
-        == (kernel == "pallas_interpreter")
+        == (128 - lanes if kernel == "pallas_interpreter" else 0)
     data = GLMData(design=DenseDesign(x=args[0]), labels=args[1],
                    offsets=args[2], weights=args[3])
     per_lane, passes = jax.jit(problem_.run_lanes)(data, w0, lam)
@@ -319,3 +319,67 @@ def test_a_buckets_counts_are_its_lanes_results_summed(kernel):
         == float(rows @ np.asarray(per_lane.evaluations[real]))
     assert len(set(np.asarray(per_lane.evaluations[real]))) >= 3
     assert np.all(np.asarray(per_lane.iterations[5:]) == 0)
+
+
+@pytest.mark.parametrize("design_dtype", ["float32", "bfloat16"])
+def test_the_kernels_bucket_solve_is_the_closed_forms_lane_for_lane(
+        design_dtype):
+    """A bucket's solve through the entities-last kernel (the interpreter)
+    against the same solve on the closed-form route, lane by lane. The flat
+    loop is the same and its rules are; the kernel sums a lane's rows and
+    columns in another order, which moves last bits of values and gradients.
+    So at a tolerance that ends every lane above float32's floor (1e-3: a
+    gradient of about sqrt(2 x l2 x ulp(value)), some 1e-3 here, is what the
+    Armijo search on function values leaves; at 1e-4 a tenth of these lanes
+    end there, other lanes on either route) each lane converges in as many
+    iterations and evaluations on either route,
+    to the same coefficients by tolerance; and either way the bucket's
+    ``passes`` are its slowest lane's evaluations. 140 lanes: two blocks of
+    128, the second mostly padding."""
+    from photon_ml_tpu.game.random_effect import RandomEffectSolver
+    from photon_ml_tpu.ops.design import DenseDesign
+    from photon_ml_tpu.ops.objective import GLMData
+
+    rng = np.random.default_rng(31)
+    lanes, s, d = 140, 21, 5
+    dtype = jnp.dtype(design_dtype)
+    x = np.asarray(jnp.asarray(rng.normal(size=(lanes, s, d)), dtype)
+                   .astype(jnp.float32))
+    rows = rng.integers(1, s + 1, size=lanes)
+    rows[[3, 77]] = 0  # lanes that weigh nothing
+    rows[5] = 1
+    weights = (np.arange(s)[None, :] < rows[:, None]).astype(np.float32)
+    x = x * weights[:, :, None]
+    planted = rng.normal(size=(lanes, d))
+    p = 1.0 / (1.0 + np.exp(-np.einsum("esd,ed->es", x, planted)))
+    y = (rng.random((lanes, s)) < p).astype(np.float32)
+    offsets = rng.normal(size=(lanes, s)).astype(np.float32) * weights
+    data = GLMData(design=DenseDesign(x=jnp.asarray(x, dtype)),
+                   labels=jnp.asarray(y), offsets=jnp.asarray(offsets),
+                   weights=jnp.asarray(weights))
+    w0, lam = jnp.zeros((lanes, d), jnp.float32), jnp.float32(1.0)
+
+    def solve(**route):
+        problem_ = RandomEffectSolver(
+            task=TaskType.LOGISTIC_REGRESSION,
+            config=GLMOptimizationConfiguration(
+                regularization=L2Regularization,
+                optimizer_config=OptimizerConfig(
+                    max_iterations=25, tolerance=1e-3, track_states=False)),
+            design_dtype=design_dtype, **route)._problem()
+        kernel = problem_.objective.entity_kernel_evaluation(data, lam)
+        assert (kernel is not None) == route.get("fused_interpret", False)
+        return jax.jit(problem_.run_lanes)(data, w0, lam)
+
+    (kernel, kernel_passes) = solve(fused_interpret=True)
+    (closed, closed_passes) = solve(fused=False)
+    assert np.asarray(kernel.converged).all()
+    for name in ("iterations", "evaluations", "converged"):
+        np.testing.assert_array_equal(getattr(kernel, name),
+                                      getattr(closed, name), err_msg=name)
+    np.testing.assert_allclose(kernel.w, closed.w, rtol=1e-3, atol=1e-5)
+    np.testing.assert_allclose(kernel.value, closed.value, rtol=1e-5)
+    evaluations = np.asarray(kernel.evaluations)
+    assert (evaluations[[3, 77]] == 1).all()
+    assert len(set(evaluations)) >= 4
+    assert int(kernel_passes) == int(closed_passes) == evaluations.max()

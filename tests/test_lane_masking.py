@@ -29,7 +29,10 @@ from photon_ml_tpu.optimize import (
     minimize_owlqn,
     minimize_tron,
 )
-from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs_lanes
+from photon_ml_tpu.optimize.lbfgs import (
+    minimize_lbfgs_lanes,
+    vmapped_evaluation,
+)
 
 E, S, D = 6, 40, 4
 CONFIG = OptimizerConfig(max_iterations=25, tolerance=1e-9,
@@ -277,7 +280,7 @@ def _floor_config(dtype):
 
 
 def _lane_fun(counter):
-    """``fun(lane, w)`` of ``minimize_lbfgs_lanes`` over ``_objective``, the
+    """``fun(lane, w)`` of ``vmapped_evaluation`` over ``_objective``, the
     value kept in the lanes' dtype (the counter's zero is a float64)."""
     def fun(lane, w):
         f, g = _objective(counter, *lane)[0](w)
@@ -292,8 +295,8 @@ def _zeros(lanes):
 def _flat(lanes, config, counter=None):
     """``(result, passes)`` of the flat loop from zero."""
     fun = _lane_fun(counter or Counter())
-    out = jax.jit(lambda l, w: minimize_lbfgs_lanes(fun, l, w, config))(
-        lanes, _zeros(lanes))
+    out = jax.jit(lambda l, w: minimize_lbfgs_lanes(
+        vmapped_evaluation(fun, l), w, config))(lanes, _zeros(lanes))
     return jax.block_until_ready(out)
 
 

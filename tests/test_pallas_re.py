@@ -5,9 +5,10 @@ the same opt-in the pallas_glm tests use); the TPU speedup claim lives in
 the ``-m slow`` lane. The load-bearing contracts:
 
 - **kernel correctness**: single-pass (values, grads) match the closed
-  form per entity, f32 and bf16 designs, ragged weight-0 padding included;
+  form per entity, f32 and bf16 designs, ragged weight-0 padding included,
+  and no lane's depend on its neighbours in the block;
 - **engagement**: ``RandomEffectSolver(fused=True, fused_interpret=True)``
-  trains through the kernel (the custom_vmap all-batched rule) and lands
+  trains through the kernel (the flat loop's lanes-last evaluation) and lands
   within tolerance of the XLA ``_solve_bucket`` path — and with
   ``fused=True`` but NO interpreter on CPU the gate is inert, producing
   BIT-identical output to ``fused=False`` (the default-flip safety net);
@@ -62,14 +63,19 @@ def _batch(e, s, d, seed=0, dtype=np.float32, dead_frac=0.3):
     return x, w, y, off, wt
 
 
+def _kernel(x, w, y, off, wt):
+    return pallas_re.fused_entity_value_and_grad(
+        LogisticLoss, jnp.asarray(x), jnp.asarray(w), jnp.asarray(y),
+        jnp.asarray(off), jnp.asarray(wt), interpret=True)
+
+
 class TestKernel:
+    # entity counts under one 128-lane block, and over it by no multiple
     @pytest.mark.parametrize("e,s,d", [(13, 11, 5), (8, 16, 4), (40, 7, 3),
-                                       (1, 5, 2)])
+                                       (1, 5, 2), (130, 9, 3), (300, 8, 8)])
     def test_matches_closed_form_f32(self, e, s, d):
         x, w, y, off, wt = _batch(e, s, d, seed=e)
-        vals, grads = pallas_re.fused_entity_value_and_grad(
-            LogisticLoss, jnp.asarray(x), jnp.asarray(w), jnp.asarray(y),
-            jnp.asarray(off), jnp.asarray(wt), interpret=True)
+        vals, grads = _kernel(x, w, y, off, wt)
         assert vals.shape == (e,) and grads.shape == (e, d)
         for i in range(e):
             rv, rg = _ref_value_and_grad(x[i], w[i], y[i], off[i], wt[i])
@@ -78,13 +84,10 @@ class TestKernel:
             np.testing.assert_allclose(np.asarray(grads[i]), rg, rtol=1e-4,
                                        atol=1e-5)
 
-    def test_bf16_design_accumulates_f32(self):
-        e, s, d = 10, 9, 6
+    @pytest.mark.parametrize("e,s,d", [(10, 9, 6), (131, 33, 4)])
+    def test_bf16_design_accumulates_f32(self, e, s, d):
         xf, w, y, off, wt = _batch(e, s, d, seed=3)
-        vals, grads = pallas_re.fused_entity_value_and_grad(
-            LogisticLoss, jnp.asarray(xf, jnp.bfloat16), jnp.asarray(w),
-            jnp.asarray(y), jnp.asarray(off), jnp.asarray(wt),
-            interpret=True)
+        vals, grads = _kernel(jnp.asarray(xf, jnp.bfloat16), w, y, off, wt)
         assert vals.dtype == jnp.float32 and grads.dtype == jnp.float32
         x16 = np.asarray(jnp.asarray(xf, jnp.bfloat16).astype(jnp.float32))
         for i in range(e):
@@ -99,29 +102,85 @@ class TestKernel:
         x, w, y, off, wt = _batch(6, 5, 3, seed=9)
         wt[2] = 0.0
         x[2] = 0.0
-        vals, grads = pallas_re.fused_entity_value_and_grad(
-            LogisticLoss, jnp.asarray(x), jnp.asarray(w), jnp.asarray(y),
-            jnp.asarray(off), jnp.asarray(wt), interpret=True)
+        vals, grads = _kernel(x, w, y, off, wt)
         assert float(vals[2]) == 0.0
         assert not np.asarray(grads[2]).any()
 
+    def test_a_lane_of_one_real_row(self):
+        x, w, y, off, wt = _batch(6, 19, 3, seed=4, dead_frac=0.0)
+        wt[3, 1:] = 0.0
+        x[3, 1:] = 0.0
+        off[3, 1:] = 0.0
+        vals, grads = _kernel(x, w, y, off, wt)
+        rv, rg = _ref_value_and_grad(x[3, :1], w[3], y[3, :1], off[3, :1],
+                                     wt[3, :1])
+        np.testing.assert_allclose(float(vals[3]), rv, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(grads[3]), rg, rtol=1e-5,
+                                   atol=1e-7)
+
+    @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+    def test_a_lane_does_not_depend_on_its_neighbours(self, dtype):
+        """Bit for bit: a lane in a batch of 300 (its third block of 128
+        lanes, among lanes of other data) against the lane alone (lane 0 of
+        a block whose other lanes are padding). The entities are the
+        kernel's last axis and every operation on it is elementwise."""
+        x, w, y, off, wt = _batch(300, 21, 5, seed=2)
+        x = np.asarray(jnp.asarray(x, dtype))
+        vals, grads = _kernel(x, w, y, off, wt)
+        for i in (0, 127, 128, 299):
+            one = slice(i, i + 1)
+            v1, g1 = _kernel(x[one], w[one], y[one], off[one], wt[one])
+            assert np.array_equal(np.asarray(v1), np.asarray(vals[one]))
+            assert np.array_equal(np.asarray(g1), np.asarray(grads[one]))
+
+    def test_operands_not_laid_out_are_refused(self):
+        """The lanes-last entry takes ``entity_layout``'s operands, whose
+        width is the block plan's: it copies nothing, so it pads nothing."""
+        x, w, y, off, wt = _batch(13, 11, 5)
+        laid = pallas_re.entity_layout(*(jnp.asarray(a)
+                                         for a in (x, y, off, wt)))
+        assert laid[0].shape == (5, 16, 128) and laid[1].shape == (16, 128)
+        with pytest.raises(ValueError, match="not entity_layout's"):
+            pallas_re.entity_value_and_grad_lanes(
+                LogisticLoss, *(a[..., :100] for a in laid),
+                jnp.zeros((5, 100)), interpret=True)
+        # coefficients for the bucket's 13 lanes alone are taken as they are
+        vals, grads = pallas_re.entity_value_and_grad_lanes(
+            LogisticLoss, *laid, jnp.asarray(w).T, interpret=True)
+        assert vals.shape == (13,) and grads.shape == (5, 13)
+
 
 class TestPlan:
-    def test_plan_idempotent_on_its_own_padding(self):
-        for (e, s, d) in [(13, 11, 5), (1000, 64, 8), (7, 3, 1),
-                          (8, 200, 40)]:
-            plan = pallas_re.entity_plan(e, s, d, jnp.float32)
-            assert plan is not None
-            be, e_pad = plan
-            assert be % pallas_re.ENTITY_TILE == 0
-            assert e_pad % be == 0 and e_pad >= e
-            assert pallas_re.entity_plan(e_pad, s, d, jnp.float32) == plan
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("e,s,d", [(13, 11, 5), (1000, 64, 8), (7, 3, 1),
+                                       (8, 200, 40), (168301, 56, 8),
+                                       (56309, 141, 8)])
+    def test_plan_idempotent_on_its_own_padding(self, e, s, d, dtype):
+        plan = pallas_re.entity_plan(e, s, d, dtype)
+        assert plan is not None
+        be, e_pad = plan
+        assert be % pallas_re.ENTITY_TILE == 0 and pallas_re.ENTITY_TILE == 128
+        assert e_pad % be == 0 and e <= e_pad < e + be
+        assert pallas_re.entity_plan(e_pad, s, d, dtype) == plan
+        assert be * pallas_re._entity_bytes(s, d, dtype) \
+            + pallas_re._body_bytes(d, dtype) \
+            <= pallas_re.VMEM_BUDGET_BYTES < 16 << 20
 
     def test_oversized_lane_is_ineligible(self):
-        # one entity's padded slab alone exceeds the block budget
+        # 128 entities' padded slabs alone exceed the block budget
         assert pallas_re.entity_plan(100, 2048, 256, jnp.float32) is None
         assert not pallas_re.lane_fits_vmem(2048, 256, jnp.float32)
         assert pallas_re.entity_pad(100, 2048, 256, jnp.float32) == 0
+
+    @pytest.mark.parametrize("s,fits", [(56, True), (141, True),
+                                        (1096, True), (1104, False),
+                                        (2151, False)])
+    def test_the_gate_divides_lanes_of_8_columns_by_their_rows(self, s, fits):
+        """At a block of 128 lanes the longest float32 lane of 8 columns is
+        1,096 rows: the benchmark cell's buckets of 56 and 141 rows run the
+        kernel, those of 1,104 and longer keep the closed form, as before
+        the entities went last (PERF.md, PR 31)."""
+        assert pallas_re.lane_fits_vmem(s, 8, jnp.float32) == fits
 
     def test_pad_matches_plan(self):
         for (e, s, d) in [(13, 11, 5), (64, 16, 4)]:
@@ -138,9 +197,7 @@ class TestCustomVmap:
         vals_v, grads_v = jax.vmap(vag)(
             jnp.asarray(x), jnp.asarray(w), jnp.asarray(y),
             jnp.asarray(off), jnp.asarray(wt))
-        vals_k, grads_k = pallas_re.fused_entity_value_and_grad(
-            LogisticLoss, jnp.asarray(x), jnp.asarray(w), jnp.asarray(y),
-            jnp.asarray(off), jnp.asarray(wt), interpret=True)
+        vals_k, grads_k = _kernel(x, w, y, off, wt)
         assert np.array_equal(np.asarray(vals_v), np.asarray(vals_k))
         assert np.array_equal(np.asarray(grads_v), np.asarray(grads_k))
 
@@ -157,7 +214,7 @@ class TestCustomVmap:
 
 
 def _re_problem(n=3000, n_ent=41, d=4, seed=3):
-    """41 entities: deliberately NOT a multiple of the 8-entity tile, so
+    """41 entities: deliberately NOT a multiple of the 128-entity tile, so
     the solver's pre-pad path is always exercised."""
     rng = np.random.default_rng(seed)
     xr = rng.normal(size=(n, d)).astype(np.float32)
@@ -207,7 +264,7 @@ class TestSolverEngagement:
     def test_inert_gate_is_bit_identical_on_cpu(self):
         """fused=True (the DEFAULT) without the interpreter on CPU must
         change nothing, bit for bit — the production fallback contract
-        (projected/streaming datasets and non-TPU backends keep XLA)."""
+        (non-TPU backends keep XLA)."""
         data = _re_problem()
         off = np.zeros(data.n_samples, np.float32)
         ma, sa = _solver().train(_dataset(data), off, 1.0)
