@@ -128,11 +128,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "lambda sweep, the reference's ModelTraining "
                         "semantics — fastest for DENSE designs (fused "
                         "kernel + warm starts). batched: one vmapped solve "
-                        "over all lambdas — measured 1.7x faster for wide "
-                        "CHUNKED-SPARSE designs (the per-iteration gather "
-                        "is shared across lambda lanes), 0.6x on dense; "
-                        "see glm/training.py::train_glm_sweep_batched for "
-                        "the measurement table")
+                        "over all lambdas — the lanes share a wide "
+                        "CHUNKED-SPARSE design's index traffic but run in "
+                        "lockstep without warm starts; see "
+                        "glm/training.py::train_glm_sweep_batched")
     p.add_argument("--multihost", action="store_true",
                    help="form a multi-controller job before touching any "
                         "device (jax.distributed.initialize from PHOTON_* "

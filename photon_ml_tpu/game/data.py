@@ -485,8 +485,8 @@ class FixedEffectDataset:
         if isinstance(host_design, DenseDesign):
             design = DenseDesign(x=jnp.asarray(host_design.x, dtype))
         else:
-            # single-chip wide-sparse: the chunked dual layout (measured
-            # ~20x the CsrDesign segment_sum/scatter path on TPU — see
+            # single-chip wide-sparse: the chunked dual layout (gathers and
+            # chunk sums where CsrDesign scatters every entry — see
             # ops/design.py::ChunkedSparseDesign)
             from photon_ml_tpu.ops.design import ChunkedSparseDesign
 

@@ -29,6 +29,7 @@ import numpy as np
 from photon_ml_tpu.evaluation import EvaluationResults, Evaluator, evaluate_all
 from photon_ml_tpu.glm.problem import GLMOptimizationConfiguration, OptimizationProblem
 from photon_ml_tpu.models import Coefficients, GeneralizedLinearModel
+from photon_ml_tpu.ops.design import design_kind
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.normalization import NormalizationContext, NoNormalization
 from photon_ml_tpu.ops.objective import GLMData, GLMObjective
@@ -144,8 +145,8 @@ def train_glm_sweep(
     the coefficient length (the stacked layout's ``dim`` property reflects
     block shapes, not the model).
 
-    Spans (``telemetry/tracing.py``): one ``glm.sweep{solves, warm_start}``
-    around the whole call, one ``glm.solve{regularization_weight, iterations,
+    Spans (``telemetry/tracing.py``): one ``glm.sweep{solves, warm_start,
+    design}`` around the whole call, one ``glm.solve{regularization_weight, iterations,
     evaluations, converged}`` around each solve's dispatch, the last three
     held as the result's device scalars and read only when the record is. A
     ``glm.solve`` span's ``seconds`` is the HOST's dispatch time, never the
@@ -170,7 +171,8 @@ def train_glm_sweep(
 
     out: list[TrainedModel] = []
     with tracing.span("glm.sweep", solves=len(regularization_weights),
-                      warm_start=bool(warm_start)):
+                      warm_start=bool(warm_start),
+                      design=design_kind(data.design)):
         # the eager problem serves compute_variances below; building it is
         # also where a concrete reg_mask is held to 0/1 (the traced one
         # inside the compiled solve cannot be)
@@ -241,23 +243,22 @@ def train_glm_sweep_batched(
     convergence) and the batched program runs until the SLOWEST lane
     stops. Results are returned in the same descending-lambda order.
 
-    Measured on a TPU v5e on 2026-07-31, before PR 1, and not measured on
-    the present chip (5 lambdas '100;10;1;0.1;0.01', D2H-sync timing, min
-    of 3) — the verdict is LAYOUT-DEPENDENT:
+    Which of the two is faster depends on the LAYOUT, by what an iteration
+    costs and what a warm start saves:
 
-    - dense 200k x 1024, 50 iters: sequential 0.75 s, batched 1.27 s —
-      **0.59x, a loss**. The dense sequential path runs the fused Pallas
-      kernel at the HBM wall and warm starts slash late-lane iterations.
-      Round 4: the multi-row-margin kernel (``ops/pallas_glm.py::
-      fused_value_and_grad_multi``, dispatched automatically through a
-      custom-vmap rule when the solve vmaps over lambda) cuts the batched
-      dense time to 0.95 s — still 0.78x sequential: lockstep lanes
-      cannot beat warm starts on dense, with or without idle-MXU-row use.
-    - chunked-sparse 3.2M nnz, d=20k, 30 iters: sequential 4.34 s,
-      batched 2.49 s — **1.74x**. Here the per-iteration cost is XLA's
-      random gather (~16-20 ns/nnz, tools/layout_crossover.py) whose
-      indices are lambda-independent, so the gather hoists out of the
-      vmap and K lanes share one pass.
+    - dense: the sequential path runs the fused Pallas kernel once an
+      evaluation and warm starts cut the later lanes' iterations, which
+      lockstep lanes give up. The multi-row-margin kernel
+      (``ops/pallas_glm.py::fused_value_and_grad_multi``, dispatched through a
+      custom-vmap rule when the solve vmaps over lambda) reads the design
+      once for all lanes and still runs as long as the slowest lane.
+    - chunked-sparse: an evaluation is two gathers whose indices do not
+      depend on lambda, so under the vmap the index traffic is shared by the
+      K lanes and only the gathered tables grow K-fold.
+
+    Neither has been measured on the present chip; what one sequential
+    sparse evaluation takes there, and where, is in PERF.md (sections 5 and
+    6, PR 32), and no cell runs this function.
 
     Use batched for wide-sparse sweeps; keep sequential (the default, and
     the reference's exact semantics) for dense designs.

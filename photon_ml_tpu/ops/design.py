@@ -28,13 +28,18 @@ what deletes the reference's four hand-written aggregator classes per loss.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 Array = jax.Array
+
+#: the span around :meth:`ChunkedSparseDesign.from_coo`'s build
+BUILD_SPAN = "design.build"
 
 
 @jax.tree_util.register_dataclass
@@ -121,30 +126,251 @@ class CsrDesign:
         )
 
 
-def _chunk_sorted(keys: np.ndarray, payload_idx: np.ndarray, n_keys: int,
-                  chunk: int) -> tuple[np.ndarray, np.ndarray]:
-    """Chunk entries sorted by ``keys`` into fixed-width groups per key.
+def _entries_in_key_order(keys: Array, other: Array, vals: Array,
+                          n_keys: int) -> tuple[Array, Array, Array, Array]:
+    """The entries ordered by key, dropped ones (value 0) last under the key
+    ``n_keys``, and each key's first position in that order
+    (``(n_keys + 1,)``: a key's run is ``starts[k]:starts[k + 1]``). Entries
+    that come in key order (a CSR's rows) are not sorted again; the others go
+    through one device sort by key (a key's entries in no
+    stated order: every contraction sums over them)."""
+    keys = _masked_keys(keys, vals, n_keys=n_keys)
+    if not bool(_in_order(keys)):
+        # the sort takes its operands' memory: the caller's arrays go as copies
+        keys, other, vals = _sort_entries(keys, jnp.copy(other),
+                                          jnp.copy(vals))
+    return keys, other, vals, _run_starts(keys, n_keys=n_keys)
 
-    Returns ``(gather, chunk_key)``: ``gather`` is an ``(M, chunk)`` int64 index into
-    the payload (−1 = padding slot), ``chunk_key`` ``(M,)`` the key id of
-    each chunk. A key with k entries occupies ceil(k/chunk) chunks.
+
+@functools.partial(jax.jit, static_argnames=("n_keys",))
+def _masked_keys(keys, vals, *, n_keys):
+    return jnp.where(vals != 0, keys, jnp.int32(n_keys))
+
+
+@jax.jit
+def _in_order(keys):
+    return jnp.all(keys[1:] >= keys[:-1])
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def _sort_entries(keys, other, vals):
+    # by key, then by the other id: a (row, col) pair's duplicates end up
+    # side by side, which the busy bins' planes need (one program for every
+    # sort of a build: the sort is what compiles longest)
+    return lax.sort((keys, other, vals), num_keys=2)
+
+
+@functools.partial(jax.jit, static_argnames=("n_keys",))
+def _run_starts(keys, *, n_keys):
+    return jnp.searchsorted(
+        keys, jnp.arange(n_keys + 1, dtype=jnp.int32)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks", "one_each"))
+def _chunk_runs(other, vals, starts, *, chunk, n_chunks, one_each=False):
+    """Cut every key's run into rows of ``chunk`` slots: ``(values, other
+    ids, key of each row)`` with ``n_chunks`` rows, a key of k entries taking
+    ceil(k / chunk) of them, in key order. With ``one_each`` every key, one
+    without entries too, has its first row at its own index (row ``k`` is key
+    ``k``), and the rows a key takes beyond its first follow those, in key
+    order. A slot past its run's end holds value 0 and id 0. Every row reads
+    ``chunk`` neighbours of the ordered entries."""
+    counts = starts[1:] - starts[:-1]
+    n_keys = counts.shape[0]
+    per_key = (counts + (chunk - 1)) // chunk
+    if one_each:
+        per_key = jnp.maximum(per_key, 1) - 1  # rows beyond a key's first
+    last = jnp.cumsum(per_key, dtype=jnp.int32)  # a key's rows end here
+    row = jnp.arange(n_chunks, dtype=jnp.int32)
+    if one_each:
+        over = row - n_keys  # a row's place among the rows beyond the first
+        key = jnp.searchsorted(last, jnp.maximum(over, 0),
+                               side="right").astype(jnp.int32)
+        key = jnp.minimum(key, n_keys - 1)
+        nth = jnp.where(over < 0, 0, over - (last - per_key)[key] + 1)
+        key = jnp.where(over < 0, row, key)
+    else:
+        key = jnp.searchsorted(last, row, side="right").astype(jnp.int32)
+        key = jnp.minimum(key, n_keys - 1)
+        nth = row - (last - per_key)[key]
+    within = nth * chunk  # entries of the key before the row
+    # slots-major, (chunk, n_chunks), while it is made: the long axis last
+    # is the one the chip's tiles do not pad; the transposition at the end
+    # is free, a result of (n_chunks, chunk) being held long axis last too
+    lane = jnp.arange(chunk, dtype=jnp.int32)[:, None]
+    live = lane < (counts[key] - within)[None, :]
+    at = jnp.where(live, (starts[key] + within)[None, :] + lane, 0)
+
+    def rows_of(flat):
+        if not flat.shape[0]:  # no entries: every slot is padding
+            return jnp.zeros(at.shape, flat.dtype).T
+        got = jnp.take(flat, at.reshape(-1), axis=0).reshape(at.shape)
+        return jnp.where(live, got, jnp.zeros((), flat.dtype)).T
+
+    return rows_of(vals), rows_of(other), key
+
+
+@functools.partial(jax.jit, static_argnames=("n_keys",))
+def _mixed_values(keys, vals, starts, *, n_keys):
+    """Per key, how often the value changes inside its run of the ordered
+    entries: 0 where all its entries carry one value."""
+    change = (keys[1:] == keys[:-1]) & (vals[1:] != vals[:-1])
+    upto = jnp.concatenate([jnp.zeros((2,), jnp.int32),
+                            jnp.cumsum(change, dtype=jnp.int32)])
+    return upto[starts[1:]] - upto[starts[:-1]]
+
+
+@jax.jit
+def _places(keys, rows, place_of):
+    """Each entry's place among the busy bins (``place_of[bin]``, -1 for a bin
+    that is none), -1 too for every entry of a row in a busy bin but the
+    first: the entries come ordered by (bin, row)."""
+    again = jnp.concatenate([
+        jnp.zeros((1,), bool),
+        (keys[1:] == keys[:-1]) & (rows[1:] == rows[:-1])])
+    return jnp.where(again, -1, jnp.take(place_of, keys, axis=0))
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "by_row"))
+def _plane(place, rows, *, shape, by_row):
+    """The busy bins' bit planes, ``shape`` ``(words, lanes)``: with
+    ``by_row`` bit ``place % 32`` of ``[place // 32, row]``, else bit
+    ``row % 32`` of ``[row // 32, place]``, set for every entry that has a
+    place. Bits are added: no (row, place) comes twice. One scatter into the
+    flat plane (a position fits int32: :func:`_hot_tier` sees to it)."""
+    packed, lane = (place, rows) if by_row else (rows, place)
+    at = jnp.where(place >= 0, (packed // _WORD) * shape[1] + lane,
+                   shape[0] * shape[1])  # past the end: dropped
+    bit = jnp.uint32(1) << (packed % _WORD).astype(jnp.uint32)
+    flat = jnp.zeros((shape[0] * shape[1],), jnp.uint32)
+    return flat.at[at].add(bit, mode="drop").reshape(shape)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _without(vals, place):
+    """The values with the planes' entries zeroed, and how many those are."""
+    return (jnp.where(place >= 0, jnp.zeros((), vals.dtype), vals),
+            jnp.sum(place >= 0, dtype=jnp.int32))
+
+
+def _hot_tier(rows, cols, vals, n_rows: int, n_cols: int,
+              hot_columns: int | None):
+    """The busy bins of a design taken out of its entries: ``(rows, cols,
+    vals, planes)``: the entries that stay in the chunks, ordered by (bin,
+    row), and the fields ``hot_*`` of the design with ``hot_entries`` (none
+    but that, and the entries as they came, where no bin qualifies). Every
+    large step ends before the next is asked for: the chip reserves a
+    program's temporaries when it is enqueued."""
+    keys = _masked_keys(cols, vals, n_keys=n_cols)
+    keys, by, of = _sort_entries(keys, jnp.copy(rows), jnp.copy(vals))
+    starts = _run_starts(keys, n_keys=n_cols)
+    counts = np.diff(np.asarray(starts))
+    fit = (counts > 0) & (np.asarray(_mixed_values(
+        keys, of, starts, n_keys=n_cols)) == 0)
+    if hot_columns is None:
+        fit &= counts.astype(np.int64) * _HOT_ONE_IN >= n_rows
+        hot_columns = _HOT_MAX
+    order = np.argsort(-counts, kind="stable")
+    busiest = order[fit[order]][:hot_columns]
+    k = -(-len(busiest) // 256) * 256  # whole words, whole tiles of lanes
+    words = -(-n_rows // _WORD)
+    if not k or max(k // _WORD * n_rows, words * k) >= np.iinfo(np.int32).max:
+        return rows, cols, vals, {"hot_entries": 0}
+    hot_cols = np.zeros(k, np.int32)
+    hot_cols[:len(busiest)] = busiest
+    place_of = np.full(n_cols + 1, -1, np.int32)
+    place_of[busiest] = np.arange(len(busiest), dtype=np.int32)
+    first = np.zeros(k, np.int32)
+    first[:len(busiest)] = np.asarray(starts)[busiest]
+    hot_vals = jnp.where(jnp.arange(k) < len(busiest),
+                         jnp.take(of, jnp.asarray(first)), 0)
+    place = _places(keys, by, jnp.asarray(place_of))
+    by_row = jax.block_until_ready(
+        _plane(place, by, shape=(k // _WORD, n_rows), by_row=True))
+    by_bin = jax.block_until_ready(
+        _plane(place, by, shape=(words, k), by_row=False))
+    of, n_hot = jax.block_until_ready(_without(of, place))
+    del place
+    # what stays, first: one more sort, of these arrays' own memory
+    left = int(counts.sum()) - int(n_hot)
+    keys = _masked_keys(keys, of, n_keys=n_cols)
+    keys, by, of = _sort_entries(keys, by, of)
+    return by[:left], keys[:left], of[:left], dict(
+        hot_cols=jnp.asarray(hot_cols), hot_vals=hot_vals, hot_by_row=by_row,
+        hot_by_bin=by_bin, hot_entries=int(n_hot))
+
+
+#: lanes of a table row that :func:`_lookup` gathers whole
+_LANES = 128
+#: table rows that one gather of :func:`_lookup` fetches (512 B each): 1.3 GB
+#: of them. What was measured, at column chunks of 16 (PERF.md, section 6, PR
+#: 32): gathered blocks of (16, 131072, 128) ran 4.4 times as long an index
+#: as blocks of (16, 163840, 128), which this constant gives. The cause is not
+#: known: the row side's blocks, (40, 65536, 128), are a power of two as well
+#: and ran at the full rate. The other chunk widths (8 to 128: blocks of
+#: 327,680 to 20,480) are compiled for the chip in tests/test_tpu_layouts.py
+#: and have not been timed (ROADMAP S0)
+_LOOKUP_ROWS = 5 << 19
+
+
+def _lookup(table: Array, idx: Array) -> Array:
+    """``table[idx]`` for a 1-D ``table`` and ``(C, M)`` indices that lie in
+    range, ``M`` the long axis.
+
+    Not ``jnp.take``: on the chip XLA serialises a gather of scalars, one
+    element at a time whatever the table's size, and fetches whole rows of
+    128 lanes four times as fast an index (PERF.md, section 6, PR 32). So the
+    table is read as ``(T / 128, 128)``, the row ``idx >> 7`` of every index
+    is gathered and the lane ``idx & 127`` picked by comparison, ``M`` in
+    blocks so that the gathered rows of one block stay near a gigabyte
+    (``_LOOKUP_ROWS``). The indices stay ``(C, M)`` throughout: the long axis
+    last is the one the chip's tiles do not pad.
     """
-    counts = np.bincount(keys, minlength=n_keys)
-    present = np.flatnonzero(counts)
-    n_chunks_per = -(-counts[present] // chunk)
-    total = int(n_chunks_per.sum())
-    chunk_key = np.repeat(present, n_chunks_per).astype(np.int32)
-    # entry positions: within-key offset → (chunk row, slot)
-    starts = np.zeros(len(present) + 1, np.int64)
-    np.cumsum(counts[present], out=starts[1:])
-    chunk_starts = np.zeros(len(present) + 1, np.int64)
-    np.cumsum(n_chunks_per, out=chunk_starts[1:])
-    within = np.arange(len(keys)) - np.repeat(starts[:-1], counts[present])
-    chunk_row = np.repeat(chunk_starts[:-1], counts[present]) + within // chunk
-    slot = within % chunk
-    gather = np.full((total, chunk), -1, np.int64)
-    gather[chunk_row, slot] = payload_idx
-    return gather, chunk_key
+    c, m = idx.shape[-2:]
+    if idx.ndim != 2:
+        return jax.vmap(_lookup, in_axes=(None, 0))(table, idx)
+    rows = jnp.pad(table, (0, -table.shape[0] % _LANES)).reshape(-1, _LANES)
+    lane = jnp.arange(_LANES, dtype=idx.dtype)
+
+    def pick(i):
+        got = rows.at[i // _LANES].get(mode="promise_in_bounds")
+        return jnp.sum(jnp.where((i % _LANES)[..., None] == lane, got, 0),
+                       axis=-1)
+
+    block = max(_LOOKUP_ROWS // max(c, 1) // 1024 * 1024, 1024)
+    whole = m // block
+    out = []
+    if whole:
+        blocks = lax.map(
+            lambda b: pick(lax.dynamic_slice_in_dim(idx, b * block, block,
+                                                    axis=1)),
+            jnp.arange(whole, dtype=jnp.int32))
+        out.append(jnp.moveaxis(blocks, 0, 1).reshape(c, whole * block))
+    if m % block or not whole:
+        out.append(pick(idx[:, whole * block:]))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+#: bits of a word of the busy bins' planes
+_WORD = 32
+#: a bin is busy where it holds an entry in one row of this many or more:
+#: below it a bit a row costs more than the bin's few entries looked up
+_HOT_ONE_IN = 1024
+#: the most busy bins that get planes (a plane is a bit a row, stored twice)
+_HOT_MAX = 4096
+#: designs of fewer entries keep every bin in the chunks
+_HOT_MIN_ENTRIES = 1 << 19
+
+
+def _planes_dot(bits: Array, coef: Array) -> Array:
+    """``Σ_a Σ_j bit j of bits[a, b] * coef[a, j]`` for ``(A, B)`` words and
+    ``(A, 32)`` coefficients: one pass over the words, a sum along the major
+    axis, ``B`` in the lanes."""
+    acc = jnp.zeros(bits.shape, coef.dtype)
+    for j in range(_WORD):
+        on = (bits & jnp.uint32(1 << j)) != 0
+        acc = acc + jnp.where(on, coef[:, j][:, None], 0)
+    return jnp.sum(acc, axis=0)
 
 
 @jax.tree_util.register_dataclass
@@ -152,13 +378,12 @@ def _chunk_sorted(keys: np.ndarray, payload_idx: np.ndarray, n_keys: int,
 class ChunkedSparseDesign:
     """Dual chunked-COO sparse design: scatters shrunk by chunk partial sums.
 
-    Motivation (measured on a TPU v5e before PR 1, 12.8M nnz, d=100k; not
-    measured on the present chip):
-    ``CsrDesign``'s per-nnz ``segment_sum`` margins cost ~116 ms and its
-    scatter-add transpose ~89 ms, while a gather + fixed-width row-sum of
-    the same entries costs ~5 ms — XLA lowers large scatters serially on
-    TPU, but gathers and lane reductions stream. So this layout stores the
-    entries TWICE, pre-sorted on host at build time:
+    ``CsrDesign`` takes one ``segment_sum`` of every entry for the margins
+    and one scatter-add of every entry for the gradient transpose, and XLA
+    runs a scatter-add on a TPU one element at a time, while reductions
+    along a fixed width stream. So this layout stores the entries TWICE,
+    each side ordered by its key at build time (:meth:`layout`, on the
+    device), and only chunk sums are scattered:
 
     - row-major: ``(Mr, C)`` values/col-ids with one row id per chunk —
       margins = per-chunk ``Σ v·w[col]`` then a segment-sum of ONLY
@@ -170,20 +395,48 @@ class ChunkedSparseDesign:
     width trades padding (small C) against scatter length (large C); the
     builder defaults to the per-key median rounded to a multiple of 8,
     clamped to [8, 128]. 2x memory vs CsrDesign — the price of replacing
-    both big scatters. This is the counterpart of the reference's executor-
-    local hash-map gradient accumulation in
-    ``function/glm/ValueAndGradientAggregator.scala``, re-shaped for a
-    machine that hates random writes and loves wide reads.
+    both big scatters. On the chip a ``(M, C)`` array lies with ``M``, its
+    long axis, in the lanes (C = 8 to 128 there would be padded to 128), so
+    the build and the contractions walk it slots-major.
+
+    A look-up costs the same whatever it fetches (nanoseconds an index), so
+    a design whose entries crowd into a few bins (hashed one-hot features: a
+    few thousand bins of a million hold four fifths of the entries) keeps
+    those out of the chunks: a BUSY bin whose entries all carry one value is
+    a bit a row (``hot_*``: bit planes, again stored twice, once with the
+    rows and once with the bins in the lanes), and a contraction reads the
+    planes in one streaming pass with no look-up at all. Which bins, and
+    whether any, the build decides from the counts it sees (:meth:`layout`).
+    What the build and an evaluation take at 2 x 10^8 entries, and which
+    operations take it: PERF.md, sections 5 and 6 (PR 32). This is the
+    counterpart of the reference's executor-local hash-map gradient
+    accumulation in ``function/glm/ValueAndGradientAggregator.scala``,
+    re-shaped for a machine that hates random writes and loves wide reads.
     """
 
     rvals: Array  # (Mr, C) f32
     rcols: Array  # (Mr, C) int32
-    rrow: Array  # (Mr,) int32 — row id per chunk (non-decreasing)
+    rrow: Array  # (Mr,) int32 — row id per chunk
     cvals: Array  # (Mc, C) f32
     crows: Array  # (Mc, C) int32
     ccol: Array  # (Mc,) int32 — col id per chunk (non-decreasing)
     n_rows: int = dataclasses.field(metadata=dict(static=True))
     n_cols: int = dataclasses.field(metadata=dict(static=True))
+    #: chunk ``i`` is row ``i``'s first, for every row, and the chunks past
+    #: ``n_rows`` (non-decreasing in ``rrow``) belong to the rows that hold
+    #: more than a chunk is wide: the first ``n_rows`` chunk sums ARE margins,
+    #: and only the others go through a segment-sum
+    rows_first: bool = dataclasses.field(
+        default=False, metadata=dict(static=True))
+    #: the busy bins, or None: their ids and the one value each one's entries
+    #: carry (``(K,)``; unused places hold value 0), and their planes: bit
+    #: ``k % 32`` of ``hot_by_row[k // 32, i]`` and bit ``i % 32`` of
+    #: ``hot_by_bin[i // 32, k]`` say that row ``i`` has an entry in busy bin
+    #: ``k`` (one: a second entry of that row and bin stays in the chunks)
+    hot_cols: Array | None = None  # (K,) int32
+    hot_vals: Array | None = None  # (K,) f32
+    hot_by_row: Array | None = None  # (K / 32, n_rows) uint32
+    hot_by_bin: Array | None = None  # (ceil(n_rows / 32), K) uint32
 
     @property
     def n_samples(self) -> int:
@@ -194,37 +447,53 @@ class ChunkedSparseDesign:
         return self.n_cols
 
     @staticmethod
-    def _gather2d(table: Array, idx: Array) -> Array:
-        """``table[idx]`` for a 2D index array via a FLAT gather + reshape —
-        XLA lowers a gather with a 2D start-index array ~30x slower on TPU
-        (measured 129 ms vs 4.3 ms for 13M indices)."""
-        return jnp.take(table, idx.reshape(-1), axis=0).reshape(idx.shape)
+    def _chunk_sums(vals: Array, idx: Array, table: Array) -> Array:
+        """``Σ_slot vals * table[idx]`` per chunk, for ``(M, C)`` chunks."""
+        acc = jnp.promote_types(jnp.promote_types(vals.dtype, table.dtype),
+                                jnp.float32)
+        got = _lookup(table, jnp.swapaxes(idx, -1, -2))
+        return jnp.sum((jnp.swapaxes(vals, -1, -2) * got).astype(acc),
+                       axis=-2)
 
     def matvec(self, w: Array) -> Array:
-        acc = jnp.promote_types(jnp.promote_types(self.rvals.dtype, w.dtype),
-                                jnp.float32)
-        part = jnp.sum((self.rvals * self._gather2d(w, self.rcols)
-                        ).astype(acc), axis=-1)
-        return jax.ops.segment_sum(part, self.rrow, num_segments=self.n_rows,
-                                   indices_are_sorted=True)
+        with jax.named_scope("design.matvec"):
+            part = self._chunk_sums(self.rvals, self.rcols, w)
+            if not self.rows_first:
+                out = jax.ops.segment_sum(
+                    part, self.rrow, num_segments=self.n_rows,
+                    indices_are_sorted=True)
+            elif part.shape[-1] == self.n_rows:
+                out = part
+            else:
+                out = part[..., :self.n_rows] + jax.ops.segment_sum(
+                    part[..., self.n_rows:], self.rrow[self.n_rows:],
+                    num_segments=self.n_rows, indices_are_sorted=True)
+            if self.hot_cols is not None:
+                coef = self.hot_vals * _lookup(w, self.hot_cols[None, :])[0]
+                out = out + _planes_dot(self.hot_by_row,
+                                        coef.reshape(-1, _WORD))
+            return out
+
+    def _transposed(self, vals: Array, g: Array, hot_vals) -> Array:
+        with jax.named_scope("design.rmatvec"):
+            out = jax.ops.segment_sum(
+                self._chunk_sums(vals, self.crows, g), self.ccol,
+                num_segments=self.n_cols, indices_are_sorted=True)
+            if self.hot_cols is not None:
+                words = self.hot_by_bin.shape[0]
+                per_row = jnp.pad(g, (0, words * _WORD - g.shape[0]))
+                sums = _planes_dot(self.hot_by_bin,
+                                   per_row.reshape(words, _WORD))
+                out = out.at[self.hot_cols].add(hot_vals * sums)
+            return out
 
     def rmatvec(self, g: Array) -> Array:
-        acc = jnp.promote_types(jnp.promote_types(self.cvals.dtype, g.dtype),
-                                jnp.float32)
-        part = jnp.sum((self.cvals * self._gather2d(g, self.crows)
-                        ).astype(acc), axis=-1)
-        return jax.ops.segment_sum(part, self.ccol, num_segments=self.n_cols,
-                                   indices_are_sorted=True)
+        return self._transposed(self.cvals, g, self.hot_vals)
 
     def rmatvec_squared(self, g: Array) -> Array:
         """``(X²)ᵀ g`` — the Hessian-diagonal contraction (values squared)."""
-        acc = jnp.promote_types(jnp.promote_types(self.cvals.dtype, g.dtype),
-                                jnp.float32)
-        part = jnp.sum((jnp.square(self.cvals)
-                        * self._gather2d(g, self.crows)).astype(acc),
-                       axis=-1)
-        return jax.ops.segment_sum(part, self.ccol, num_segments=self.n_cols,
-                                   indices_are_sorted=True)
+        hot = None if self.hot_vals is None else jnp.square(self.hot_vals)
+        return self._transposed(jnp.square(self.cvals), g, hot)
 
     @staticmethod
     def default_chunk(counts: np.ndarray) -> int:
@@ -236,56 +505,116 @@ class ChunkedSparseDesign:
         return int(np.clip(-(-med // 8) * 8, 8, 128))
 
     @staticmethod
-    def layout_numpy(rows, cols, vals, *, row_chunk: int | None = None,
+    def layout(rows, cols, vals, n_rows: int, n_cols: int, *,
+               row_chunk: int | None = None,
+               col_chunk: int | None = None,
+               rows_first: bool = True,
+               hot_columns: int | None = None) -> dict:
+        """THE build: both chunk layouts from COO triplets (host or device
+        arrays), made on the device in int32 and float32. Per side: the
+        entries ordered by the side's key (one sort, none for entries that
+        come in that order), every key's run cut into rows of the chunk
+        width. Only per-key counts visit the host (the default widths, the
+        number of chunk rows and the busy bins are read from them). Explicit
+        zeros are dropped; duplicate ``(row, col)`` entries keep separate
+        slots. Every row's first chunk stands at the row's own index
+        (``rows_first``; a caller that stacks layouts of several blocks turns
+        that off and gets the chunks of non-empty rows alone).
+
+        ``hot_columns``: how many of the busiest bins become bit planes
+        (:func:`_hot_tier`): 0 for none; by default those that hold an entry
+        in one row of ``_HOT_ONE_IN`` or more, ``_HOT_MAX`` at the most, in a
+        design of ``_HOT_MIN_ENTRIES`` entries or more. Only a bin whose
+        entries all carry one value can be one, and of a row's duplicate
+        entries in it the first alone; what the planes hold leaves the
+        chunks."""
+        rows = jnp.asarray(rows, jnp.int32).reshape(-1)
+        cols = jnp.asarray(cols, jnp.int32).reshape(-1)
+        vals = jnp.asarray(vals, jnp.float32).reshape(-1)
+        limit = np.iinfo(np.int32).max
+        if max(n_rows, n_cols) >= limit or vals.shape[0] >= limit - 128:
+            raise ValueError(
+                f"{vals.shape[0]} entries of a {n_rows} x {n_cols} design "
+                f"pass what int32 positions address")
+        hot = {"hot_entries": 0}
+        if hot_columns != 0 and (hot_columns is not None
+                                 or vals.shape[0] >= _HOT_MIN_ENTRIES):
+            rows, cols, vals, hot = _hot_tier(
+                rows, cols, vals, int(n_rows), int(n_cols), hot_columns)
+
+        def side(keys, other, n_keys, chunk, first_each=False):
+            _, other, v, starts = _entries_in_key_order(keys, other, vals,
+                                                        n_keys)
+            counts = np.diff(np.asarray(starts))
+            if chunk is None:
+                chunk = ChunkedSparseDesign.default_chunk(counts)
+            per_key = -(-counts // chunk)
+            if first_each:
+                per_key = np.maximum(per_key, 1)
+            n_chunks = int(per_key.sum())
+            if n_chunks * chunk >= limit:
+                raise ValueError(f"{n_chunks} chunks of {chunk} slots pass "
+                                 f"what int32 positions address")
+            return _chunk_runs(other, v, starts, chunk=int(chunk),
+                               n_chunks=n_chunks, one_each=first_each) + (
+                                   int(chunk), int(counts.sum()))
+
+        cvals, crows, ccol, col_chunk, _ = side(
+            cols, rows, int(n_cols), col_chunk)
+        rvals, rcols, rrow, row_chunk, entries = side(
+            rows, cols, int(n_rows), row_chunk, bool(rows_first))
+        return dict(rvals=rvals, rcols=rcols, rrow=rrow, cvals=cvals,
+                    crows=crows, ccol=ccol, row_chunk=row_chunk,
+                    col_chunk=col_chunk, rows_first=bool(rows_first),
+                    entries=entries + hot["hot_entries"], **hot)
+
+    @staticmethod
+    def layout_numpy(rows, cols, vals, n_rows: int, n_cols: int, *,
+                     row_chunk: int | None = None,
                      col_chunk: int | None = None) -> dict:
-        """Host-side chunk layouts as numpy arrays (for stacking/sharding)."""
-        rows = np.asarray(rows, np.int64)
-        cols = np.asarray(cols, np.int64)
-        vals = np.asarray(vals, np.float32)
-        live = vals != 0  # drop explicit zero padding from CSR-style inputs
-        rows, cols, vals = rows[live], cols[live], vals[live]
-        if row_chunk is None:
-            row_chunk = ChunkedSparseDesign.default_chunk(
-                np.bincount(rows) if len(rows) else np.zeros(1, np.int64))
-        if col_chunk is None:
-            col_chunk = ChunkedSparseDesign.default_chunk(
-                np.bincount(cols) if len(cols) else np.zeros(1, np.int64))
-
-        def layout(keys, chunk):
-            order = np.argsort(keys, kind="stable")
-            gather, chunk_key = _chunk_sorted(
-                keys[order], order,
-                max(int(keys.max()) + 1 if len(keys) else 1, 1), chunk)
-            pad = gather < 0
-            safe = np.where(pad, 0, gather)
-            v = np.where(pad, 0.0, vals[safe] if len(vals) else 0.0
-                         ).astype(np.float32)
-            return v, safe, chunk_key
-
-        rvals, r_src, rrow = layout(rows, row_chunk)
-        cvals, c_src, ccol = layout(cols, col_chunk)
-        safe_cols = cols[r_src] if len(cols) else np.zeros_like(r_src)
-        safe_rows = rows[c_src] if len(rows) else np.zeros_like(c_src)
-        return dict(
-            rvals=rvals, rcols=safe_cols.astype(np.int32), rrow=rrow,
-            cvals=cvals, crows=safe_rows.astype(np.int32), ccol=ccol,
-            row_chunk=row_chunk, col_chunk=col_chunk)
+        """:meth:`layout` with its arrays on the host, for callers that pad
+        and stack the layouts of several row blocks (chunk rows of non-empty
+        keys only, so that padding chunks can follow them; every bin in the
+        chunks)."""
+        lay = ChunkedSparseDesign.layout(
+            rows, cols, vals, n_rows, n_cols, row_chunk=row_chunk,
+            col_chunk=col_chunk, rows_first=False, hot_columns=0)
+        return {k: np.asarray(v) if isinstance(v, jax.Array) else v
+                for k, v in lay.items()}
 
     @staticmethod
     def from_coo(rows, cols, vals, n_rows: int, n_cols: int,
                  row_chunk: int | None = None, col_chunk: int | None = None,
-                 ) -> "ChunkedSparseDesign":
-        """Build both layouts from host COO triplets. Duplicate (row, col)
-        entries occupy separate slots and accumulate in every contraction,
-        the same semantics as CsrDesign."""
-        lay = ChunkedSparseDesign.layout_numpy(
-            rows, cols, vals, row_chunk=row_chunk, col_chunk=col_chunk)
-        return ChunkedSparseDesign(
-            rvals=jnp.asarray(lay["rvals"]), rcols=jnp.asarray(lay["rcols"]),
-            rrow=jnp.asarray(lay["rrow"]),
-            cvals=jnp.asarray(lay["cvals"]), crows=jnp.asarray(lay["crows"]),
-            ccol=jnp.asarray(lay["ccol"]),
-            n_rows=int(n_rows), n_cols=int(n_cols))
+                 hot_columns: int | None = None) -> "ChunkedSparseDesign":
+        """Build both layouts from COO triplets (:meth:`layout`), under a
+        ``design.build`` span that carries the sizes. Duplicate (row, col)
+        entries accumulate in every contraction, the same semantics as
+        CsrDesign."""
+        from photon_ml_tpu.telemetry import tracing
+
+        with tracing.span(BUILD_SPAN, rows=int(n_rows),
+                          dim=int(n_cols)) as build:
+            lay = ChunkedSparseDesign.layout(
+                rows, cols, vals, n_rows, n_cols, row_chunk=row_chunk,
+                col_chunk=col_chunk, hot_columns=hot_columns)
+            sizes = {k: lay.pop(k) for k in ("entries", "row_chunk",
+                                             "col_chunk", "hot_entries")}
+            design = ChunkedSparseDesign(
+                **lay, n_rows=int(n_rows), n_cols=int(n_cols))
+            jax.block_until_ready(design)
+            build.set(**sizes, row_slots=design.rvals.size,
+                      col_slots=design.cvals.size,
+                      hot_columns=0 if design.hot_cols is None
+                      else design.hot_cols.shape[0])
+        return design
 
 
 Design = Union[DenseDesign, CsrDesign, ChunkedSparseDesign]
+
+
+def design_kind(design) -> str:
+    """A design's name in span attributes: ``dense``, ``csr``,
+    ``chunked_sparse``; another type's own name, lower-cased."""
+    names = {DenseDesign: "dense", CsrDesign: "csr",
+             ChunkedSparseDesign: "chunked_sparse"}
+    return names.get(type(design), type(design).__name__.lower())

@@ -178,7 +178,7 @@ def shard_glm_data(data: GLMData, n_shards: int, *, device_put_mesh: Optional[Me
         for b in range(n_shards):
             sel = block_of == b
             lays.append(ChunkedSparseDesign.layout_numpy(
-                local_row[sel], cols[sel], vals[sel],
+                local_row[sel], cols[sel], vals[sel], per, design.n_cols,
                 row_chunk=row_chunk, col_chunk=col_chunk))
         mr = max(lay["rrow"].shape[0] for lay in lays)
         mc = max(lay["ccol"].shape[0] for lay in lays)

@@ -128,3 +128,122 @@ def test_the_kernel_takes_the_bucket_entities_last(bucket_program):
     assert not re.search(rf"f32\[{D},(?:{LANES})\][^ ]* concatenate\(", body)
     assert not re.search(rf"f32\[{D},{S},(?:{LANES})\][^ ]* (?:copy|transpose)\(",
                          body)
+
+
+# --- the wide sparse design's evaluation (PERF.md, PR 32) --------------------
+SPARSE_ROWS, SPARSE_DIM, SPARSE_CHUNKS = 400_000, 1_000_000, 1_100_000
+#: (row chunk, column chunk, every row one chunk): the click-through shape
+#: first (rows of 40 slots, column chunks of 16: the one that was timed on the
+#: chip), then the other widths ``default_chunk`` yields and a row side that
+#: takes its segment-sum: compiled here, not yet timed (ROADMAP S0)
+SPARSE_WIDTHS = [(40, 16, True), (40, 8, True), (40, 32, True),
+                 (40, 128, True), (8, 16, False), (64, 64, False)]
+
+
+@pytest.fixture(scope="module")
+def sparse_evaluation(one_chip):
+    """One value-and-gradient evaluation over a ``ChunkedSparseDesign`` of the
+    given chunk widths, compiled for the chip: its text and its
+    temporaries."""
+    from photon_ml_tpu.ops.design import ChunkedSparseDesign
+    from photon_ml_tpu.ops.losses import LogisticLoss
+    from photon_ml_tpu.ops.objective import GLMData, GLMObjective
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    n, d, mc = SPARSE_ROWS, SPARSE_DIM, SPARSE_CHUNKS
+    built = {}
+
+    def compiled(row_chunk, col_chunk, rows_first):
+        key = (row_chunk, col_chunk, rows_first)
+        if key not in built:
+            mr = n if rows_first else mc
+            design = ChunkedSparseDesign(
+                rvals=sds((mr, row_chunk)),
+                rcols=sds((mr, row_chunk), jnp.int32),
+                rrow=sds((mr,), jnp.int32), cvals=sds((mc, col_chunk)),
+                crows=sds((mc, col_chunk), jnp.int32),
+                ccol=sds((mc,), jnp.int32), n_rows=n, n_cols=d,
+                rows_first=rows_first)
+            data = GLMData(design=design, labels=sds((n,)), offsets=sds((n,)),
+                           weights=sds((n,)))
+            objective = GLMObjective(loss=LogisticLoss, fused=True)
+            with jax.enable_x64(False):
+                program = jax.jit(lambda w, data: objective.value_and_grad(
+                    w, data, 1.0)).lower(sds((d,)), data).compile()
+            built[key] = (program.as_text(), program.memory_analysis())
+        return built[key]
+
+    return compiled
+
+
+@pytest.mark.parametrize("widths", SPARSE_WIDTHS, ids=str)
+def test_sparse_evaluation_gathers_whole_rows(sparse_evaluation, widths):
+    """Every gather of the evaluation fetches rows of 128 lanes (a gather of
+    scalars runs one element at a time on the chip), and both sides'
+    operations carry their scope."""
+    text, _ = sparse_evaluation(*widths)
+    gathers = re.findall(r"= f32\[([0-9,]+)\]\S* gather\(", text)
+    assert gathers and all(g.endswith(",128") for g in gathers), gathers
+    assert "design.matvec" in text and "design.rmatvec" in text
+
+
+@pytest.mark.parametrize("widths", SPARSE_WIDTHS, ids=str)
+def test_sparse_evaluation_pads_no_chunk_array(sparse_evaluation, widths):
+    """A ``(M, C)`` chunk array is never re-laid with C (8 to 64) in the
+    128-lane dimension (at C = 16 and 40, 8 and 3.2 times its bytes: the
+    parent's evaluation did not fit the chip at 8,000,000 rows for it), and
+    the temporaries stay near the two blocks of gathered rows."""
+    text, memory = sparse_evaluation(*widths)
+    row_chunk, col_chunk, rows_first = widths
+    sides = ((SPARSE_ROWS if rows_first else SPARSE_CHUNKS, row_chunk),
+             (SPARSE_CHUNKS, col_chunk))
+    for rows, width in sides:
+        laid = set(re.findall(rf"[fs]32\[{rows},{width}\]{{([0-9,]+):",
+                              text))
+        assert laid <= {"0,1"} or width == 128, (rows, width, laid)
+    assert memory.temp_size_in_bytes < 4 * 2**30
+
+
+def test_busy_bins_planes_are_read_in_one_pass_a_side(one_chip):
+    """The bit planes of the busy bins (``hot_by_row``, ``hot_by_bin``) are
+    what an evaluation streams: each is the operand of one fusion and of
+    nothing else (no copy, no transposition, no pass a bit), and the row side
+    with chunks beyond the rows' first sums only those by row."""
+    from photon_ml_tpu.ops.design import ChunkedSparseDesign
+    from photon_ml_tpu.ops.losses import LogisticLoss
+    from photon_ml_tpu.ops.objective import GLMData, GLMObjective
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    n, d, k, mr, mc = SPARSE_ROWS, SPARSE_DIM, 3072, 600_000, 300_000
+    words = -(-n // 32)
+    design = ChunkedSparseDesign(
+        rvals=sds((mr, 8)), rcols=sds((mr, 8), jnp.int32),
+        rrow=sds((mr,), jnp.int32), cvals=sds((mc, 16)),
+        crows=sds((mc, 16), jnp.int32), ccol=sds((mc,), jnp.int32),
+        n_rows=n, n_cols=d, rows_first=True,
+        hot_cols=sds((k,), jnp.int32), hot_vals=sds((k,)),
+        hot_by_row=sds((k // 32, n), jnp.uint32),
+        hot_by_bin=sds((words, k), jnp.uint32))
+    data = GLMData(design=design, labels=sds((n,)), offsets=sds((n,)),
+                   weights=sds((n,)))
+    objective = GLMObjective(loss=LogisticLoss, fused=True)
+    with jax.enable_x64(False):
+        program = jax.jit(lambda w, data: objective.value_and_grad(
+            w, data, 1.0)).lower(sds((d,)), data).compile()
+    text = program.as_text()
+    entry = text[text.index("ENTRY"):]
+    for shape in (f"{k // 32},{n}", f"{words},{k}"):
+        (plane,) = re.findall(rf"(%\S+) = u32\[{shape}\]\S* parameter\(",
+                              entry)
+        users = re.findall(rf"= \S+ (\w+)\([^)]*{re.escape(plane)}[,)]",
+                           entry)
+        assert users == ["fusion"], (shape, users)
+        assert not re.search(rf"= u32\[{shape}\]", entry.replace(
+            f"{plane} = u32[{shape}]", ""))
+    # the rows' first chunks are margins as they stand: the segment-sum (a
+    # scatter) takes the mr - n chunks beyond them
+    assert "design.matvec/scatter-add" in text
+    assert re.search(rf"f32\[{mr - n}(,1)?\]", text)
+    assert program.memory_analysis().temp_size_in_bytes < 4 * 2**30
