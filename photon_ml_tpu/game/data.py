@@ -891,10 +891,10 @@ class RandomEffectDataset:
 def resident_fat_bytes(buckets) -> int:
     """f32 HBM estimate of a coordinate's device-RESIDENT bucket tensors —
     the :func:`~photon_ml_tpu.game.random_effect._materialize_fat` product:
-    x (E,S,D) + labels/weights/gather-idx/scatter-idx (E,S) each. The
-    single home of the formula (build guard, estimator budget, probe)."""
+    x (E,S,D) + labels/weights (E,S) each. The single home of the formula
+    (build guard, estimator budget, probe)."""
     return sum(
-        e * s * d * 4 + 4 * e * s * 4
+        e * s * d * 4 + 2 * e * s * 4
         for (e, s, d) in (b.tensor_shape for b in buckets))
 
 

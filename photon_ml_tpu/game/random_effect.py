@@ -18,13 +18,16 @@ lanes; the fixed effect's single solve keeps the nested ``minimize_lbfgs``,
 which works a direction out once an iteration (``lbfgs.py`` says why the
 loops are two).
 
-A coordinate's sweep is one traced body, :func:`_sweep_fused_impl`: a bucket
-at a time it gathers the residual offsets, joins the warm start out of the
-previous sweep's coefficient table, solves, takes margins, scatters them into
-the score vector and flattens the coefficients. A resident dataset
-(``RandomEffectDatasetConfig.resident``) runs it over all its buckets as one
-program a sweep; a streaming one runs the same body a bucket a program, and
-waits for each before the next bucket uploads, so peak HBM stays one bucket.
+A coordinate's sweep is one traced body, :func:`_sweep_fused_impl`: it
+scatters the residual offsets into the buckets' padded slots, then a bucket at
+a time joins the warm start out of the previous sweep's coefficient table,
+solves, takes margins and flattens the coefficients, and at the end looks
+every row's score up among the buckets' margins; rows go in and come out
+through one index of each row's slot (:meth:`RandomEffectSolver._row_slots`).
+A resident dataset (``RandomEffectDatasetConfig.resident``) runs it over all
+its buckets as one program a sweep; a streaming one runs the same body a
+bucket a program, and waits for each before the next bucket uploads, so peak
+HBM stays one bucket.
 One compilation serves every sweep, cold and warm.
 
 Padding correctness: padded sample rows carry weight 0 (contribute nothing);
@@ -54,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_ml_tpu.game.data import RandomEffectDataset, REBucket
 from photon_ml_tpu.game.model import RandomEffectModel
 from photon_ml_tpu.glm.problem import GLMOptimizationConfiguration, OptimizationProblem
-from photon_ml_tpu.ops.design import DenseDesign
+from photon_ml_tpu.ops.design import DenseDesign, lookup
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.objective import GLMData, GLMObjective
 from photon_ml_tpu.parallel.mesh import ENTITY_AXIS, replicated
@@ -183,25 +186,20 @@ class RandomEffectSolver:
                                                P(self._lane_axes())))
 
     def _static_arrays(self, dataset: RandomEffectDataset, i: int,
-                       bucket: REBucket, n: int):
-        """Device placements of the per-sweep-invariant bucket arrays,
-        cached on the dataset so each CD sweep re-uploads only the small
-        dynamic inputs (warm starts). Two index arrays ride along: the
-        clipped gather index (entity-padded with 0 — harmless, padded rows
-        are weight-0) for the residual-offset gather, and the scatter index
-        (dead rows → ``n``, dropped by the ``mode="drop"`` scatter;
-        deliberately NOT entity-padded, since zero-padding a scatter index
-        would alias sample 0). A streaming dataset caches nothing: upload
-        and drop (peak HBM = one bucket instead of all).
+                       bucket: REBucket):
+        """Device placements of the per-sweep-invariant bucket arrays
+        ``(x, labels, weights)``, cached on the dataset so each CD sweep
+        re-uploads only the small dynamic inputs (warm starts). A streaming
+        dataset caches nothing: upload and drop (peak HBM = one bucket
+        instead of all).
 
         When the dataset carries source data and the shard densifies
         (:meth:`_compact_shared`), the fat tensors are materialized ON
         DEVICE by one gather through the compact index maps instead of
         being filled on host and shipped over the host→device link — the
-        padded tensors are 3-4x the compact form.
-        The gather runs ONCE per dataset (cached), so repeated sweeps pay
-        nothing: leaving the gathers INSIDE the sweep program instead
-        measured 3x on the 10M-row RE bench (re-gathering per solve)."""
+        padded tensors are 3-4x the compact form. The gather runs ONCE per
+        dataset (cached): inside the sweep program every solve would pay it
+        again."""
 
         def build():
             shared = self._compact_shared(dataset)
@@ -212,28 +210,22 @@ class RandomEffectSolver:
                 identity = (fi.shape[1] == shared[0].shape[1]
                             and bool((fi == np.arange(fi.shape[1])).all()))
                 return _materialize_fat(
-                    *shared, perm_d, counts_d, fi_d, n=n,
+                    *shared, perm_d, counts_d, fi_d,
                     S=int(bucket.sample_idx.shape[1]),
                     identity_cols=identity)
             return (self._put(bucket.x.astype(self._x_dtype)
                               if self.design_dtype != "float32"
                               else bucket.x),
                     self._put(bucket.labels),
-                    self._put(bucket.weights),
-                    self._put(np.maximum(bucket.sample_idx, 0)),
-                    jnp.asarray(np.where(bucket.sample_idx >= 0,
-                                         bucket.sample_idx, n)))
+                    self._put(bucket.weights))
 
         if not dataset.config.resident:
             return build()
-        # n (the dead-row scatter sentinel) is baked into the built index,
-        # so it must key the cache: the same dataset reused with a
-        # different-length offsets vector gets a fresh sentinel. The design
-        # dtype keys it too — the built x tensors land in _x_dtype, and a
-        # dataset reused across solvers with different dtypes must not hit
-        # the other's cache (device_dense_shard keys by dtype for the same
-        # reason).
-        key = (i, n, self.mesh, self.entity_axis, self.design_dtype)
+        # the design dtype keys the cache — the built x tensors land in
+        # _x_dtype, and a dataset reused across solvers with different
+        # dtypes must not hit the other's cache (device_dense_shard keys by
+        # dtype for the same reason)
+        key = (i, self.mesh, self.entity_axis, self.design_dtype)
         cached = dataset._device_cache.get(key)
         if cached is None:
             cached = build()
@@ -267,17 +259,42 @@ class RandomEffectSolver:
 
     def _sweep_inputs(self, dataset: RandomEffectDataset, ks, n: int,
                       warm: Optional[RandomEffectModel], shard_dim: int):
-        """The sweep body's per-bucket inputs for buckets ``ks``:
-        ``(statics, warm_ctxs, cidxs, e_reals)`` (single home, shared by
-        train() and _warm_compile() so they can never pre-compile different
-        layouts)."""
+        """The sweep body's inputs for buckets ``ks``: ``(statics,
+        warm_ctxs, cidxs, e_reals, row_slots)``, the first four a bucket
+        each (single home, shared by train() and _warm_compile() so they
+        can never pre-compile different layouts)."""
         buckets = [(k, dataset.buckets[k]) for k in ks]
-        return (tuple(self._static_arrays(dataset, k, b, n)
+        return (tuple(self._static_arrays(dataset, k, b)
                       for k, b in buckets),
                 tuple(self._warm_ctx(dataset, k, b, warm, shard_dim)
                       for k, b in buckets),
                 tuple(self._coef_idx(dataset, k, b) for k, b in buckets),
-                tuple(b.n_entities for _, b in buckets))
+                tuple(b.n_entities for _, b in buckets),
+                self._row_slots(dataset, tuple(ks), n))
+
+    def _row_slots(self, dataset: RandomEffectDataset, ks: tuple, n: int):
+        """Where each of the ``n`` rows stands among the padded slots of
+        buckets ``ks``, their flat ``(entities, rows)`` layouts end to end:
+        ``(1, n)`` int32, as ``ops/design.py::lookup`` takes an index; a row
+        that none of them holds points one past the last slot. The inverse
+        of the buckets' sample indices, and the one index the sweep body
+        moves rows by, both ways: a row has one slot at the most, so a
+        gather or a scatter over every padded slot is a scatter or a gather
+        over the ``n`` rows. Built on the host once a dataset (a streaming
+        dataset builds a bucket's a sweep and drops it, as its statics)."""
+        key = ("rowslots", ks, n)
+        slots = dataset._device_cache.get(key)
+        if slots is None:
+            sizes = [dataset.buckets[k].sample_idx.size for k in ks]
+            host = np.full(n, sum(sizes), np.int32)
+            for k, base in zip(ks, np.cumsum([0] + sizes)):
+                flat = dataset.buckets[k].sample_idx.reshape(-1)
+                live = np.flatnonzero((flat >= 0) & (flat < n))
+                host[flat[live]] = base + live
+            slots = jnp.asarray(host[None, :])
+            if dataset.config.resident:
+                dataset._device_cache[key] = slots
+        return slots
 
     def _compact_arrays(self, dataset: RandomEffectDataset, i: int,
                         bucket: REBucket):
@@ -319,9 +336,12 @@ class RandomEffectSolver:
         if static is None:
             _, s, d = bucket.tensor_shape
             lane = DenseDesign(x=jax.ShapeDtypeStruct((s, d), self._x_dtype))
+            rows = int(np.count_nonzero(bucket.sample_idx >= 0))
             static = {
-                "rows": int(np.count_nonzero(bucket.sample_idx >= 0)),
-                "s_max": s, "dim": d,
+                "rows": rows, "s_max": s, "dim": d,
+                # what the sweep indexes for this bucket: its real rows on
+                # the way in and again on the way out, no padded slot
+                "moved_slots": 2 * rows,
                 "kernel": "pallas" if self._problem().objective
                 ._entity_kernel_serves(lane, s, d) else "closed_form"}
             dataset._device_cache[key] = static
@@ -429,7 +449,7 @@ class RandomEffectSolver:
         # always worth doing here (overlapped with the fixed-effect
         # stage); only the zero-data execution is skippable when this
         # process already compiled the program
-        statics, warm_ctxs, cidxs, e_reals = self._sweep_inputs(
+        statics, warm_ctxs, cidxs, e_reals, row_slots = self._sweep_inputs(
             dataset, range(len(buckets)), n, None, 0)
         sig = hash((self, n,
                     tuple((b.tensor_shape, b.n_entities) for b in buckets),
@@ -442,7 +462,7 @@ class RandomEffectSolver:
             out = _sweep_fused_jit(
                 self, jnp.zeros((n,), jnp.float32),
                 jnp.zeros((), jnp.float32), statics, warm_ctxs,
-                self._zero_coeffs(dataset), cidxs, e_reals)
+                self._zero_coeffs(dataset), cidxs, e_reals, row_slots)
             np.asarray(out[1][:1])  # D2H pull: waits for the program
             _PRECOMPILED.add(sig)
         object.__setattr__(dataset, "_warm_compiled", (self.mesh,))
@@ -502,12 +522,13 @@ class RandomEffectSolver:
                         and tuple(off_sharding.spec) else None)
 
         def sweep(ks):
-            statics, warm_ctxs, cidxs, e_reals = self._sweep_inputs(
-                dataset, ks, n, warm_start, shard_dim)
+            statics, warm_ctxs, cidxs, e_reals, row_slots = \
+                self._sweep_inputs(dataset, ks, n, warm_start, shard_dim)
             scores, payload, coeffs_unsorted, counts, evaluations = \
                 _sweep_fused_jit(
                     self, offsets_dev, lam_dev, statics, warm_ctxs,
-                    coeffs_warm, cidxs, e_reals, out_sharding=out_sharding)
+                    coeffs_warm, cidxs, e_reals, row_slots,
+                    out_sharding=out_sharding)
             for k, counts_k in zip(ks, counts):
                 self._record_solve(dataset, k, dataset.buckets[k], counts_k)
             return scores, payload, coeffs_unsorted, evaluations
@@ -708,58 +729,80 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
 
 
 def _sweep_fused_impl(solver, offsets_dev, lam, statics, warm_ctxs,
-                      coeffs_warm, cidxs, e_reals, out_sharding=None):
+                      coeffs_warm, cidxs, e_reals, row_slots,
+                      out_sharding=None):
     """The sweep body: one program for the sweep of the buckets it is
     given — all of a resident dataset's, one of a streaming dataset's —
-    and the only code that, per bucket, gathers residual offsets, gathers
-    warm starts from the previous sweep's coefficient table, solves,
-    computes margins and scatters them into the score vector (zero on every
-    other bucket's rows); plus the flat coefficient/variance payload for
-    the model's D2H, the device coefficient mirror (passive scoring), and
-    each bucket's counts for its ``game.re.solve`` span.
+    and the only code that moves the residual offsets into the buckets'
+    padded slots, per bucket gathers warm starts from the previous sweep's
+    coefficient table, solves and computes margins, and moves the margins
+    back into a score vector (zero on a row that none of these buckets
+    holds); plus the flat coefficient/variance payload for the model's D2H,
+    the device coefficient mirror (passive scoring), and each bucket's
+    counts for its ``game.re.solve`` span.
+
+    Rows move both ways by ``row_slots``, each row's slot among the
+    buckets' flat padded layouts end to end
+    (:meth:`RandomEffectSolver._row_slots`), because on the chip a random
+    access costs by the index, whatever it fetches, and a bucket's padded
+    slots are five to six times its rows (PERF.md, sections 5 and 6, PR
+    33). In: the ``n`` offsets are scattered to their slots, and a slot
+    that holds no row stays zero; gathering the offsets over every bucket's
+    padded ``(entities, rows)`` index moved the same values. Out: the
+    ``n`` scores are looked up among the margins at the same slots
+    (``ops/design.py::lookup``: whole 128-lane rows of its table gathered,
+    the lane picked); scattering every padded slot's margin to its row
+    moved the same values, after a sort of the slots. The warm start's and
+    the coefficient mirror's gathers, ``entities x dim`` elements a bucket,
+    go through the same look-up: from tables this small it runs at a third
+    of a scalar gather's time.
 
     ``coeffs_warm`` is sized to the dataset's full key-table length from
     sweep 0 (zeros — every ``found`` is False), so a single compilation
     serves the cold sweep and every warm sweep.
 
-    Statics are the fat 5-tuple per bucket — ``(x, labels, weights,
-    gather_idx, scatter_idx)`` — either uploaded from host fills or
-    materialized on device from the compact index maps
-    (:func:`_materialize_fat`); the sweep program is identical either
-    way, and gathering inside the program instead re-paid the gather
-    every solve (measured 3x on the 10M-row RE bench).
+    Statics are the fat 3-tuple per bucket — ``(x, labels, weights)`` —
+    either uploaded from host fills or materialized on device from the
+    compact index maps (:func:`_materialize_fat`); the sweep program is
+    identical either way.
     """
-    scores = jnp.zeros_like(offsets_dev)
+    sizes = [e_real * wt_d.shape[1] for (_, _, wt_d), e_real
+             in zip(statics, e_reals)]
+    # a row that no bucket holds points past the last slot: dropped here,
+    # and reading the zero appended below
+    slots_off = jnp.zeros((sum(sizes),), jnp.float32).at[row_slots[0]].set(
+        offsets_dev, mode="drop")
+    margins: list[jnp.ndarray] = []
     flat_w: list[jnp.ndarray] = []
     flat_v: list[jnp.ndarray] = []
     coef_parts: list[jnp.ndarray] = []
     counts: list[dict] = []
-    for statics_k, (pos_d, found_d), cidx, \
-            e_real in zip(statics, warm_ctxs, cidxs, e_reals):
-        x_d, lab_d, wt_d, idx_d, store_d = statics_k
-        # zero for padded rows: their weight is 0, and the margin must
-        # stay finite
-        boff = jnp.take(offsets_dev, idx_d.reshape(-1),
-                        mode="clip").reshape(idx_d.shape) * (wt_d > 0)
+    for (x_d, lab_d, wt_d), (pos_d, found_d), cidx, e_real, part in zip(
+            statics, warm_ctxs, cidxs, e_reals,
+            jnp.split(slots_off, np.cumsum(sizes)[:-1])):
+        # the mesh's pad lanes (past e_real) hold no row
+        boff = jnp.pad(part.reshape(e_real, -1),
+                       ((0, wt_d.shape[0] - e_real), (0, 0)))
+        # zero where the weight is: the margin must stay finite
+        boff = boff * (wt_d > 0)
+        # the join's positions lie in the table (model.py::key_join)
         w0 = jnp.where(
             found_d,
-            jnp.take(coeffs_warm, pos_d.reshape(-1),
-                     mode="clip").reshape(pos_d.shape),
+            lookup(coeffs_warm, pos_d.reshape(1, -1)).reshape(pos_d.shape),
             0.0).astype(jnp.float32)
         w_dev, variances, _conv, counts_k = _solve_bucket_jit(
             solver, x_d, lab_d, boff, wt_d, w0, lam)
         counts.append(counts_k)
-        margins = _margins_bucket(x_d, w_dev)[:e_real]
-        # dead rows carry index n, which mode="drop" discards (negative
-        # indices would WRAP, not drop)
-        scores = scores.at[store_d].set(margins, mode="drop")
+        margins.append(_margins_bucket(x_d, w_dev)[:e_real].reshape(-1))
         flat_w.append(w_dev[:e_real].reshape(-1))
         flat_v.append(jnp.asarray(variances)[:e_real].reshape(-1))
         coef_parts.append(
-            w_dev[:e_real].reshape(-1)[cidx].astype(jnp.float32))
+            lookup(flat_w[-1], cidx[None, :])[0].astype(jnp.float32))
+    scores = lookup(jnp.concatenate(
+        margins + [jnp.zeros((1,), jnp.float32)]), row_slots)[0]
     if out_sharding is not None:
         # keep the score vector in the caller's (e.g. data-axis) layout:
-        # without the constraint GSPMD replicates the scatter output,
+        # without the constraint GSPMD replicates the looked-up vector,
         # silently un-sharding the CD score decomposition
         # (tests/test_sharded_scores.py — ROADMAP item 5 prototype)
         scores = jax.lax.with_sharding_constraint(scores, out_sharding)
@@ -793,12 +836,12 @@ _sweep_fused_jit = profiling.profile_jit(
     static_argnames=("solver", "e_reals", "out_sharding"))
 
 
-@partial(jax.jit, static_argnames=("n", "S", "identity_cols"))
+@partial(jax.jit, static_argnames=("S", "identity_cols"))
 def _materialize_fat(shard_x, labels_g, weights_g, perm_d, counts_d, fi_d,
-                     *, n: int, S: int, identity_cols: bool = False):
+                     *, S: int, identity_cols: bool = False):
     """One device-side program turning compact index maps into the fat
-    bucket tensors ``(x, labels, weights, gather_idx, scatter_idx)`` — the
-    exact 5-tuple the host-fill path uploads, built from the shared dense
+    bucket tensors ``(x, labels, weights)`` — the exact 3-tuple the
+    host-fill path uploads, built from the shared dense
     shard image instead of shipped over the wire. Runs once per bucket per
     dataset (the caller caches the result). The (E, S) sample index is
     itself derived on device from the padding-free ``perm``/``counts``
@@ -826,8 +869,7 @@ def _materialize_fat(shard_x, labels_g, weights_g, perm_d, counts_d, fi_d,
              * rmask[:, :, None] * cmask[:, None, :])
     labels = labels_g[clip] * rmask
     weights = weights_g[clip] * rmask
-    store = jnp.where(rmask, idx_d, n)
-    return x, labels, weights, clip, store
+    return x, labels, weights
 
 
 def _shard_dim(dataset: RandomEffectDataset) -> int:

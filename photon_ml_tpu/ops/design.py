@@ -300,9 +300,9 @@ def _hot_tier(rows, cols, vals, n_rows: int, n_cols: int,
         hot_by_bin=by_bin, hot_entries=int(n_hot))
 
 
-#: lanes of a table row that :func:`_lookup` gathers whole
+#: lanes of a table row that :func:`lookup` gathers whole
 _LANES = 128
-#: table rows that one gather of :func:`_lookup` fetches (512 B each): 1.3 GB
+#: table rows that one gather of :func:`lookup` fetches (512 B each): 1.3 GB
 #: of them. What was measured, at column chunks of 16 (PERF.md, section 6, PR
 #: 32): gathered blocks of (16, 131072, 128) ran 4.4 times as long an index
 #: as blocks of (16, 163840, 128), which this constant gives. The cause is not
@@ -313,22 +313,25 @@ _LANES = 128
 _LOOKUP_ROWS = 5 << 19
 
 
-def _lookup(table: Array, idx: Array) -> Array:
+def lookup(table: Array, idx: Array) -> Array:
     """``table[idx]`` for a 1-D ``table`` and ``(C, M)`` indices that lie in
     range, ``M`` the long axis.
 
     Not ``jnp.take``: on the chip XLA serialises a gather of scalars, one
     element at a time whatever the table's size, and fetches whole rows of
-    128 lanes four times as fast an index (PERF.md, section 6, PR 32). So the
-    table is read as ``(T / 128, 128)``, the row ``idx >> 7`` of every index
-    is gathered and the lane ``idx & 127`` picked by comparison, ``M`` in
-    blocks so that the gathered rows of one block stay near a gigabyte
-    (``_LOOKUP_ROWS``). The indices stay ``(C, M)`` throughout: the long axis
-    last is the one the chip's tiles do not pad.
+    128 lanes four times as fast an index (PERF.md, section 6, PR 32), as
+    long as the compiler stages the table in VMEM: up to about 5M entries;
+    from 6M or more the same gather runs at 10 to 16 ns an index, as slow
+    as the scalars' (PERF.md, section 6, PR 33). So the table is read as
+    ``(T / 128, 128)``, the row ``idx >> 7`` of every index is gathered and
+    the lane ``idx & 127`` picked by comparison, ``M`` in blocks so that the
+    gathered rows of one block stay near a gigabyte (``_LOOKUP_ROWS``). The
+    indices stay ``(C, M)`` throughout: the long axis last is the one the
+    chip's tiles do not pad.
     """
     c, m = idx.shape[-2:]
     if idx.ndim != 2:
-        return jax.vmap(_lookup, in_axes=(None, 0))(table, idx)
+        return jax.vmap(lookup, in_axes=(None, 0))(table, idx)
     rows = jnp.pad(table, (0, -table.shape[0] % _LANES)).reshape(-1, _LANES)
     lane = jnp.arange(_LANES, dtype=idx.dtype)
 
@@ -451,7 +454,7 @@ class ChunkedSparseDesign:
         """``Σ_slot vals * table[idx]`` per chunk, for ``(M, C)`` chunks."""
         acc = jnp.promote_types(jnp.promote_types(vals.dtype, table.dtype),
                                 jnp.float32)
-        got = _lookup(table, jnp.swapaxes(idx, -1, -2))
+        got = lookup(table, jnp.swapaxes(idx, -1, -2))
         return jnp.sum((jnp.swapaxes(vals, -1, -2) * got).astype(acc),
                        axis=-2)
 
@@ -469,7 +472,7 @@ class ChunkedSparseDesign:
                     part[..., self.n_rows:], self.rrow[self.n_rows:],
                     num_segments=self.n_rows, indices_are_sorted=True)
             if self.hot_cols is not None:
-                coef = self.hot_vals * _lookup(w, self.hot_cols[None, :])[0]
+                coef = self.hot_vals * lookup(w, self.hot_cols[None, :])[0]
                 out = out + _planes_dot(self.hot_by_row,
                                         coef.reshape(-1, _WORD))
             return out

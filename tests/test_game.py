@@ -1309,6 +1309,129 @@ class TestOneSweepBody:
             started += np.count_nonzero(want)
         assert started == len(keys)
 
+    @staticmethod
+    def _scatter_sweep(solver, dataset, offsets, lam, warm, dim):
+        """The plain reference of the sweep's moves, as the sweep body made
+        them before it moved rows by their slots: a bucket at a time a
+        gather of the offsets over the padded ``(entities, rows)`` index,
+        the body's own solve and margins, and a scatter of every padded
+        slot's margin into the score vector, a dead slot's at ``n`` and
+        dropped."""
+        import jax
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.game import random_effect
+
+        n = len(offsets)
+        coeffs_warm = (solver._zero_coeffs(dataset) if warm is None
+                       else warm.coeffs_device)
+        inputs = []
+        for k, b in enumerate(dataset.buckets):
+            x, labels, weights = solver._static_arrays(dataset, k, b)
+            inputs.append((
+                x, labels, weights,
+                jnp.asarray(np.maximum(b.sample_idx, 0)),
+                jnp.asarray(np.where(b.sample_idx >= 0, b.sample_idx, n)),
+                *solver._warm_ctx(dataset, k, b, warm, dim)))
+
+        @jax.jit
+        def sweep(offsets, lam, inputs, coeffs_warm):
+            scores = jnp.zeros_like(offsets)
+            for x, labels, weights, idx, store, pos, found in inputs:
+                boff = jnp.take(offsets, idx.reshape(-1), mode="clip"
+                                ).reshape(idx.shape) * (weights > 0)
+                w0 = jnp.where(found, jnp.take(
+                    coeffs_warm, pos.reshape(-1), mode="clip"
+                ).reshape(pos.shape), 0.0).astype(jnp.float32)
+                w, *_ = random_effect._solve_bucket_jit(
+                    solver, x, labels, boff, weights, w0, lam)
+                scores = scores.at[store].set(
+                    random_effect._margins_bucket(x, w), mode="drop")
+            return scores
+
+        return np.asarray(sweep(jnp.asarray(offsets, jnp.float32),
+                                jnp.asarray(lam, jnp.float32), inputs,
+                                coeffs_warm))
+
+    @staticmethod
+    def _with_a_bucket_of_padding(dataset):
+        """``dataset`` and one more bucket that holds entities without a
+        row: dead slots only (what a mesh's pad lanes are)."""
+        import dataclasses
+
+        from photon_ml_tpu.game.data import REBucket
+
+        e, s, d = 2, 8, dataset.buckets[0].tensor_shape[2]
+        top = max(int(b.entity_ids.max()) for b in dataset.buckets)
+        empty = REBucket(
+            entity_ids=np.arange(top + 1, top + 1 + e, dtype=np.int64),
+            x=np.zeros((e, s, d), np.float32),
+            labels=np.zeros((e, s), np.float32), offsets_zero=True,
+            weights=np.zeros((e, s), np.float32),
+            sample_idx=np.full((e, s), -1, np.int64),
+            feature_index=np.full((e, d), -1, np.int64))
+        return dataclasses.replace(dataset,
+                                   buckets=[*dataset.buckets, empty])
+
+    @pytest.mark.parametrize("resident", [True, False],
+                             ids=["resident", "streaming"])
+    @pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+    def test_rows_moved_by_their_slots_equal_the_padded_moves(self, warm,
+                                                              resident):
+        """Bit for bit, with rows that no bucket holds (an entity's rows
+        past the cap: score 0), dead slots in every bucket and a bucket of
+        nothing else."""
+        data, _ = make_mixed_data(n=900, n_entities=17)
+        ds = self._with_a_bucket_of_padding(RandomEffectDataset.build(
+            "re", data, RandomEffectDatasetConfig(
+                "entityId", "re", active_data_upper_bound=120,
+                cache_device_buckets=resident)))
+        assert ds.config.resident == resident and len(ds.buckets) == 4
+        held = np.concatenate([b.sample_idx[b.sample_idx >= 0]
+                               for b in ds.buckets])
+        assert 0 < len(held) == len(np.unique(held)) < 900
+        assert all((b.sample_idx < 0).any() for b in ds.buckets)
+        solver = self._solver("NONE")
+        offsets = np.random.default_rng(5).normal(size=900).astype(
+            np.float32)
+        start = None
+        if warm:
+            start, _ = solver.train(ds, np.zeros(900, np.float32), lam=0.5,
+                                    dim=4)
+        _, scores = solver.train(ds, offsets, lam=0.5, warm_start=start,
+                                 dim=4)
+        scores = np.asarray(scores)
+        np.testing.assert_array_equal(
+            scores, self._scatter_sweep(solver, ds, offsets, 0.5, start, 4))
+        unheld = np.setdiff1d(np.arange(900), held)
+        assert not scores[unheld].any() and scores[held].all()
+
+    def test_the_row_slots_are_built_once_a_dataset(self):
+        """A resident dataset keeps one index of its rows' slots for its
+        buckets and ``n`` and a second sweep builds none; offsets of another
+        length get their own; a streaming dataset keeps none."""
+        data, _ = make_mixed_data(n=900, n_entities=17)
+        resident, streaming = self._datasets(data)
+        solver = self._solver("NONE")
+        kept = lambda ds: {k: v for k, v in ds._device_cache.items()
+                           if k[0] == "rowslots"}
+        zeros = np.zeros(900, np.float32)
+        solver.train(resident, zeros, lam=0.5, dim=4)
+        (key, first), = kept(resident).items()
+        assert key == ("rowslots", (0, 1, 2), 900)
+        solver.train(resident, zeros, lam=0.5, dim=4)
+        assert set(kept(resident)) == {key} and kept(resident)[key] is first
+        # seven rows more, which no bucket holds: their scores are zero
+        _, longer = solver.train(resident, np.zeros(907, np.float32),
+                                 lam=0.5, dim=4)
+        assert set(kept(resident)) == {key, ("rowslots", (0, 1, 2), 907)}
+        _, scores = solver.train(resident, zeros, lam=0.5, dim=4)
+        np.testing.assert_array_equal(np.asarray(longer)[:900],
+                                      np.asarray(scores))
+        assert not np.asarray(longer)[900:].any()
+        solver.train(streaming, zeros, lam=0.5, dim=4)
+        assert not kept(streaming)
+
     def test_streaming_sweep_records_a_solve_span_a_bucket(self):
         from photon_ml_tpu.telemetry import tracing
 

@@ -217,6 +217,8 @@ def test_a_traced_fit_records_one_solve_span_a_bucket(problem, records):
         for s in spans:
             e, smax, d = buckets[s["bucket"]].tensor_shape
             assert (s["lanes"], s["s_max"], s["dim"]) == (e, smax, d)
+            # its rows indexed on the way in and on the way out, no padding
+            assert s["moved_slots"] == 2 * s["rows"]
             assert s["kernel"] == "closed_form"  # no TPU here
             assert "unresolved" not in s
             assert 0 <= s["converged"] <= s["lanes"]

@@ -9,12 +9,13 @@ sharded formulation needs a working prototype).
 What this file proves on the 8-device virtual mesh:
 
 - The random-effect sweep accepts a DATA-SHARDED residual-offset vector
-  and returns a data-sharded score vector: the fused sweep's
-  ``jnp.zeros_like(offsets)`` inherits the sharding, the bucket gathers
-  (entity-grouped indices against the data-sharded operand) and the score
-  scatter are compiled by GSPMD with the resharding collectives
-  (all-gather of operand / all-to-all) inserted automatically — no code
-  changes in the solver, equality with the flat path to float tolerance.
+  and returns a data-sharded score vector: the fused sweep constrains its
+  score vector to the offsets' sharding, and the scatter of the offsets
+  into the buckets' slots (entity-grouped slots against the data-sharded
+  operand) and the scores' look-up among the margins are compiled by GSPMD
+  with the resharding collectives (all-gather of operand / all-to-all)
+  inserted automatically — no code changes in the solver, equality with
+  the flat path to float tolerance.
 - A full manual CD sweep (fixed + random effect) runs end-to-end with
   every score vector carrying ``P("data")`` sharding, equal to the flat
   sweep.
@@ -25,7 +26,7 @@ What this file proves on the 8-device virtual mesh:
 Measured overhead — a NEGATIVE result, recorded deliberately (8-device
 CPU mesh, 1e6 rows, 2000 entities, chained sweeps, min of 3):
 flat 1.99 s/sweep vs sharded 18.25 s/sweep = **9.2x slower**. GSPMD
-satisfies the entity-grouped bucket gather by all-gathering the sharded
+satisfied the entity-grouped bucket gather by all-gathering the sharded
 score vector and re-slicing after the scatter, so the sharded layout adds
 collectives without removing any memory pressure: per-chip peak still
 holds a full score vector transiently. CPU-mesh collective costs

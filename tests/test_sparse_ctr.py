@@ -333,15 +333,15 @@ def test_lookup_in_blocks(monkeypatch):
     rng = np.random.default_rng(4)
     table = jnp.asarray(rng.normal(size=1000), jnp.float32)  # 1000 % 128 != 0
     idx = jnp.asarray(rng.integers(0, 1000, size=(2, 2500)), jnp.int32)
-    np.testing.assert_array_equal(np.asarray(design._lookup(table, idx)),
+    np.testing.assert_array_equal(np.asarray(design.lookup(table, idx)),
                                   np.asarray(table)[np.asarray(idx)])
     few = idx[:, :300]  # under one block
-    np.testing.assert_array_equal(np.asarray(design._lookup(table, few)),
+    np.testing.assert_array_equal(np.asarray(design.lookup(table, few)),
                                   np.asarray(table)[np.asarray(few)])
     stacked = jnp.stack([idx, idx[::-1]])
-    np.testing.assert_array_equal(np.asarray(design._lookup(table, stacked)),
+    np.testing.assert_array_equal(np.asarray(design.lookup(table, stacked)),
                                   np.asarray(table)[np.asarray(stacked)])
     tables = jnp.stack([table, 2 * table])
-    got = jax.vmap(design._lookup, in_axes=(0, None))(tables, idx)
+    got = jax.vmap(design.lookup, in_axes=(0, None))(tables, idx)
     np.testing.assert_array_equal(np.asarray(got[1]),
                                   2 * np.asarray(table)[np.asarray(idx)])

@@ -38,23 +38,27 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def bucket_program(one_chip):
-    """The compiled text of one kernel bucket's solve. The objective's gate
-    asks for the backend's name, which is the CPU's here: the test answers
-    for the chip it compiles for."""
-    from photon_ml_tpu.game.random_effect import (
-        RandomEffectSolver,
-        _solve_bucket_impl,
-    )
+def _solver():
+    """The cells' random-effect solver: L-BFGS, history ``M``, L2."""
+    from photon_ml_tpu.game.random_effect import RandomEffectSolver
 
-    solver = RandomEffectSolver(
+    return RandomEffectSolver(
         task=TaskType.LOGISTIC_REGRESSION,
         config=GLMOptimizationConfiguration(
             regularization=L2Regularization,
             optimizer_config=OptimizerConfig(
                 max_iterations=25, tolerance=1e-6, history=M,
                 track_states=False)))
+
+
+@pytest.fixture(scope="module")
+def bucket_program(one_chip):
+    """The compiled text of one kernel bucket's solve. The objective's gate
+    asks for the backend's name, which is the CPU's here: the test answers
+    for the chip it compiles for."""
+    from photon_ml_tpu.game.random_effect import _solve_bucket_impl
+
+    solver = _solver()
     sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                               sharding=one_chip)
     # float32 as on the chip: the suite's 64-bit mode is not Mosaic's
@@ -128,6 +132,75 @@ def test_the_kernel_takes_the_bucket_entities_last(bucket_program):
     assert not re.search(rf"f32\[{D},(?:{LANES})\][^ ]* concatenate\(", body)
     assert not re.search(rf"f32\[{D},{S},(?:{LANES})\][^ ]* (?:copy|transpose)\(",
                          body)
+
+
+# --- the random-effect sweep's moves (PERF.md, PR 33) ------------------------
+SWEEP_ROWS = 3_000_000
+#: (entities, rows) of a kernel bucket and of two closed-form ones; the
+#: scores' look-up runs a whole block and a rest
+#: (``ops/design.py::_LOOKUP_ROWS``)
+SWEEP_BUCKETS = [(50_000, 56), (300, 9_000), (20, 140_000)]
+
+
+@pytest.fixture(scope="module")
+def sweep_program(one_chip):
+    """The compiled text of a resident coordinate's sweep, the body's own
+    inputs as ``RandomEffectSolver._sweep_inputs`` lays them."""
+    from photon_ml_tpu.game.random_effect import _sweep_fused_impl
+
+    solver = _solver()
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    statics = tuple((sds((e, s, D)), sds((e, s)), sds((e, s)))
+                    for e, s in SWEEP_BUCKETS)
+    warm_ctxs = tuple((sds((e, D), jnp.int32), sds((e, D), jnp.bool_))
+                      for e, _ in SWEEP_BUCKETS)
+    cidxs = tuple(sds((e * D,), jnp.int32) for e, _ in SWEEP_BUCKETS)
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.enable_x64(False):
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            _sweep_fused_impl,
+            static_argnames=("solver", "e_reals", "out_sharding")).lower(
+                solver, sds((SWEEP_ROWS,)), sds(()), statics, warm_ctxs,
+                sds((sum(e for e, _ in SWEEP_BUCKETS) * D,)), cidxs,
+                tuple(e for e, _ in SWEEP_BUCKETS),
+                sds((1, SWEEP_ROWS), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    return text
+
+
+def test_the_sweep_moves_rows_and_not_padded_slots(sweep_program):
+    """What the sweep indexes, it indexes by the row: one scatter (the
+    offsets into their slots) and its sort, each over the ``n`` rows, none
+    over a bucket's padded slots; and every gather (the scores' look-up,
+    the warm start's and the coefficient mirror's) fetches rows of 128
+    lanes: a gather of scalars runs an element at a time on the chip."""
+    moved = re.findall(r"= \(?[fs]32\[(\d+)\][^\n]* (sort|scatter)\(",
+                       sweep_program)
+    assert {kind for _, kind in moved} == {"sort", "scatter"}
+    assert len(moved) == 2
+    slots = sum(e * s for e, s in SWEEP_BUCKETS)
+    assert sorted(int(size) for size, _ in moved) == [SWEEP_ROWS, slots]
+    assert re.search(rf"s32\[{SWEEP_ROWS}\][^\n]* sort\(", sweep_program)
+    gathers = re.findall(
+        r"= f32\[([0-9,]+)\]\S* gather\([^\n]*slice_sizes={([0-9,]+)}",
+        sweep_program)
+    assert len(gathers) >= 2 + 2 * len(SWEEP_BUCKETS)
+    assert all(shape.endswith(",128") and fetched == "1,128"
+               for shape, fetched in gathers), gathers
+
+
+def test_the_sweep_holds_one_index_of_the_rows(sweep_program):
+    """The rows' slots come in as ``(1, n)``, the long axis last, and no
+    index of a bucket's ``(entities, rows)`` shape is left in the program
+    (``(50000, 56)`` with 56 in the 128-lane dimension is 2.3 times its
+    bytes)."""
+    assert re.search(rf"s32\[1,{SWEEP_ROWS}\]\S* parameter\(", sweep_program)
+    assert not re.search(rf"s32\[{SWEEP_ROWS},1\]{{1,0", sweep_program)
+    for e, s in SWEEP_BUCKETS:
+        assert not re.search(rf"s32\[{e},{s}\]\S* parameter\(", sweep_program)
 
 
 # --- the wide sparse design's evaluation (PERF.md, PR 32) --------------------

@@ -12,10 +12,11 @@ the loop, one for each trip of the loop's body, and (the nested form of
 ``optimize/lbfgs.py::minimize_lbfgs`` under ``vmap``) one for each trip of the
 line search's ``while`` inside it. An instruction of a loop's body runs once
 a trip, so a loop's trips are the count that most of its body's instructions
-share. Printed for every top-level loop of every traced unit that took a
-millisecond or more: its device time, outer trips, the line search's trips and
-time, passes, the entity kernel's events (one a pass inside the loop) and time,
-and the operations that take most of its time.
+share. Printed for every top-level loop of every traced unit that runs an
+evaluation (the sweep's other loops, a look-up's blocks among them, do not):
+its device time, outer trips, the line search's trips and time, passes, the
+entity kernel's events (one a pass inside the loop) and time, and the
+operations that take most of its time.
 """
 
 from __future__ import annotations
@@ -32,6 +33,10 @@ from benchmark import trace  # noqa: E402
 
 PROGRAMS = re.compile(r"^jit__(sweep_fused|solve_bucket)_impl")
 KERNEL = "fused_entity_value_and_grad"
+#: what an evaluation runs, by the trace's names: the entity kernel, or the
+#: closed form's two contractions. A top-level loop without one is no solve
+#: (the blocks of ``ops/design.py::lookup``, a scatter XLA wrote as a loop)
+EVALUATION = (KERNEL, "multiply_reduce_fusion")
 
 
 def _instruction(name: str) -> str:
@@ -59,7 +64,7 @@ def buckets(ops: list[trace.Interval]) -> list[dict]:
         name = _instruction(n)
         loop = trace.short(n) == "while"
         if not loops and loop:
-            current = {"seconds": (e - s) / 1e9,
+            current = {"seconds": (e - s) / 1e9, "solve": False,
                        "body": collections.Counter(), "inner": {},
                        "kernel_events": 0, "kernel_ns": 0, "events": []}
             out.append(current)
@@ -73,6 +78,7 @@ def buckets(ops: list[trace.Interval]) -> list[dict]:
             elif not loop:
                 current["inner"][loops[1]]["body"][name] += 1
             current["events"].append((s, e, n))
+            current["solve"] |= trace.short(n) in EVALUATION
             if trace.short(n) == KERNEL:
                 current["kernel_events"] += 1
                 current["kernel_ns"] += e - s
@@ -100,9 +106,8 @@ def main(argv) -> int:
                     if lo <= m[0] < hi and PROGRAMS.match(trace.short(m[2]))]
         for p, (ps, pe, _) in enumerate(programs):
             inside = [o for o in chip.ops if o[0] >= ps and o[1] <= pe]
-            for k, b in enumerate(buckets(inside)):
-                if b["seconds"] < 1e-3:  # a scatter, not a solve
-                    continue
+            solves = [b for b in buckets(inside) if b.pop("solve")]
+            for k, b in enumerate(solves):
                 print(json.dumps({"unit": u, "program": p,
                                   "program_s": (pe - ps) / 1e9,
                                   "bucket": k, **b}), flush=True)

@@ -9,7 +9,7 @@ the device-resident fat tensors actually break:
   compact path defers the (E,S,D) fills)
 - ``fat_mb``: bytes the device-resident fat tensors would occupy in HBM at
   f32 / bf16 (the ``_materialize_fat`` product: x (E,S,D) + labels/weights
-  (E,S) + 2 index maps)
+  (E,S))
 - ``slots/rows``: padding inflation of the chosen bucketing
 
 Run:  PYTHONPATH=/root/repo python tools/re_scaling_probe.py [--big]
