@@ -177,6 +177,7 @@ class FixedEffectCoordinate:
                 data, w0, jnp.asarray(self.lam, jnp.float32))
             solve.set(iterations=result.iterations,
                       evaluations=result.evaluations,
+                      hvps=result.hvps,
                       converged=result.converged)
         tracing.set_on_enclosing("cd.step", evaluations=result.evaluations)
         if tracing.enabled():

@@ -15,9 +15,9 @@ million random-effect entities.
 Optimizer dispatch follows the reference exactly: an L1/elastic-net
 regularization context selects OWLQN (the L1 part handled by orthant
 projection, never differentiated); TRON may be requested explicitly and uses
-exact autodiff Hessian-vector products; otherwise L-BFGS. The regularization
-weight ``lam`` is a *dynamic* scalar so a single XLA compilation serves the
-whole warm-start lambda sweep.
+the objective's closed-form Hessian-vector products; otherwise L-BFGS. The
+regularization weight ``lam`` is a *dynamic* scalar so a single XLA
+compilation serves the whole warm-start lambda sweep.
 """
 
 from __future__ import annotations
@@ -67,6 +67,17 @@ class GLMOptimizationConfiguration:
                 "TRON needs a twice-differentiable objective; L1/elastic-net "
                 "requires OWLQN (as in the reference)")
 
+    @property
+    def solver(self) -> OptimizerType:
+        """The minimizer a solve under this configuration runs: TRON when
+        asked for, OWL-QN whenever the regularization has an L1 part, else
+        L-BFGS (:meth:`OptimizationProblem.run`'s dispatch)."""
+        if self.optimizer == OptimizerType.TRON:
+            return OptimizerType.TRON
+        if self.regularization.has_l1:
+            return OptimizerType.OWLQN
+        return OptimizerType.LBFGS
+
 
 @dataclasses.dataclass(frozen=True)
 class OptimizationProblem:
@@ -91,7 +102,8 @@ class OptimizationProblem:
         l1, l2 = self._split(lam)
         fun = lambda w: self.objective.value_and_grad(w, data, l2)
         cfg = self.config.optimizer_config
-        if self.config.optimizer == OptimizerType.TRON:
+        solver = self.config.solver
+        if solver == OptimizerType.TRON:
             hvp = lambda w, v: self.objective.hvp(w, v, data, l2)
             # operator form only when it pays: the fused one-pass Hvp
             # kernel per CG product, d2 pass hoisted per outer iteration
@@ -101,7 +113,7 @@ class OptimizationProblem:
             hvp_at = ((lambda w: self.objective.hvp_operator(w, data, l2))
                       if prefers is not None and prefers(data) else None)
             return minimize_tron(fun, hvp, w0, cfg, hvp_at=hvp_at)
-        if self.config.regularization.has_l1:
+        if solver == OptimizerType.OWLQN:
             return minimize_owlqn(fun, w0, l1, cfg)
         return minimize_lbfgs(fun, w0, cfg)
 
@@ -122,8 +134,7 @@ class OptimizationProblem:
         objective under ``vmap``. OWL-QN and TRON batches are ``vmap(run)``,
         whose nested loops count no passes: ``None``.
         """
-        if (self.config.optimizer == OptimizerType.TRON
-                or self.config.regularization.has_l1):
+        if self.config.solver != OptimizerType.LBFGS:
             return jax.vmap(self.run, in_axes=(0, 0, None))(
                 data, w0, lam), None
         _, l2 = self._split(lam)

@@ -146,9 +146,11 @@ def train_glm_sweep(
     block shapes, not the model).
 
     Spans (``telemetry/tracing.py``): one ``glm.sweep{solves, warm_start,
-    design}`` around the whole call, one ``glm.solve{regularization_weight, iterations,
-    evaluations, converged}`` around each solve's dispatch, the last three
-    held as the result's device scalars and read only when the record is. A
+    design, optimizer}`` around the whole call, one
+    ``glm.solve{regularization_weight, iterations, evaluations, hvps,
+    converged}`` around each solve's dispatch, the last four
+    held as the result's device scalars and read only when the record is
+    (``hvps``: TRON's Hessian-vector products, zero from the others). A
     ``glm.solve`` span's ``seconds`` is the HOST's dispatch time, never the
     device's (the solves are dispatched back to back, nothing here waits for
     one): the device time of a solve is the ``jit_run`` event of a profiler
@@ -172,7 +174,8 @@ def train_glm_sweep(
     out: list[TrainedModel] = []
     with tracing.span("glm.sweep", solves=len(regularization_weights),
                       warm_start=bool(warm_start),
-                      design=design_kind(data.design)):
+                      design=design_kind(data.design),
+                      optimizer=config.solver.name):
         # the eager problem serves compute_variances below; building it is
         # also where a concrete reg_mask is held to 0/1 (the traced one
         # inside the compiled solve cannot be)
@@ -205,6 +208,7 @@ def train_glm_sweep(
                              normalization, reg_mask)
                 solve.set(iterations=result.iterations,
                           evaluations=result.evaluations,
+                          hvps=result.hvps,
                           converged=result.converged)
             w_solved = fault_value("optimizer.step", result.w,
                                    regularization_weight=float(lam))

@@ -511,6 +511,9 @@ def fused_hvp(x, v, d2w, *, block_rows: int | None = None,
     itemsize = jnp.dtype(x.dtype).itemsize
     out = pl.pallas_call(
         _hvp_kernel,
+        # the operation's name in a profiler trace, as the kernel above has
+        # its own (benchmark/metrics/hvp_kernel_roofline_pct.json)
+        name="fused_hvp",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((b, d), lambda i: (i, 0), memory_space=pltpu.VMEM),

@@ -68,7 +68,10 @@ class OptimizerResult:
     ``evaluations`` counts the calls of the value-and-gradient function the
     solve made, the one at ``w0`` included: ``iterations + 1`` when no line
     search (or trust region) rejected a trial point, more by one for each
-    rejected one. TRON's Hessian-vector products are not evaluations.
+    rejected one. TRON's Hessian-vector products are not evaluations:
+    they are ``hvps``, the products the solve's conjugate-gradient loops
+    made (each one pass over the design, as an evaluation is), zero from
+    every minimizer that makes none.
     """
 
     w: Array
@@ -79,6 +82,7 @@ class OptimizerResult:
     converged: Array  # bool scalar
     values: Array
     grad_norms: Array
+    hvps: Array  # int32 scalar
 
 
 def init_trace(config: OptimizerConfig, f0: Array, gnorm0: Array) -> tuple[Array, Array]:

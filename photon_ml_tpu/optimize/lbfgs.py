@@ -227,6 +227,7 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
         iterations=final.it, evaluations=final.evals,
         converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
+        hvps=jnp.zeros_like(final.it),
     )
 
 
@@ -460,4 +461,5 @@ def minimize_lbfgs_lanes(evaluate: Callable[[Array], tuple[Array, Array]],
         iterations=final.it, evaluations=final.evals,
         converged=final.converged,
         values=final.values.T, grad_norms=final.grad_norms.T,
+        hvps=jnp.zeros_like(final.it),
     ), trips + 1

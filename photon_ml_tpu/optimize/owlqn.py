@@ -149,6 +149,7 @@ def minimize_owlqn(fun: ValueAndGrad, w0: Array, l1_weight,
         iterations=final.it, evaluations=final.evals,
         converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
+        hvps=jnp.zeros_like(final.it),
     )
 
 

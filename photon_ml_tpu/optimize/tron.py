@@ -5,9 +5,13 @@ port of LIBLINEAR's TRON). Same structure — an outer trust-region loop whose
 radius adapts via the LIBLINEAR constants (eta0/1/2, sigma1/2/3), and an inner
 Steihaug conjugate-gradient solve that touches the Hessian **only through
 Hessian-vector products** — but both loops are nested ``lax.while_loop``s
-compiled into one XLA program (SURVEY.md §7 hard part #4), and the Hvp comes
-from forward-over-reverse autodiff (:meth:`GLMObjective.hvp`) instead of a
-hand-written ``HessianVectorAggregator``.
+compiled into one XLA program (SURVEY.md §7 hard part #4). The product is the
+caller's: for a GLM the closed form ``Xᵀ(d2 ∘ (Xv)) + λv`` of
+:meth:`GLMObjective.hvp_operator` (the counterpart of the reference's
+``HessianVectorAggregator``; nothing is differentiated), whose weights ``d2``
+are computed once an outer iteration and whose two contractions are one read
+of the design in the Pallas kernel ``ops/pallas_glm.py::fused_hvp`` where
+that serves the design. Every product is counted: ``OptimizerResult.hvps``.
 
 On a sharded mesh each Hvp carries one ``psum``, so the inner CG is k
 collectives back-to-back on ICI — the pattern that replaces the reference's
@@ -43,7 +47,9 @@ _CG_TOL = 0.1  # inner CG stops at ||r|| <= 0.1 * ||g||
 def _trcg(hvp, g: Array, delta: Array, max_cg: int, active: Array):
     """Steihaug truncated CG: approximately solve H s = -g within ||s||<=delta.
 
-    Returns ``(s, at_boundary, prered)`` where ``prered = -(g.s + 0.5 s.Hs)``
+    Returns ``(s, at_boundary, prered, products)`` where ``products`` (int32)
+    is the loop's trip count, one Hessian-vector product a trip, and
+    ``prered = -(g.s + 0.5 s.Hs)``
     is the quadratic-model reduction, tracked incrementally from CG internals
     (interior step: q -= 0.5*alpha*r.r; boundary step: q += -tau*r.r +
     0.5*tau^2*p.Hp, using the invariant r.p = r.r) so the outer loop never
@@ -52,7 +58,9 @@ def _trcg(hvp, g: Array, delta: Array, max_cg: int, active: Array):
     tolerance masking keeps the loop shape static for XLA. ``active`` is the
     outer loop's condition for this solve: under ``vmap`` the CG loop runs
     while any lane's is unfinished, and a lane whose solve has ended starts
-    it done (its step is discarded with the rest of its iteration).
+    it done (its step is discarded with the rest of its iteration, and it
+    counts no product: a batched ``while_loop`` holds the state of a lane
+    whose own condition is false, ``i`` included).
     """
     cg_tol = _CG_TOL * jnp.linalg.norm(g)
 
@@ -96,7 +104,7 @@ def _trcg(hvp, g: Array, delta: Array, max_cg: int, active: Array):
             jnp.int32(0), (~active) | (jnp.linalg.norm(r0) <= cg_tol))
     s, r, p, rr, q, i, done = lax.while_loop(cond, body, init)
     at_boundary = jnp.linalg.norm(s) >= delta * (1.0 - 1e-6)
-    return s, at_boundary, -q
+    return s, at_boundary, -q, i
 
 
 def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
@@ -119,7 +127,7 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
 
     init = _State(
         w=w0, f=f0, g=g0, delta=gnorm0,
-        it=jnp.int32(0), evals=jnp.int32(1),
+        it=jnp.int32(0), evals=jnp.int32(1), hvps=jnp.int32(0),
         converged=gnorm0 <= tol, failed=jnp.asarray(False),
         values=values, grad_norms=gnorms,
     )
@@ -129,8 +137,8 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
 
     def body(s):
         op = hvp_at(s.w) if hvp_at is not None else (lambda v: hvp(s.w, v))
-        step, at_boundary, prered = _trcg(op, s.g, s.delta,
-                                          config.cg_max_iterations, cond(s))
+        step, at_boundary, prered, products = _trcg(
+            op, s.g, s.delta, config.cg_max_iterations, cond(s))
         snorm = jnp.linalg.norm(step)
         w_new = s.w + step
         f_new, g_new = fun(w_new)
@@ -179,7 +187,8 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
             f=jnp.where(accept, f_new, s.f),
             g=jnp.where(accept, g_new, s.g),
             delta=delta, it=it,
-            evals=s.evals + 1,  # the one call of ``fun`` above; Hvps apart
+            evals=s.evals + 1,  # the one call of ``fun`` above
+            hvps=s.hvps + products,
             converged=accept & (jnp.linalg.norm(g_new) <= tol),
             failed=stuck,
             values=values, grad_norms=gnorms,
@@ -191,6 +200,7 @@ def minimize_tron(fun: ValueAndGrad, hvp: Hvp, w0: Array,
         iterations=final.it, evaluations=final.evals,
         converged=final.converged,
         values=final.values, grad_norms=final.grad_norms,
+        hvps=final.hvps,
     )
 
 
@@ -203,6 +213,7 @@ class _State:
     delta: Array
     it: Array
     evals: Array
+    hvps: Array
     converged: Array
     failed: Array
     values: Array

@@ -111,10 +111,13 @@ def test_vmapped_lanes_equal_the_lanes_solved_one_by_one(name):
         # batched contraction sums in another order than a lane's own)
         assert int(batched.iterations[e]) == int(alone.iterations), e
         assert int(batched.evaluations[e]) == int(alone.evaluations), e
+        assert int(batched.hvps[e]) == int(alone.hvps), e
         assert bool(batched.converged[e]) == bool(alone.converged), e
         np.testing.assert_allclose(batched.w[e], alone.w, rtol=1e-9,
                                    atol=1e-12)
     assert int(batched.iterations[5]) == 0 and bool(batched.converged[5])
+    assert int(batched.hvps[5]) == 0
+    assert (int(jnp.sum(batched.hvps)) > 0) == (name == "tron")
     if name != "tron":  # TRON has no line search to fail
         assert int(batched.iterations[4]) == 1
         assert int(batched.evaluations[4]) == 2 + CONFIG.max_line_search
@@ -198,6 +201,10 @@ def test_a_finished_lane_adds_no_pass_to_its_batch(name):
     assert int(pair.evaluations[0]) == int(alone.evaluations)
     assert pair_counter.calls == alone_calls
     assert products.calls == alone_products
+    # and the lanes' own counts: the real lane's as alone (for TRON the
+    # products the batch made), the finished lane's none
+    assert int(pair.hvps[0]) == int(alone.hvps) == alone_products
+    assert int(pair.hvps[1]) == 0
 
 
 def test_unbatched_lbfgs_is_bit_for_bit_the_parents():

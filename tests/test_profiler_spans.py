@@ -33,7 +33,7 @@ from photon_ml_tpu.glm import training
 from photon_ml_tpu.glm.problem import GLMOptimizationConfiguration
 from photon_ml_tpu.ops.design import DenseDesign
 from photon_ml_tpu.ops.objective import GLMData
-from photon_ml_tpu.ops.regularization import L2Regularization
+from photon_ml_tpu.ops.regularization import L1Regularization, L2Regularization
 from photon_ml_tpu.optimize import (
     OptimizerConfig,
     minimize_lbfgs,
@@ -43,7 +43,7 @@ from photon_ml_tpu.optimize import (
 from photon_ml_tpu.telemetry import profiling, tracing
 from photon_ml_tpu.telemetry.metrics import MetricsRegistry
 from photon_ml_tpu.telemetry.tracing import Tracer
-from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.types import OptimizerType, TaskType
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
@@ -236,6 +236,7 @@ def test_train_glm_sweep_span_tree(traced):
     by_id = {r["span_id"]: r for r in records}
     (sweep,) = [r for r in records if r["name"] == "glm.sweep"]
     assert sweep["solves"] == len(WEIGHTS) and sweep["warm_start"] is True
+    assert sweep["optimizer"] == "LBFGS"
     solves = [r for r in records if r["name"] == "glm.solve"]
     assert [s["regularization_weight"] for s in solves] \
         == sorted(WEIGHTS, reverse=True)
@@ -244,6 +245,7 @@ def test_train_glm_sweep_span_tree(traced):
         assert s["iterations"] == int(t.result.iterations)
         assert s["evaluations"] == int(t.result.evaluations)
         assert s["evaluations"] >= s["iterations"] + 1
+        assert s["hvps"] == 0 and type(s["hvps"]) is int
         assert s["converged"] is bool(t.result.converged)
     (compiled,) = [r for r in records if r["name"] == "jit.compile"]
     assert compiled["fn"] == "glm.sweep_solve"
@@ -257,6 +259,41 @@ def test_train_glm_sweep_span_tree(traced):
         at = by_id[at["parent_id"]]
         ancestors.append(at["name"])
     assert ancestors == ["glm.solve", "glm.sweep"]
+
+
+@pytest.mark.parametrize("optimizer, regularization, named", [
+    (OptimizerType.TRON, L2Regularization, "TRON"),
+    (OptimizerType.LBFGS, L1Regularization, "OWLQN"),
+])
+def test_sweep_names_its_minimizer_and_solves_carry_their_products(
+        optimizer, regularization, named):
+    """``glm.sweep{optimizer}`` is the minimizer the configuration selects
+    (OWL-QN whenever the regularization has an L1 part), and a TRON solve's
+    ``glm.solve{hvps}`` is its result's count of Hessian-vector products."""
+    data = _glm_data()
+    records = []
+    untap = tracing.GLOBAL_TRACER.add_tap(records.append)
+    try:
+        trained = training.train_glm_sweep(
+            TaskType.LOGISTIC_REGRESSION, data, WEIGHTS,
+            GLMOptimizationConfiguration(
+                optimizer=optimizer, regularization=regularization,
+                optimizer_config=OptimizerConfig(max_iterations=15,
+                                                 cg_max_iterations=20)))
+        tracing.flush()
+    finally:
+        untap()
+    (sweep,) = [r for r in records if r["name"] == "glm.sweep"]
+    assert sweep["optimizer"] == named
+    solves = [r for r in records if r["name"] == "glm.solve"]
+    assert len(solves) == len(WEIGHTS)
+    for s, t in zip(solves, trained):
+        assert s["hvps"] == int(t.result.hvps) and type(s["hvps"]) is int
+        if named == "TRON":
+            assert s["iterations"] <= s["hvps"] <= 20 * s["iterations"]
+            assert s["evaluations"] == s["iterations"] + 1
+        else:
+            assert s["hvps"] == 0
 
 
 # --- without a profiler ------------------------------------------------------

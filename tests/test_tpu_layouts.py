@@ -320,3 +320,87 @@ def test_busy_bins_planes_are_read_in_one_pass_a_side(one_chip):
     assert "design.matvec/scatter-add" in text
     assert re.search(rf"f32\[{mr - n}(,1)?\]", text)
     assert program.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+# --- the fixed effect's TRON solve (PERF.md, PR 35) --------------------------
+TRON_ROWS, TRON_DIM = 1_500_000, 1024  # the cell glm_tron_1024.lambda_path's
+
+
+@pytest.fixture(scope="module")
+def tron_program(one_chip):
+    """The compiled text of ``OptimizationProblem.run`` under TRON at the
+    benchmark cell's shapes and settings (nothing of that size is held: the
+    arguments are shapes)."""
+    from photon_ml_tpu.glm.training import build_problem
+    from photon_ml_tpu.ops.design import DenseDesign
+    from photon_ml_tpu.ops.objective import GLMData
+    from photon_ml_tpu.types import OptimizerType
+
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    config = GLMOptimizationConfiguration(
+        optimizer=OptimizerType.TRON, regularization=L2Regularization,
+        optimizer_config=OptimizerConfig(max_iterations=15, tolerance=1e-5,
+                                         cg_max_iterations=20))
+    data = GLMData(design=DenseDesign(x=sds(TRON_ROWS, TRON_DIM)),
+                   labels=sds(TRON_ROWS), offsets=sds(TRON_ROWS),
+                   weights=sds(TRON_ROWS))
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.enable_x64(False):
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            build_problem(TaskType.LOGISTIC_REGRESSION, config).run).lower(
+                data, sds(TRON_DIM), sds()).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def _bodies(text):
+    """Per ``while`` of the module, the text of its body and of every
+    computation the body calls."""
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+    out = []
+    for body in re.findall(r" while\(.*body=%?([\w.\-]+)", text):
+        seen, todo = {}, [body]
+        while todo:
+            name = todo.pop()
+            if name not in seen:
+                seen[name] = computations[name]
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                    seen[name])
+        out.append("\n".join(seen.values()))
+    return out
+
+
+def test_the_tron_solve_holds_both_kernels_and_no_copy_of_the_design(
+        tron_program):
+    """Conjugate gradients inside the trust region's loop: two ``while``s,
+    ``fused_hvp`` in the inner one's body, ``fused_value_and_grad`` in the
+    outer one's (and once before it); the design reaches every call as the
+    program's own argument in one layout; and no ``copy``, ``pad`` or
+    ``transpose`` makes an array of the design's size anywhere, so none
+    inside a loop's body (PRs 29 and 31 each found one there)."""
+    text, memory = tron_program
+    design = rf"f32\[{TRON_ROWS},{TRON_DIM}\]"
+    calls = re.findall(
+        r"%(fused_\w+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*", text)
+    assert sorted(calls) == ["fused_hvp", "fused_value_and_grad",
+                             "fused_value_and_grad"]
+    for line in re.findall(
+            r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text):
+        assert re.search(r"operand_layout_constraints=\{" + design
+                         + r"\{1,0\}", line)
+    assert set(re.findall(design + r"\{([0-9,]+):", text)) == {"1,0"}
+    bodies = _bodies(text)
+    assert len(bodies) == 2
+    inner, outer = sorted(bodies, key=len)
+    assert "%fused_hvp" in inner and "%fused_value_and_grad" not in inner
+    assert "%fused_value_and_grad" in outer and "%fused_hvp" in outer
+    made = re.findall(rf"= {design}\S* ([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "broadcast",
+                         "multiply"}, set(made)
+    # broadcast and multiply: inside the fusion that is the curvature pass's
+    # margins (a multiply-reduce over the design, nothing of its size stored)
+    assert memory.temp_size_in_bytes < 64 * 2**20
