@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Union
 
 import jax
@@ -166,48 +167,56 @@ def _run_starts(keys, *, n_keys):
         keys, jnp.arange(n_keys + 1, dtype=jnp.int32)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks", "one_each"))
-def _chunk_runs(other, vals, starts, *, chunk, n_chunks, one_each=False):
+def _slots_of(flat, at, live):
+    """``flat[at]`` where ``live``, else 0, for ``(slots, M)`` positions, as
+    ``(M, slots)``. Slots-major while it is made: the long axis last is the
+    one the chip's tiles do not pad; the transposition at the end is free, a
+    result of ``(M, slots)`` being held long axis last too."""
+    if not flat.shape[0]:  # no entries: every slot is padding
+        return jnp.zeros(at.shape, flat.dtype).T
+    got = jnp.take(flat, at.reshape(-1), axis=0).reshape(at.shape)
+    return jnp.where(live, got, jnp.zeros((), flat.dtype)).T
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks", "skip"))
+def _chunk_runs(other, vals, starts, *, chunk, n_chunks, skip=0):
     """Cut every key's run into rows of ``chunk`` slots: ``(values, other
     ids, key of each row)`` with ``n_chunks`` rows, a key of k entries taking
-    ceil(k / chunk) of them, in key order. With ``one_each`` every key, one
-    without entries too, has its first row at its own index (row ``k`` is key
-    ``k``), and the rows a key takes beyond its first follow those, in key
-    order. A slot past its run's end holds value 0 and id 0. Every row reads
-    ``chunk`` neighbours of the ordered entries."""
+    ceil(k / chunk) of them, in key order; with ``skip``, of every run what
+    follows its first ``skip`` entries. A slot past its run's end holds value
+    0 and id 0. Every row reads ``chunk`` neighbours of the ordered
+    entries."""
     counts = starts[1:] - starts[:-1]
+    begin = starts[:-1] + jnp.minimum(counts, skip)
+    counts = jnp.maximum(counts - skip, 0)
     n_keys = counts.shape[0]
     per_key = (counts + (chunk - 1)) // chunk
-    if one_each:
-        per_key = jnp.maximum(per_key, 1) - 1  # rows beyond a key's first
     last = jnp.cumsum(per_key, dtype=jnp.int32)  # a key's rows end here
     row = jnp.arange(n_chunks, dtype=jnp.int32)
-    if one_each:
-        over = row - n_keys  # a row's place among the rows beyond the first
-        key = jnp.searchsorted(last, jnp.maximum(over, 0),
-                               side="right").astype(jnp.int32)
-        key = jnp.minimum(key, n_keys - 1)
-        nth = jnp.where(over < 0, 0, over - (last - per_key)[key] + 1)
-        key = jnp.where(over < 0, row, key)
-    else:
-        key = jnp.searchsorted(last, row, side="right").astype(jnp.int32)
-        key = jnp.minimum(key, n_keys - 1)
-        nth = row - (last - per_key)[key]
-    within = nth * chunk  # entries of the key before the row
-    # slots-major, (chunk, n_chunks), while it is made: the long axis last
-    # is the one the chip's tiles do not pad; the transposition at the end
-    # is free, a result of (n_chunks, chunk) being held long axis last too
+    key = jnp.searchsorted(last, row, side="right").astype(jnp.int32)
+    key = jnp.minimum(key, n_keys - 1)
+    within = (row - (last - per_key)[key]) * chunk  # the key's entries before
     lane = jnp.arange(chunk, dtype=jnp.int32)[:, None]
     live = lane < (counts[key] - within)[None, :]
-    at = jnp.where(live, (starts[key] + within)[None, :] + lane, 0)
+    at = jnp.where(live, (begin[key] + within)[None, :] + lane, 0)
+    return _slots_of(vals, at, live), _slots_of(other, at, live), key
 
-    def rows_of(flat):
-        if not flat.shape[0]:  # no entries: every slot is padding
-            return jnp.zeros(at.shape, flat.dtype).T
-        got = jnp.take(flat, at.reshape(-1), axis=0).reshape(at.shape)
-        return jnp.where(live, got, jnp.zeros((), flat.dtype)).T
 
-    return rows_of(vals), rows_of(other), key
+@functools.partial(jax.jit, static_argnames=("chunk", "fold"))
+def _first_chunks(other, vals, starts, *, chunk, fold):
+    """Every key's first ``chunk`` entries, ``fold`` keys a row: ``(values,
+    other ids)`` of ``(m, fold * chunk)``, ``m = ceil(keys / fold)``, key
+    ``k * m + j`` in row ``j`` from slot ``k * chunk``. A slot past its run's
+    end, or of a key past the last, holds value 0 and id 0."""
+    n = starts.shape[0] - 1
+    m = -(-n // fold)
+    # (fold * chunk, m): what ``a`` holds for each slot's key
+    spread = lambda a: jnp.repeat(jnp.pad(a, (0, fold * m - n)).reshape(
+        fold, m), chunk, axis=0)
+    lane = jnp.tile(jnp.arange(chunk, dtype=jnp.int32), fold)[:, None]
+    live = lane < spread(starts[1:] - starts[:-1])
+    at = jnp.where(live, spread(starts[:-1]) + lane, 0)
+    return _slots_of(vals, at, live), _slots_of(other, at, live)
 
 
 @functools.partial(jax.jit, static_argnames=("n_keys",))
@@ -306,10 +315,14 @@ _LANES = 128
 #: of them. What was measured, at column chunks of 16 (PERF.md, section 6, PR
 #: 32): gathered blocks of (16, 131072, 128) ran 4.4 times as long an index
 #: as blocks of (16, 163840, 128), which this constant gives. The cause is not
-#: known: the row side's blocks, (40, 65536, 128), are a power of two as well
-#: and ran at the full rate. The other chunk widths (8 to 128: blocks of
-#: 327,680 to 20,480) are compiled for the chip in tests/test_tpu_layouts.py
-#: and have not been timed (ROADMAP S0)
+#: known: the row side's blocks of a 1M-entry table, (40, 65536, 128), are a
+#: power of two as well and run at the full rate. Timed on the chip since
+#: (PERF.md, section 6, PR 36, call 1), the row side's look-ups from the
+#: sparse cell's 1M-entry coefficients, 50M-80M indices: chunks 16, 12, 11,
+#: 10 (as 16 x 5M, 12 x 5M, 24 x 2.5M, 88 x 625,000, 11 x 5M, 40 x 1.25M)
+#: gathered at 1.76-1.80 ns an index and picked their lanes at 0.68-0.88;
+#: this constant's blocks and those of 1 << 21, 3 << 20 and 5 << 18 were
+#: within 4% of one another there
 _LOOKUP_ROWS = 5 << 19
 
 
@@ -354,6 +367,19 @@ def lookup(table: Array, idx: Array) -> Array:
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
+#: sublanes of the chip's (8, 128) tile of 32-bit values: a ``(M, C)`` chunk
+#: array lies with C in them, padded to a multiple of 8
+_SUBLANES = 8
+#: the widest chunk a build makes
+_MAX_CHUNK = 128
+#: what :meth:`ChunkedSparseDesign.row_widths` weighs, in ns: a stored slot
+#: of a row chunk (its whole table row gathered, 1.77-1.80, and the lane
+#: picked, 0.68-0.88) and an overflow chunk's sum through the segment-sum
+#: (8.76-8.98), as the chip ran them from a 1M-entry table (PERF.md, section
+#: 6, PR 36, call 1)
+_SLOT_NS = 2.55
+_SUM_NS = 8.8
+
 #: bits of a word of the busy bins' planes
 _WORD = 32
 #: a bin is busy where it holds an entry in one row of this many or more:
@@ -394,13 +420,24 @@ class ChunkedSparseDesign:
     - col-major: ``(Mc, C)`` values/row-ids with one col id per chunk —
       the gradient transpose the same way into ``d`` bins.
 
-    Chunk padding carries ``value = 0`` (contributes nothing). The chunk
-    width trades padding (small C) against scatter length (large C); the
-    builder defaults to the per-key median rounded to a multiple of 8,
-    clamped to [8, 128]. 2x memory vs CsrDesign — the price of replacing
-    both big scatters. On the chip a ``(M, C)`` array lies with ``M``, its
-    long axis, in the lanes (C = 8 to 128 there would be padded to 128), so
-    the build and the contractions walk it slots-major.
+    Chunk padding carries ``value = 0`` (contributes nothing). A chunk
+    width trades padding (wide) against segment-sum length (narrow). The
+    column side's width is :meth:`default_chunk`, the per-key median
+    rounded to a multiple of 8. The row side keeps every row's first chunk
+    at the row's own place (``fvals``: those chunk sums are margins, and
+    only the chunks a row fills beyond its first go through the
+    segment-sum), and takes its two widths from the rows' counts
+    (:meth:`row_widths`): the sparse cell's rows, 9 entries at the mean
+    after the planes, keep first chunks of 10 and overflow chunks of 2,
+    52.5M slots where chunks of 16 stored 80.0M (0.150 s a ``matvec`` where
+    it took 0.214 on the chip: PERF.md, section 6, PR 36). 2x memory vs
+    CsrDesign — the price of replacing both big scatters. On the chip a
+    ``(M, C)`` array lies with ``M``, its long axis, in the lanes and C in
+    sublanes padded to a multiple of 8, so the build and the contractions
+    walk it slots-major, and first chunks C wide are held ``fold`` rows a
+    lane column, ``fold x C`` a multiple of 8: their tiles hold no padding
+    (chunks of 10 would fill 16 sublanes; the chip's look-ups took the same
+    time an index either way, so the fold saves memory alone).
 
     A look-up costs the same whatever it fetches (nanoseconds an index), so
     a design whose entries crowd into a few bins (hashed one-hot features: a
@@ -419,18 +456,20 @@ class ChunkedSparseDesign:
 
     rvals: Array  # (Mr, C) f32
     rcols: Array  # (Mr, C) int32
-    rrow: Array  # (Mr,) int32 — row id per chunk
+    rrow: Array  # (Mr,) int32 — row id per chunk (non-decreasing)
     cvals: Array  # (Mc, C) f32
     crows: Array  # (Mc, C) int32
     ccol: Array  # (Mc,) int32 — col id per chunk (non-decreasing)
     n_rows: int = dataclasses.field(metadata=dict(static=True))
     n_cols: int = dataclasses.field(metadata=dict(static=True))
-    #: chunk ``i`` is row ``i``'s first, for every row, and the chunks past
-    #: ``n_rows`` (non-decreasing in ``rrow``) belong to the rows that hold
-    #: more than a chunk is wide: the first ``n_rows`` chunk sums ARE margins,
-    #: and only the others go through a segment-sum
-    rows_first: bool = dataclasses.field(
-        default=False, metadata=dict(static=True))
+    #: every row's first chunk, or None: ``fold`` rows a lane column, row
+    #: ``k * m + j`` in slots ``k * C`` to ``(k + 1) * C`` of column ``j``
+    #: (``m`` columns), so that ``fold * C`` fills whole tiles of 8
+    #: sublanes. Their chunk sums ARE margins; the ``r*`` chunks then hold
+    #: only what rows keep beyond their first chunk (:meth:`layout`)
+    fvals: Array | None = None  # (m, fold * C) f32
+    fcols: Array | None = None  # (m, fold * C) int32
+    fold: int = dataclasses.field(default=1, metadata=dict(static=True))
     #: the busy bins, or None: their ids and the one value each one's entries
     #: carry (``(K,)``; unused places hold value 0), and their planes: bit
     #: ``k % 32`` of ``hot_by_row[k // 32, i]`` and bit ``i % 32`` of
@@ -449,28 +488,37 @@ class ChunkedSparseDesign:
     def dim(self) -> int:
         return self.n_cols
 
+    @property
+    def rows_first(self) -> bool:
+        """Every row's first chunk at the row's own place (``fvals``)."""
+        return self.fvals is not None
+
     @staticmethod
-    def _chunk_sums(vals: Array, idx: Array, table: Array) -> Array:
-        """``Σ_slot vals * table[idx]`` per chunk, for ``(M, C)`` chunks."""
+    def _chunk_sums(vals: Array, idx: Array, table: Array,
+                    fold: int = 1) -> Array:
+        """``Σ_slot vals * table[idx]`` per chunk, for ``(M, C)`` chunks;
+        with ``fold``, ``fold`` chunks a row, the sums of the row's first
+        chunks, then of its second ..."""
         acc = jnp.promote_types(jnp.promote_types(vals.dtype, table.dtype),
                                 jnp.float32)
         got = lookup(table, jnp.swapaxes(idx, -1, -2))
-        return jnp.sum((jnp.swapaxes(vals, -1, -2) * got).astype(acc),
-                       axis=-2)
+        part = (jnp.swapaxes(vals, -1, -2) * got).astype(acc)
+        c = part.shape[-2] // fold
+        return jnp.concatenate(
+            [jnp.sum(part[..., k * c:(k + 1) * c, :], axis=-2)
+             for k in range(fold)], axis=-1)
 
     def matvec(self, w: Array) -> Array:
         with jax.named_scope("design.matvec"):
-            part = self._chunk_sums(self.rvals, self.rcols, w)
-            if not self.rows_first:
-                out = jax.ops.segment_sum(
-                    part, self.rrow, num_segments=self.n_rows,
-                    indices_are_sorted=True)
-            elif part.shape[-1] == self.n_rows:
-                out = part
-            else:
-                out = part[..., :self.n_rows] + jax.ops.segment_sum(
-                    part[..., self.n_rows:], self.rrow[self.n_rows:],
+            out = None
+            if self.rows_first:
+                out = self._chunk_sums(self.fvals, self.fcols, w,
+                                       self.fold)[..., :self.n_rows]
+            if out is None or self.rrow.shape[-1]:
+                summed = jax.ops.segment_sum(
+                    self._chunk_sums(self.rvals, self.rcols, w), self.rrow,
                     num_segments=self.n_rows, indices_are_sorted=True)
+                out = summed if out is None else out + summed
             if self.hot_cols is not None:
                 coef = self.hot_vals * lookup(w, self.hot_cols[None, :])[0]
                 out = out + _planes_dot(self.hot_by_row,
@@ -500,12 +548,41 @@ class ChunkedSparseDesign:
 
     @staticmethod
     def default_chunk(counts: np.ndarray) -> int:
-        """Median nnz of the non-empty keys, rounded to 8 in [8, 128]."""
+        """Median nnz of the non-empty keys, rounded to 8 in [8, 128]: the
+        column side's width, and the row side's where rows are stacked in
+        blocks (``rows_first`` off). Timed on the chip at 16 (the sparse
+        cell's rows before PR 36: 1.80 ns a slot gathered, 0.78 picked, of
+        which a third padding) and as the cell's column side (PERF.md,
+        section 6, PRs 32 and 36)."""
         nz = counts[counts > 0]
         if not len(nz):
             return 8
         med = int(np.median(nz))
         return int(np.clip(-(-med // 8) * 8, 8, 128))
+
+    @staticmethod
+    def row_widths(counts: np.ndarray) -> tuple[int, int]:
+        """The row side's widths where every row's first chunk stands at its
+        own place: ``(C, O)``, the first chunks' and the overflow chunks'
+        (those a row fills beyond its first), each in [1, 128], that cost an
+        evaluation least by the chip's rates: ``_SLOT_NS`` a stored slot and
+        ``_SUM_NS`` more an overflow chunk (its sum goes through the
+        segment-sum). ``O = C`` where no row overflows."""
+        k, rows = np.unique(np.asarray(counts, np.int64), return_counts=True)
+        widths = np.arange(1, _MAX_CHUNK + 1)
+        best = None
+        for c in widths:
+            over = np.maximum(k - c, 0)
+            if not over.any():
+                cost, o = _SLOT_NS * c * rows.sum(), c
+            else:
+                chunks = (-(-over[None, :] // widths[:, None]) * rows).sum(1)
+                costs = _SLOT_NS * widths * chunks + _SUM_NS * chunks
+                o = int(np.argmin(costs)) + 1
+                cost = _SLOT_NS * c * rows.sum() + costs[o - 1]
+            if best is None or cost < best[0]:
+                best = (cost, int(c), int(o))
+        return best[1:]
 
     @staticmethod
     def layout(rows, cols, vals, n_rows: int, n_cols: int, *,
@@ -520,9 +597,14 @@ class ChunkedSparseDesign:
         width. Only per-key counts visit the host (the default widths, the
         number of chunk rows and the busy bins are read from them). Explicit
         zeros are dropped; duplicate ``(row, col)`` entries keep separate
-        slots. Every row's first chunk stands at the row's own index
-        (``rows_first``; a caller that stacks layouts of several blocks turns
-        that off and gets the chunks of non-empty rows alone).
+        slots. Every row's first chunk stands at the row's own place
+        (``rows_first``: ``fvals``, ``fcols``, ``fold``; the rows' own
+        entries beyond it in the ``r*`` chunks), ``row_chunk`` wide and the
+        others ``row_chunk`` too where the caller gives it, else as
+        :meth:`row_widths` weighs the rows' counts (``row_chunk``,
+        ``row_overflow_chunk``). A caller that stacks layouts of several
+        blocks turns ``rows_first`` off and gets the chunks of non-empty
+        rows alone, :meth:`default_chunk` wide.
 
         ``hot_columns``: how many of the busiest bins become bit planes
         (:func:`_hot_tier`): 0 for none; by default those that hold an entry
@@ -545,31 +627,42 @@ class ChunkedSparseDesign:
             rows, cols, vals, hot = _hot_tier(
                 rows, cols, vals, int(n_rows), int(n_cols), hot_columns)
 
-        def side(keys, other, n_keys, chunk, first_each=False):
+        def ordered(keys, other, n_keys):
             _, other, v, starts = _entries_in_key_order(keys, other, vals,
                                                         n_keys)
-            counts = np.diff(np.asarray(starts))
-            if chunk is None:
-                chunk = ChunkedSparseDesign.default_chunk(counts)
-            per_key = -(-counts // chunk)
-            if first_each:
-                per_key = np.maximum(per_key, 1)
-            n_chunks = int(per_key.sum())
+            return other, v, starts, np.diff(np.asarray(starts))
+
+        def chunked(other, v, starts, counts, chunk, skip=0):
+            n_chunks = int((-(-np.maximum(counts - skip, 0) // chunk)).sum())
             if n_chunks * chunk >= limit:
                 raise ValueError(f"{n_chunks} chunks of {chunk} slots pass "
                                  f"what int32 positions address")
             return _chunk_runs(other, v, starts, chunk=int(chunk),
-                               n_chunks=n_chunks, one_each=first_each) + (
-                                   int(chunk), int(counts.sum()))
+                               n_chunks=n_chunks, skip=int(skip))
 
-        cvals, crows, ccol, col_chunk, _ = side(
-            cols, rows, int(n_cols), col_chunk)
-        rvals, rcols, rrow, row_chunk, entries = side(
-            rows, cols, int(n_rows), row_chunk, bool(rows_first))
-        return dict(rvals=rvals, rcols=rcols, rrow=rrow, cvals=cvals,
-                    crows=crows, ccol=ccol, row_chunk=row_chunk,
-                    col_chunk=col_chunk, rows_first=bool(rows_first),
-                    entries=entries + hot["hot_entries"], **hot)
+        other, v, starts, counts = ordered(cols, rows, int(n_cols))
+        col_chunk = int(col_chunk or ChunkedSparseDesign.default_chunk(
+            counts))
+        cvals, crows, ccol = chunked(other, v, starts, counts, col_chunk)
+        other, v, starts, counts = ordered(rows, cols, int(n_rows))
+        lay = dict(cvals=cvals, crows=crows, ccol=ccol, col_chunk=col_chunk,
+                   rows_first=bool(rows_first),
+                   entries=int(counts.sum()) + hot["hot_entries"], **hot)
+        if not rows_first:
+            row_chunk = int(row_chunk or ChunkedSparseDesign.default_chunk(
+                counts))
+            rvals, rcols, rrow = chunked(other, v, starts, counts, row_chunk)
+            return dict(rvals=rvals, rcols=rcols, rrow=rrow,
+                        row_chunk=row_chunk, **lay)
+        first, beyond = (int(row_chunk),) * 2 if row_chunk else \
+            ChunkedSparseDesign.row_widths(counts)
+        fold = _SUBLANES // math.gcd(first, _SUBLANES)
+        fvals, fcols = _first_chunks(other, v, starts, chunk=first, fold=fold)
+        rvals, rcols, rrow = chunked(other, v, starts, counts, beyond,
+                                     skip=first)
+        return dict(rvals=rvals, rcols=rcols, rrow=rrow, fvals=fvals,
+                    fcols=fcols, fold=fold, row_chunk=first,
+                    row_overflow_chunk=beyond, **lay)
 
     @staticmethod
     def layout_numpy(rows, cols, vals, n_rows: int, n_cols: int, *,
@@ -600,12 +693,15 @@ class ChunkedSparseDesign:
             lay = ChunkedSparseDesign.layout(
                 rows, cols, vals, n_rows, n_cols, row_chunk=row_chunk,
                 col_chunk=col_chunk, hot_columns=hot_columns)
-            sizes = {k: lay.pop(k) for k in ("entries", "row_chunk",
-                                             "col_chunk", "hot_entries")}
+            lay.pop("rows_first")
+            sizes = {k: lay.pop(k) for k in (
+                "entries", "row_chunk", "row_overflow_chunk", "col_chunk",
+                "hot_entries")}
             design = ChunkedSparseDesign(
                 **lay, n_rows=int(n_rows), n_cols=int(n_cols))
             jax.block_until_ready(design)
-            build.set(**sizes, row_slots=design.rvals.size,
+            build.set(**sizes,
+                      row_slots=design.fvals.size + design.rvals.size,
                       col_slots=design.cvals.size,
                       hot_columns=0 if design.hot_cols is None
                       else design.hot_cols.shape[0])

@@ -179,14 +179,22 @@ def test_the_build_agrees_with_csr(case, chunks):
     close(built.rmatvec_squared(g), squared.rmatvec(g))
     # int32 and float32 throughout; padding holds value 0; a dropped zero
     # takes no slot
-    assert built.rcols.dtype == built.crows.dtype == jnp.int32
-    assert built.rvals.dtype == built.cvals.dtype == jnp.float32
+    assert (built.rcols.dtype == built.fcols.dtype == built.crows.dtype
+            == jnp.int32)
+    assert (built.rvals.dtype == built.fvals.dtype == built.cvals.dtype
+            == jnp.float32)
     live = int(np.count_nonzero(v))
-    assert int(jnp.count_nonzero(built.rvals)) == live
+    assert _row_entries(built) == live
     assert int(jnp.count_nonzero(built.cvals)) == live
-    if chunks[0] is not None:
+    if chunks[0] is not None:  # a caller's widths: the row side's both
+        assert built.fvals.shape[-1] == built.fold * chunks[0]
         assert built.rvals.shape[-1] == chunks[0]
         assert built.cvals.shape[-1] == chunks[1]
+
+
+def _row_entries(built):
+    return int(jnp.count_nonzero(built.fvals)
+               + jnp.count_nonzero(built.rvals))
 
 
 def _one_hot_case(name):
@@ -233,8 +241,8 @@ def test_busy_bins_as_planes_agree_with_csr(case, hot):
     in_planes = sum(int(jax.lax.population_count(p).sum())
                     for p in (built.hot_by_row, built.hot_by_bin))
     assert in_planes == 2 * record["hot_entries"] > 0
-    for side in (built.rvals, built.cvals):
-        assert int(jnp.count_nonzero(side)) == live - record["hot_entries"]
+    assert _row_entries(built) == live - record["hot_entries"]
+    assert int(jnp.count_nonzero(built.cvals)) == live - record["hot_entries"]
     # a plane's bin has one value, and a (row, bin) pair one bit
     busy = np.asarray(built.hot_cols)[np.asarray(built.hot_vals) != 0]
     assert len(set(busy)) == len(busy) <= hot
@@ -251,7 +259,7 @@ def test_a_small_design_keeps_every_bin_in_the_chunks():
     r, c, v, n, d = _one_hot_case("plain")
     built = ChunkedSparseDesign.from_coo(r, c, v, n, d)
     assert built.hot_cols is None and built.hot_by_row is None
-    assert int(jnp.count_nonzero(built.rvals)) == int(np.count_nonzero(v))
+    assert _row_entries(built) == int(np.count_nonzero(v))
 
 
 def test_build_record_carries_the_sizes():
@@ -262,7 +270,11 @@ def test_build_record_carries_the_sizes():
     assert record["rows"] == n and record["dim"] == d
     assert record["entries"] == int(np.count_nonzero(v))
     assert (record["row_chunk"], record["col_chunk"]) == (8, 16)
-    assert record["row_slots"] == built.rvals.size
+    assert record["row_overflow_chunk"] == 8
+    # every stored slot of the row side: first chunks and overflow chunks
+    assert record["row_slots"] == built.fvals.size + built.rvals.size
+    assert record["row_slots"] == n * 8 + 8 * int(np.sum(np.maximum(
+        -(-np.bincount(r[v != 0], minlength=n) // 8) - 1, 0)))
     assert record["col_slots"] == built.cvals.size
     assert record["seconds"] > 0
     assert design_kind(built) == "chunked_sparse"
@@ -299,20 +311,20 @@ def test_a_rows_first_chunk_takes_no_segment_sum():
     per_row = np.bincount(r, minlength=n)
     fits = ChunkedSparseDesign.from_coo(r, c, v, n, d, 16, 8)
     assert int(per_row.max()) <= 16
-    assert fits.rows_first and fits.rvals.shape == (n, 16)
-    assert np.array_equal(np.asarray(fits.rrow), np.arange(n))
+    assert fits.rows_first and fits.fvals.shape == (n, 16)
+    assert fits.rvals.shape == (0, 16) and fits.rrow.shape == (0,)
     text = jax.jit(fits.matvec.__func__).lower(
         fits, jnp.zeros(d, jnp.float32)).as_text()
     assert "scatter" not in text
-    # a row wider than the chunk: its first chunk still at its own index, the
-    # others after the last row's, and only those are summed by row
+    # a row wider than the chunk: its first chunk still at its own place,
+    # the others, and only those, summed by row
     split = ChunkedSparseDesign.from_coo(r, c, v, n, d, 8, 8)
     assert split.rows_first and int(per_row.max()) > 8
     beyond = np.maximum(np.ceil(per_row / 8).astype(int) - 1, 0)
-    assert split.rvals.shape == (n + beyond.sum(), 8)
+    assert split.fvals.shape == (n, 8) and split.fold == 1
+    assert split.rvals.shape == (beyond.sum(), 8)
     assert np.array_equal(np.asarray(split.rrow),
-                          np.concatenate([np.arange(n),
-                                          np.repeat(np.arange(n), beyond)]))
+                          np.repeat(np.arange(n), beyond))
     w = jnp.asarray(np.random.default_rng(2).normal(size=d), jnp.float32)
     np.testing.assert_allclose(np.asarray(fits.matvec(w)),
                                np.asarray(split.matvec(w)), rtol=1e-5,
@@ -320,8 +332,163 @@ def test_a_rows_first_chunk_takes_no_segment_sum():
     # a caller that stacks blocks gets the chunks of non-empty rows alone
     lay = ChunkedSparseDesign.layout_numpy(r, c, v, n, d, row_chunk=8,
                                            col_chunk=8)
-    assert not lay["rows_first"]
+    assert not lay["rows_first"] and "fvals" not in lay
     assert lay["rvals"].shape[0] == int(np.ceil(per_row / 8).sum())
+
+
+def _runs_case(c, n, duplicates, hot):
+    """Rows of 0, 1, C, C + 1 and 3C + 2 entries in turn, distinct bins a
+    row but for ``duplicates`` (a row's entries again, other values), and
+    with ``hot`` a bin that every other row holds at value 1; explicit zeros
+    up to 4,096 entries in 300 bins, so that every case shares the build's
+    compiled programs."""
+    rng = np.random.default_rng(c * 100 + n)
+    d, e = 300, 4096
+    sizes = np.resize([0, 1, c, c + 1, 3 * c + 2], n)
+    r = np.repeat(np.arange(n), sizes)
+    cols = np.concatenate([rng.choice(np.arange(1, d), k, replace=False)
+                           for k in sizes])
+    v = rng.normal(size=len(r))
+    if duplicates:
+        twice = rng.random(len(r)) < 0.3
+        r, cols = np.r_[r, r[twice]], np.r_[cols, cols[twice]]
+        v = np.r_[v, rng.normal(size=int(twice.sum()))]
+    if hot:
+        r = np.r_[r, np.arange(0, n, 2)]
+        cols = np.r_[cols, np.zeros(len(range(0, n, 2)), int)]
+        v = np.r_[v, np.ones(len(range(0, n, 2)))]
+    pad = e - len(r)
+    r = np.r_[r, rng.integers(0, n, pad)]
+    cols, v = np.r_[cols, rng.integers(0, d, pad)], np.r_[v, np.zeros(pad)]
+    order = rng.permutation(e)
+    return (r[order].astype(np.int32), cols[order].astype(np.int32),
+            v[order].astype(np.float32), n, d)
+
+
+@pytest.mark.parametrize("n", [101, 96])
+@pytest.mark.parametrize("case", ["plain", "duplicates", "planes",
+                                  "duplicates_planes"])
+@pytest.mark.parametrize("widths", [(11, 4), (12, 12), (10, 2), (16, 16),
+                                    (3, 5), (1, 1)], ids=str)
+def test_the_row_layout_equals_a_dense_design(widths, case, n, monkeypatch):
+    """Every row's first chunk C wide, ``fold`` rows a lane column so that
+    ``fold x C`` is a multiple of 8, the rest in overflow chunks O wide:
+    the three contractions equal a dense design's, at an ``n`` the fold
+    divides and at one it does not."""
+    c, o = widths
+    monkeypatch.setattr(ChunkedSparseDesign, "row_widths",
+                        staticmethod(lambda counts: (c, o)))
+    r, cols, v, n, d = _runs_case(c, n, "duplicates" in case,
+                                  "planes" in case)
+    built, records = _records(lambda: ChunkedSparseDesign.from_coo(
+        r, cols, v, n, d, hot_columns=8 if "planes" in case else 0))
+    dense = np.zeros((n, d))
+    squared = np.zeros((n, d))
+    np.add.at(dense, (r, cols), v.astype(np.float64))
+    np.add.at(squared, (r, cols), np.square(v.astype(np.float64)))
+    rng = np.random.default_rng(1)
+    w = rng.normal(size=d)
+    g = rng.normal(size=n)
+    close = lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), b, rtol=1e-5, atol=1e-5)
+    three = jax.jit(lambda x, w, g: (x.matvec(w), x.rmatvec(g),
+                                     x.rmatvec_squared(g)))(
+        built, jnp.asarray(w, jnp.float32), jnp.asarray(g, jnp.float32))
+    for got, want in zip(three, (dense @ w, dense.T @ g, squared.T @ g)):
+        close(got, want)
+    fold = 8 // np.gcd(c, 8)
+    assert built.fold == fold and built.fvals.shape == (-(-n // fold),
+                                                        fold * c)
+    assert built.rvals.shape[-1] == o
+    (record,) = [x for x in records if x["name"] == BUILD_SPAN]
+    assert (record["row_chunk"], record["row_overflow_chunk"]) == (c, o)
+    if "planes" in case:
+        assert record["hot_entries"] > 0
+    live = v != 0
+    assert _row_entries(built) == int(live.sum()) - record["hot_entries"]
+    if "planes" not in case:
+        over = np.maximum(np.bincount(r[live], minlength=n) - c, 0)
+        assert built.rvals.shape[0] == int(np.sum(-(-over // o)))
+
+
+#: remaining entries a row of the sparse cell's 5,000,000 after the planes,
+#: per million, 0 to 17 of them (ISSUE 36, redrawn from the generator's law)
+CELL_COUNTS = [2, 31, 255, 1612, 7753, 25809, 64470, 122175, 179096, 203457,
+               178403, 121776, 62424, 24340, 6806, 1413, 167, 11]
+#: what the width rule makes of them at the chip's rates (PERF.md, section 6)
+CELL_WIDTHS = (10, 2)
+
+
+@pytest.mark.parametrize("counts, widths", [
+    (np.repeat(np.arange(18), CELL_COUNTS), CELL_WIDTHS),
+    (np.full(1000, 16), (16, 16)),  # flat: the median's width, as before
+    (np.full(1000, 8), (8, 8)),
+    (np.r_[np.full(999, 8), 9], (8, 1)),  # one entry over: an O of 1
+    (np.zeros(10, int), (1, 1)),
+], ids=["cell", "flat16", "flat8", "one_over", "empty"])
+def test_the_width_rule(counts, widths):
+    assert ChunkedSparseDesign.row_widths(counts) == widths
+    if widths[0] == widths[1] and counts.any() and (counts == counts[0]).all():
+        assert widths[0] == ChunkedSparseDesign.default_chunk(counts)
+
+
+@pytest.mark.parametrize("row_chunk", [12, 5, 16])
+def test_an_explicit_row_chunk_is_honoured(row_chunk):
+    r, c, v, n, d = _case("duplicates")
+    built, records = _records(lambda: ChunkedSparseDesign.from_coo(
+        r, c, v, n, d, row_chunk=row_chunk))
+    fold = 8 // np.gcd(row_chunk, 8)
+    assert built.fold == fold
+    assert built.fvals.shape == (-(-n // fold), fold * row_chunk)
+    assert built.rvals.shape[-1] == row_chunk
+    (record,) = [x for x in records if x["name"] == BUILD_SPAN]
+    assert record["row_chunk"] == record["row_overflow_chunk"] == row_chunk
+
+
+def _stacked_side(keys, other, vals, n_keys, chunk):
+    """The stacked layout's side, in numpy: entries of distinct (key, other)
+    pairs ordered by them, each key's run cut into chunks of ``chunk``."""
+    live = vals != 0
+    keys, other, vals = keys[live], other[live], vals[live]
+    order = np.lexsort((other, keys))
+    keys, other, vals = keys[order], other[order], vals[order]
+    out_v, out_o, out_k = [], [], []
+    for k in range(n_keys):
+        at = np.flatnonzero(keys == k)
+        for s in range(0, len(at), chunk):
+            part = at[s:s + chunk]
+            out_v.append(np.pad(vals[part], (0, chunk - len(part))))
+            out_o.append(np.pad(other[part], (0, chunk - len(part))))
+            out_k.append(k)
+    return (np.array(out_v, np.float32).reshape(-1, chunk),
+            np.array(out_o, np.int32).reshape(-1, chunk),
+            np.array(out_k, np.int32))
+
+
+@pytest.mark.parametrize("case", ["plain", "unordered", "empty_rows",
+                                  "explicit_zeros"])
+def test_the_stacked_layout_is_as_before(case):
+    """``rows_first=False`` (``layout_numpy``, what
+    ``parallel/distributed.py`` stacks): the same arrays, keys and widths
+    as ever, to the byte."""
+    r, c, v, n, d = _case(case)
+    lay = ChunkedSparseDesign.layout_numpy(r, c, v, n, d)
+    assert set(lay) == {"rvals", "rcols", "rrow", "cvals", "crows", "ccol",
+                        "row_chunk", "col_chunk", "rows_first", "entries",
+                        "hot_entries"}
+    live = v != 0
+    widths = (ChunkedSparseDesign.default_chunk(np.bincount(
+        r[live], minlength=n)), ChunkedSparseDesign.default_chunk(
+            np.bincount(c[live], minlength=d)))
+    assert (lay["row_chunk"], lay["col_chunk"]) == widths
+    for got, want in zip(
+            ("rvals", "rcols", "rrow", "cvals", "crows", "ccol"),
+            _stacked_side(r, c, v, n, widths[0])
+            + _stacked_side(c, r, v, d, widths[1])):
+        assert lay[got].dtype == want.dtype
+        np.testing.assert_array_equal(lay[got], want)
+    assert not lay["rows_first"] and lay["hot_entries"] == 0
+    assert lay["entries"] == int(live.sum())
 
 
 def test_lookup_in_blocks(monkeypatch):

@@ -16,6 +16,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -205,49 +206,104 @@ def test_the_sweep_holds_one_index_of_the_rows(sweep_program):
 
 # --- the wide sparse design's evaluation (PERF.md, PR 32) --------------------
 SPARSE_ROWS, SPARSE_DIM, SPARSE_CHUNKS = 400_000, 1_000_000, 1_100_000
-#: (row chunk, column chunk, every row one chunk): the click-through shape
-#: first (rows of 40 slots, column chunks of 16: the one that was timed on the
-#: chip), then the other widths ``default_chunk`` yields and a row side that
-#: takes its segment-sum: compiled here, not yet timed (ROADMAP S0)
-SPARSE_WIDTHS = [(40, 16, True), (40, 8, True), (40, 32, True),
-                 (40, 128, True), (8, 16, False), (64, 64, False)]
+SPARSE_OVER = 50_000  # chunks beyond the rows' first
+#: (row side, column chunk): the row side ``(C, O)`` with every row's first
+#: chunk C wide at its place and overflow chunks O wide, or ``(None, C)``
+#: for chunks of non-empty rows alone, summed by row. The click-through
+#: shape first (rows of 40 slots, column chunks of 16: the one timed on the
+#: chip in PR 32), then the other widths ``default_chunk`` yields, first
+#: chunks that take a fold (12, 10: PR 36) and row sides that take their
+#: whole segment-sum
+SPARSE_WIDTHS = [((40, 40), 16), ((40, 40), 8), ((40, 40), 32),
+                 ((40, 40), 128), ((12, 12), 16), ((10, 2), 16),
+                 ((None, 8), 16), ((None, 64), 64)]
+
+
+def _sparse_design(sds, n, d, rows, col_chunk, mc, over, **hot):
+    """A ``ChunkedSparseDesign`` of described arrays: ``rows`` as in
+    ``SPARSE_WIDTHS``; ``over`` chunks beyond the rows' first."""
+    import math
+
+    from photon_ml_tpu.ops.design import ChunkedSparseDesign
+
+    first, width = rows
+    side = {}
+    if first is not None:
+        fold = 8 // math.gcd(first, 8)
+        shape = (-(-n // fold), fold * first)
+        side = dict(fvals=sds(shape), fcols=sds(shape, jnp.int32), fold=fold)
+    mr = over if first is not None else mc
+    return ChunkedSparseDesign(
+        rvals=sds((mr, width)), rcols=sds((mr, width), jnp.int32),
+        rrow=sds((mr,), jnp.int32), cvals=sds((mc, col_chunk)),
+        crows=sds((mc, col_chunk), jnp.int32), ccol=sds((mc,), jnp.int32),
+        n_rows=n, n_cols=d, **side, **hot)
+
+
+def _evaluation(sds, design, n, d):
+    """One value-and-gradient evaluation over ``design``, compiled for the
+    chip: its text and its temporaries."""
+    from photon_ml_tpu.ops.losses import LogisticLoss
+    from photon_ml_tpu.ops.objective import GLMData, GLMObjective
+
+    data = GLMData(design=design, labels=sds((n,)), offsets=sds((n,)),
+                   weights=sds((n,)))
+    objective = GLMObjective(loss=LogisticLoss, fused=True)
+    with jax.enable_x64(False):
+        program = jax.jit(lambda w, data: objective.value_and_grad(
+            w, data, 1.0)).lower(sds((d,)), data).compile()
+    return program.as_text(), program.memory_analysis()
+
+
+def _sds(one_chip):
+    return lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
 
 
 @pytest.fixture(scope="module")
 def sparse_evaluation(one_chip):
-    """One value-and-gradient evaluation over a ``ChunkedSparseDesign`` of the
-    given chunk widths, compiled for the chip: its text and its
-    temporaries."""
-    from photon_ml_tpu.ops.design import ChunkedSparseDesign
-    from photon_ml_tpu.ops.losses import LogisticLoss
-    from photon_ml_tpu.ops.objective import GLMData, GLMObjective
-
-    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=one_chip)
-    n, d, mc = SPARSE_ROWS, SPARSE_DIM, SPARSE_CHUNKS
+    """The evaluation of the given widths at ``SPARSE_ROWS``."""
+    sds = _sds(one_chip)
     built = {}
 
-    def compiled(row_chunk, col_chunk, rows_first):
-        key = (row_chunk, col_chunk, rows_first)
-        if key not in built:
-            mr = n if rows_first else mc
-            design = ChunkedSparseDesign(
-                rvals=sds((mr, row_chunk)),
-                rcols=sds((mr, row_chunk), jnp.int32),
-                rrow=sds((mr,), jnp.int32), cvals=sds((mc, col_chunk)),
-                crows=sds((mc, col_chunk), jnp.int32),
-                ccol=sds((mc,), jnp.int32), n_rows=n, n_cols=d,
-                rows_first=rows_first)
-            data = GLMData(design=design, labels=sds((n,)), offsets=sds((n,)),
-                           weights=sds((n,)))
-            objective = GLMObjective(loss=LogisticLoss, fused=True)
-            with jax.enable_x64(False):
-                program = jax.jit(lambda w, data: objective.value_and_grad(
-                    w, data, 1.0)).lower(sds((d,)), data).compile()
-            built[key] = (program.as_text(), program.memory_analysis())
-        return built[key]
+    def compiled(rows, col_chunk):
+        if (rows, col_chunk) not in built:
+            design = _sparse_design(sds, SPARSE_ROWS, SPARSE_DIM, rows,
+                                    col_chunk, SPARSE_CHUNKS, SPARSE_OVER)
+            built[rows, col_chunk] = _evaluation(sds, design, SPARSE_ROWS,
+                                                 SPARSE_DIM)
+        return built[rows, col_chunk]
 
     return compiled
+
+
+def _chunk_arrays(n, rows, col_chunk, mc, over):
+    """The ``(M, width)`` shapes of a design's chunk arrays."""
+    first, width = rows
+    out = [(mc, col_chunk), (over if first is not None else mc, width)]
+    if first is not None:
+        fold = 8 // np.gcd(first, 8)
+        out.append((-(-n // fold), fold * first))
+    return out
+
+
+def _no_chunk_array_in_the_lanes(text, shapes):
+    for rows, width in shapes:
+        laid = set(re.findall(rf"[fs]32\[{rows},{width}\]{{([0-9,]+):",
+                              text))
+        assert laid <= {"0,1"} or width == 128, (rows, width, laid)
+
+
+def _row_rows(text, n):
+    """Per scatter of the row side (``design.matvec``), the number of
+    chunk sums it adds: the index operand of its fused computation."""
+    sums = []
+    for head, body in re.findall(
+            r"\n(%\S+ \([^\n]*\) -> [^\n]*\{)\n(.*?)\n\}", text, re.S):
+        if " scatter(" in body and "design.matvec/scatter-add" in body:
+            sums += [int(m) for m in re.findall(r": s32\[(\d+)(?:,1)?\]",
+                                               head)]
+    return sums
 
 
 @pytest.mark.parametrize("widths", SPARSE_WIDTHS, ids=str)
@@ -265,16 +321,45 @@ def test_sparse_evaluation_gathers_whole_rows(sparse_evaluation, widths):
 def test_sparse_evaluation_pads_no_chunk_array(sparse_evaluation, widths):
     """A ``(M, C)`` chunk array is never re-laid with C (8 to 64) in the
     128-lane dimension (at C = 16 and 40, 8 and 3.2 times its bytes: the
-    parent's evaluation did not fit the chip at 8,000,000 rows for it), and
-    the temporaries stay near the two blocks of gathered rows."""
+    parent's evaluation did not fit the chip at 8,000,000 rows for it), the
+    row side's segment-sum takes the chunks beyond the rows' first alone,
+    and the temporaries stay near the two blocks of gathered rows."""
     text, memory = sparse_evaluation(*widths)
-    row_chunk, col_chunk, rows_first = widths
-    sides = ((SPARSE_ROWS if rows_first else SPARSE_CHUNKS, row_chunk),
-             (SPARSE_CHUNKS, col_chunk))
-    for rows, width in sides:
-        laid = set(re.findall(rf"[fs]32\[{rows},{width}\]{{([0-9,]+):",
-                              text))
-        assert laid <= {"0,1"} or width == 128, (rows, width, laid)
+    rows, col_chunk = widths
+    _no_chunk_array_in_the_lanes(text, _chunk_arrays(
+        SPARSE_ROWS, rows, col_chunk, SPARSE_CHUNKS, SPARSE_OVER))
+    summed = _row_rows(text, SPARSE_ROWS)
+    assert summed and set(summed) == {
+        SPARSE_OVER if rows[0] is not None else SPARSE_CHUNKS}, summed
+    assert memory.temp_size_in_bytes < 4 * 2**30
+
+
+#: the sparse cell as PR 36 builds it (PERF.md, section 5): 5,000,000 rows,
+#: first chunks of 10 four rows a lane column, 1,255,656 overflow chunks of
+#: 2, 3,310,517 column chunks of 16, the planes of 3,072 busy bins
+CELL_ROWS, CELL_OVER, CELL_CHUNKS, CELL_HOT = (5_000_000, 1_255_656,
+                                               3_310_517, 3072)
+
+
+def test_the_sparse_cells_evaluation(one_chip):
+    """The evaluation at the sparse cell's shapes and widths: no chunk array
+    re-laid into the lanes (the first chunks, 40 sublanes, fill five tiles),
+    only 128-lane rows gathered, the overflow chunks alone segment-summed,
+    temporaries under 4 GiB."""
+    sds = _sds(one_chip)
+    n, k = CELL_ROWS, CELL_HOT
+    design = _sparse_design(
+        sds, n, SPARSE_DIM, (10, 2), 16, CELL_CHUNKS, CELL_OVER,
+        hot_cols=sds((k,), jnp.int32), hot_vals=sds((k,)),
+        hot_by_row=sds((k // 32, n), jnp.uint32),
+        hot_by_bin=sds((-(-n // 32), k), jnp.uint32))
+    text, memory = _evaluation(sds, design, n, SPARSE_DIM)
+    assert design.fvals.shape == (n // 4, 40)
+    _no_chunk_array_in_the_lanes(text, _chunk_arrays(
+        n, (10, 2), 16, CELL_CHUNKS, CELL_OVER))
+    gathers = re.findall(r"= f32\[([0-9,]+)\]\S* gather\(", text)
+    assert gathers and all(g.endswith(",128") for g in gathers), gathers
+    assert _row_rows(text, n) == [CELL_OVER]
     assert memory.temp_size_in_bytes < 4 * 2**30
 
 
@@ -292,10 +377,11 @@ def test_busy_bins_planes_are_read_in_one_pass_a_side(one_chip):
     n, d, k, mr, mc = SPARSE_ROWS, SPARSE_DIM, 3072, 600_000, 300_000
     words = -(-n // 32)
     design = ChunkedSparseDesign(
-        rvals=sds((mr, 8)), rcols=sds((mr, 8), jnp.int32),
-        rrow=sds((mr,), jnp.int32), cvals=sds((mc, 16)),
+        rvals=sds((mr - n, 8)), rcols=sds((mr - n, 8), jnp.int32),
+        rrow=sds((mr - n,), jnp.int32), cvals=sds((mc, 16)),
         crows=sds((mc, 16), jnp.int32), ccol=sds((mc,), jnp.int32),
-        n_rows=n, n_cols=d, rows_first=True,
+        n_rows=n, n_cols=d, fvals=sds((n, 8)),
+        fcols=sds((n, 8), jnp.int32),
         hot_cols=sds((k,), jnp.int32), hot_vals=sds((k,)),
         hot_by_row=sds((k // 32, n), jnp.uint32),
         hot_by_bin=sds((words, k), jnp.uint32))
