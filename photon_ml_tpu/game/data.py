@@ -39,6 +39,7 @@ import numpy as np
 from photon_ml_tpu.game.projector import ProjectorType, RandomProjector
 from photon_ml_tpu.ops.design import CsrDesign, DenseDesign
 from photon_ml_tpu.ops.objective import GLMData
+from photon_ml_tpu.telemetry import tracing
 from photon_ml_tpu.util import group_starts as _group_starts
 from photon_ml_tpu.util import hash_uniform as _hash_uniform
 from photon_ml_tpu.util import materialize_thunk
@@ -267,16 +268,18 @@ class GameData:
     def build(labels, shards, offsets=None, weights=None, id_columns=None) -> "GameData":
         labels = np.asarray(labels, np.float32)
         n = labels.shape[0]
-        return GameData(
-            labels=labels,
-            offsets=np.zeros(n, np.float32) if offsets is None
-            else np.asarray(offsets, np.float32),
-            weights=np.ones(n, np.float32) if weights is None
-            else np.asarray(weights, np.float32),
-            shards=dict(shards),
-            id_columns={k: np.asarray(v, np.int64)
-                        for k, v in (id_columns or {}).items()},
-        )
+        with tracing.span("game.data.build", rows=int(n), shards=len(shards),
+                          id_columns=len(id_columns or {})):
+            return GameData(
+                labels=labels,
+                offsets=np.zeros(n, np.float32) if offsets is None
+                else np.asarray(offsets, np.float32),
+                weights=np.ones(n, np.float32) if weights is None
+                else np.asarray(weights, np.float32),
+                shards=dict(shards),
+                id_columns={k: np.asarray(v, np.int64)
+                            for k, v in (id_columns or {}).items()},
+            )
 
 
 @partial(jax.jit, static_argnames=("n", "d", "nnz"))

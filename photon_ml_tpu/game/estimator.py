@@ -34,6 +34,7 @@ from photon_ml_tpu.game.data import (
 from photon_ml_tpu.game.model import GameModel
 from photon_ml_tpu.glm.problem import GLMOptimizationConfiguration
 from photon_ml_tpu.sampling import DownSampler
+from photon_ml_tpu.telemetry import tracing
 from photon_ml_tpu.types import TaskType
 
 logger = logging.getLogger(__name__)
@@ -200,6 +201,11 @@ class GameEstimator:
 
     def prepare(self, data: GameData,
                 locked: Sequence[str] = ()) -> dict[str, object]:
+        with tracing.span("game.prepare"):
+            return self._prepare(data, locked)
+
+    def _prepare(self, data: GameData,
+                 locked: Sequence[str]) -> dict[str, object]:
         self._check_sequence(locked)
         self._prefetch_device_feed(data, locked)
         datasets: dict[str, object] = {}
@@ -209,16 +215,23 @@ class GameEstimator:
                 continue  # frozen coordinate: no dataset, no training
             cfg = self.coordinate_configs[cid]
             if isinstance(cfg, FixedEffectCoordinateConfig):
-                datasets[cid] = FixedEffectDataset.build(
-                    cid, data, cfg.feature_shard_id, mesh=self.mesh,
-                    dtype=(jnp.bfloat16 if cfg.design_dtype == "bfloat16"
-                           else jnp.float32))
+                with tracing.span("game.dataset.build", coordinate=cid,
+                                  kind="fixed_effect", rows=data.n_samples):
+                    datasets[cid] = FixedEffectDataset.build(
+                        cid, data, cfg.feature_shard_id, mesh=self.mesh,
+                        dtype=(jnp.bfloat16 if cfg.design_dtype == "bfloat16"
+                               else jnp.float32))
             elif isinstance(cfg, FactoredRandomEffectCoordinateConfig):
                 # rebuilt each alternation around the learned projection
                 datasets[cid] = None
             else:
-                datasets[cid] = RandomEffectDataset.build(
-                    cid, data, cfg.dataset, n_entity_shards=ep)
+                with tracing.span("game.dataset.build", coordinate=cid,
+                                  kind="random_effect",
+                                  rows=data.n_samples) as sp:
+                    datasets[cid] = RandomEffectDataset.build(
+                        cid, data, cfg.dataset, n_entity_shards=ep)
+                    sp.set(entities=datasets[cid].n_active_entities,
+                           buckets=len(datasets[cid].buckets))
                 logger.info(
                     "coordinate %s: %d active entities in %d buckets, "
                     "%d passive rows", cid, datasets[cid].n_active_entities,
@@ -371,7 +384,15 @@ class GameEstimator:
         early sweeps run. ``on_result(index, result)`` fires the moment
         each configuration finishes — the async I/O pipeline's hook for
         submitting that model's background save while the remaining grid
-        points still train."""
+        points still train. One ``game.fit`` span around the whole call."""
+        with tracing.span("game.fit", configurations=len(configurations)):
+            return self._fit(data, configurations, validation, datasets,
+                             initial_models, locked, checkpoint, resume,
+                             guard, on_result)
+
+    def _fit(self, data, configurations, validation, datasets,
+             initial_models, locked, checkpoint, resume, guard,
+             on_result) -> list[GameResult]:
         self._check_sequence(locked)
         if checkpoint is not None and len(configurations) != 1:
             raise ValueError("checkpointing supports exactly one configuration")

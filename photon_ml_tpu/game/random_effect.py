@@ -339,9 +339,6 @@ class RandomEffectSolver:
             rows = int(np.count_nonzero(bucket.sample_idx >= 0))
             static = {
                 "rows": rows, "s_max": s, "dim": d,
-                # what the sweep indexes for this bucket: its real rows on
-                # the way in and again on the way out, no padded slot
-                "moved_slots": 2 * rows,
                 "kernel": "pallas" if self._problem().objective
                 ._entity_kernel_serves(lane, s, d) else "closed_form"}
             dataset._device_cache[key] = static

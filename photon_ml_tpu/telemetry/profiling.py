@@ -52,8 +52,12 @@ Two registry hooks complement the wrapper:
   ``photon_xla_compiles_total{phase}`` /
   ``photon_xla_compile_seconds_total{phase}`` (phase: ``trace`` /
   ``lower`` / ``backend``), so the compile-vs-execute split in
-  ``tools/perf_report.py`` never under-reports un-wrapped jits.
-  Installed automatically with the first wrapper.
+  ``tools/perf_report.py`` never under-reports un-wrapped jits, and the
+  persistent cache's answers into ``photon_xla_cache_events_total{result}``
+  (``hit`` / ``miss``). Installed at this module's import. What fell before
+  the process's first profiler session is kept apart besides
+  (:func:`compiles_before_profile`): a traced job's set-up, read after its
+  window.
 """
 
 from __future__ import annotations
@@ -77,6 +81,7 @@ __all__ = [
     "record_compile",
     "total_compiles",
     "install_xla_hooks",
+    "compiles_before_profile",
 ]
 
 #: every live wrapper, so :func:`compiled_programs` can find by name the
@@ -120,16 +125,35 @@ _XLA_EVENT_PHASES = {
     "/jax/core/compile/backend_compile_duration": "backend",
 }
 
+#: jax.monitoring events of the persistent compile cache → the result label
+#: and the key of :func:`compiles_before_profile` (a hit is a program loaded
+#: from the cache, a miss one compiled and written there; both fall inside
+#: the ``backend`` phase)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": ("hit", "cache_hits"),
+    "/jax/compilation_cache/cache_misses": ("miss", "cache_misses"),
+}
+
 _hooks_lock = threading.Lock()
 _hooks_installed = False
 
+#: what the listeners counted before the process's first profiler session
+#: (:func:`compiles_before_profile`)
+_before_profile = {"seconds": 0.0, "programs": 0, "cache_hits": 0,
+                   "cache_misses": 0}
+#: compile-pipeline regions open in the process, on any thread, and when
+#: the first of them opened: a region inside another (an inner jit traced
+#: while its caller is), or beside it on another thread, adds no seconds
+_compiling = {"open": 0, "since": 0.0}
+
 
 def install_xla_hooks() -> None:
-    """Register the process-wide ``jax.monitoring`` listener that folds
+    """Register the process-wide ``jax.monitoring`` listeners that fold
     every XLA compile (profiled or not) into
     ``photon_xla_compiles_total{phase}`` and
-    ``photon_xla_compile_seconds_total{phase}``. Idempotent; installed
-    automatically by the first :class:`ProfiledFunction`."""
+    ``photon_xla_compile_seconds_total{phase}``, and the persistent cache's
+    answers into ``photon_xla_cache_events_total{result}``. Idempotent;
+    called at this module's import, so no compile after it is missed."""
     global _hooks_installed
     with _hooks_lock:
         if _hooks_installed:
@@ -143,6 +167,10 @@ def install_xla_hooks() -> None:
         "photon_xla_compile_seconds_total",
         "Wall seconds in the XLA compile pipeline across the whole "
         "process (any jit, wrapped or not)", labels=("phase",))
+    cache = _metrics.counter(
+        "photon_xla_cache_events_total",
+        "Programs the persistent compile cache served (hit) or that were "
+        "compiled and written to it (miss)", labels=("result",))
 
     def _listener(event: str, duration: float, **_kw) -> None:
         phase = _XLA_EVENT_PHASES.get(event)
@@ -154,7 +182,70 @@ def install_xla_hooks() -> None:
         except Exception:
             pass  # a telemetry hook must never break a compile
 
+    def _cache_listener(event: str, **_kw) -> None:
+        if event not in _CACHE_EVENTS:
+            return
+        result, key = _CACHE_EVENTS[event]
+        try:
+            cache.labels(result=result).inc()
+            if not _tracing.profiled():
+                with _hooks_lock:
+                    _before_profile[key] += 1
+        except Exception:
+            pass
+
+    def _phase_entered(event: str, start: float, **_kw) -> None:
+        if event not in _XLA_EVENT_PHASES:
+            return
+        try:
+            with _hooks_lock:
+                if _compiling["open"] == 0:
+                    _compiling["since"] = float(start)
+                _compiling["open"] += 1
+        except Exception:
+            pass
+
+    def _phase_left(event: str, start: float, end: float, **_kw) -> None:
+        phase = _XLA_EVENT_PHASES.get(event)
+        if phase is None:
+            return
+        try:
+            setup = not _tracing.profiled()
+            with _hooks_lock:
+                if _compiling["open"] > 0:
+                    _compiling["open"] -= 1
+                    start = _compiling["since"]
+                if not setup:
+                    return
+                _before_profile["programs"] += phase == "backend"
+                if _compiling["open"] == 0:
+                    # wall clock, as JAX stamps a region's two ends
+                    _before_profile["seconds"] += max(float(end - start), 0.0)
+        except Exception:
+            pass
+
     jax.monitoring.register_event_duration_secs_listener(_listener)
+    jax.monitoring.register_event_listener(_cache_listener)
+    jax.monitoring.register_scalar_listener(_phase_entered)
+    jax.monitoring.register_event_time_span_listener(_phase_left)
+
+
+def compiles_before_profile() -> dict:
+    """What this process spent in XLA's compile pipeline before its first
+    profiler session (``telemetry/tracing.py::profiled``), every program
+    counted, wrapped or not: ``seconds`` (wall seconds in which a trace,
+    lower or backend region was open on some thread: a jit traced inside
+    another, or compiled beside it on another thread, adds none),
+    ``programs`` (backend compiles, each a compile or a load from the
+    persistent cache), ``cache_hits`` and ``cache_misses`` (the cache's own
+    events). A process with no profiler session reads what it has spent so
+    far. ``programs - cache_hits`` is what was compiled rather than loaded:
+    the misses, and whatever the cache was not asked for."""
+    with _hooks_lock:
+        return dict(_before_profile)
+
+
+install_xla_hooks()
 
 
 def record_compile(name: str, seconds: float = 0.0,
@@ -226,7 +317,6 @@ class ProfiledFunction:
                  static_argnames: Sequence[str] = (),
                  block: bool = False,
                  registry: Optional[MetricsRegistry] = None):
-        install_xla_hooks()
         self.name = name
         self._static = tuple(static_argnames)
         self._block = block

@@ -8,9 +8,9 @@ enclosing span's id (tracked per-thread via ``contextvars``, so concurrent
 serving requests each get their own stack), and arbitrary JSON attributes.
 
 - unconfigured (the default), spans cost two contextvar operations, a
-  ``perf_counter`` pair and one ``TraceAnnotation.is_enabled()`` — cheap
-  enough to leave permanently in hot-ish paths like the coordinate-descent
-  step loop;
+  ``perf_counter`` pair, one ``TraceAnnotation.is_enabled()`` and the
+  totals' update below — cheap enough to leave permanently in hot-ish paths
+  like the coordinate-descent step loop;
 - while a JAX profiler session runs (``jax.profiler.start_trace``; the
   benchmark's ``--trace 1``), every span also opens a
   ``jax.profiler.TraceAnnotation`` of its name, so it lies on ``/host:CPU``
@@ -25,7 +25,13 @@ serving requests each get their own stack), and arbitrary JSON attributes.
   ``span_finished`` event so the EventBus→metrics bridge folds span
   durations into the registry;
 - ``timed()`` (:mod:`photon_ml_tpu.logging_util`) is now a thin wrapper
-  over a span — stage sections appear in the trace tree for free.
+  over a span — stage sections appear in the trace tree for free;
+- at every span exit, configured or not, the span's count and seconds are
+  added to a table by name (:func:`totals`): the process's flat profile,
+  one dict update under the lock the exit already takes. Nothing else is
+  built or held for it, so it stays on. A span that ran before the first
+  profiler session is in it and in no ring: what a reader wants of the set-up
+  is ``totals()`` minus the ring's records of the same name.
 
 Record layout (one JSON object per line)::
 
@@ -187,6 +193,10 @@ class Tracer:
         #: records on their way to the file and the taps, oldest first: one
         #: that holds a device value not computed yet, and those behind it
         self._pending: collections.deque = collections.deque()
+        #: name -> [count, seconds] of every span that exited (:func:`totals`)
+        self._totals: dict[str, list] = {}
+        #: set once a span has run under a profiler (:func:`profiled`)
+        self._profiled = False
 
     @property
     def enabled(self) -> bool:
@@ -239,6 +249,14 @@ class Tracer:
                     return
                 record = self._pending.popleft()
             self._write(_resolve(record, wait))
+
+    def totals(self) -> dict[str, dict]:
+        """``{name: {"count": n, "seconds": s}}`` of every span that exited
+        in this process, whatever was listening: the job's flat profile by
+        span name. ``record_span``'s externally timed regions are not in it."""
+        with self._lock:
+            return {name: {"count": n, "seconds": s}
+                    for name, (n, s) in self._totals.items()}
 
     def recorded(self) -> list[dict]:
         """A snapshot of the ring, oldest first: the last ``RING_RECORDS``
@@ -321,6 +339,7 @@ class Tracer:
                 **{k: v for k, v in sp.attrs.items()
                    if isinstance(v, (int, float, str))})
             annotation.__enter__()
+            self._profiled = True
         sp.ts = time.time()
         sp.t0 = time.perf_counter()
         try:
@@ -352,6 +371,12 @@ class Tracer:
                     sp.parent_id = next(
                         (a for a in reversed(ancestors) if a in self._open),
                         None)
+                total = self._totals.get(sp.name)
+                if total is None:
+                    self._totals[sp.name] = [1, sp.seconds]
+                else:
+                    total[0] += 1
+                    total[1] += sp.seconds
             if annotation is not None or self._sinking:
                 self._keep(sp.record(), in_ring=annotation is not None)
             bus = self._bus
@@ -480,3 +505,17 @@ def flush(wait: bool = True) -> None:
 
 def recorded() -> list[dict]:
     return GLOBAL_TRACER.recorded()
+
+
+def totals() -> dict[str, dict]:
+    return GLOBAL_TRACER.totals()
+
+
+def profiled() -> bool:
+    """True once this process has seen a profiler session: a span ran under
+    one, or one runs now. Whatever was counted before that is the process's
+    set-up to a reader of its totals (``telemetry/profiling.py`` keeps its
+    compile counts so)."""
+    if not GLOBAL_TRACER._profiled and _profiler_running():
+        GLOBAL_TRACER._profiled = True
+    return GLOBAL_TRACER._profiled

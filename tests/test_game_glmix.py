@@ -217,8 +217,6 @@ def test_a_traced_fit_records_one_solve_span_a_bucket(problem, records):
         for s in spans:
             e, smax, d = buckets[s["bucket"]].tensor_shape
             assert (s["lanes"], s["s_max"], s["dim"]) == (e, smax, d)
-            # its rows indexed on the way in and on the way out, no padding
-            assert s["moved_slots"] == 2 * s["rows"]
             assert s["kernel"] == "closed_form"  # no TPU here
             assert "unresolved" not in s
             assert 0 <= s["converged"] <= s["lanes"]
@@ -231,6 +229,44 @@ def test_a_traced_fit_records_one_solve_span_a_bucket(problem, records):
             assert s["evaluations"] <= s["row_evaluations"] \
                 <= s["evaluations"] * smax
             assert s["row_iterations"] <= s["row_evaluations"] - s["rows"]
+
+
+def test_the_set_up_and_the_unit_spans_nest_as_declared(problem, records):
+    """``game.data.build`` under nothing, ``game.prepare`` > one
+    ``game.dataset.build`` a coordinate, ``game.fit`` > ``cd.sweep``: the
+    spans ``benchmark/readers/setup_spans.py`` reads the set-up by."""
+    arrays, _, _ = problem
+    _, datasets = _fit(arrays)
+    tracing.flush()
+    by_id = {r["span_id"]: r for r in records}
+    parent = lambda r: by_id.get(r["parent_id"], {}).get("name")
+    named = lambda name: [r for r in records if r["name"] == name]
+    (build,) = named("game.data.build")
+    assert build["parent_id"] is None
+    assert (build["rows"], build["shards"], build["id_columns"]) \
+        == (WORKLOAD["rows"], 2, 2)
+    (prepare,) = named("game.prepare")
+    assert prepare["parent_id"] is None
+    builds = {r["coordinate"]: r for r in named("game.dataset.build")}
+    assert set(builds) == set(CONFIG["update_sequence"])
+    assert all(parent(r) == "game.prepare" for r in builds.values())
+    assert builds["global"]["kind"] == "fixed_effect"
+    for cid in ENTITIES:
+        assert builds[cid]["kind"] == "random_effect"
+        assert builds[cid]["rows"] == WORKLOAD["rows"]
+        assert builds[cid]["buckets"] == len(datasets[cid].buckets)
+        assert builds[cid]["entities"] == datasets[cid].n_active_entities
+    (fit,) = named("game.fit")
+    assert fit["parent_id"] is None and fit["configurations"] == 1
+    sweeps = named("cd.sweep")
+    assert sweeps and all(parent(r) == "game.fit" for r in sweeps)
+    # a warm compile that outlives prepare re-parents to root, never to a
+    # span that closed before it did
+    for r in named("jit.compile"):
+        assert r["parent_id"] is None or r["parent_id"] in by_id
+    for outer in (build, prepare, fit):
+        assert all(outer["t1"] <= r["t0"] or r["t1"] <= outer["t0"]
+                   for r in (build, prepare, fit) if r is not outer)
 
 
 def test_set_on_enclosing_reaches_the_nearest_open_span_of_that_name(records):
