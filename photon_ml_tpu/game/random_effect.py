@@ -7,10 +7,14 @@ SingleNodeOptimizationProblem}.scala``): where the reference zips an RDD of
 per-entity breeze problems with per-entity local datasets and runs millions of
 scalar-loop solves inside executors, here every size bucket is ONE batched
 compiled solve whose lanes are the bucket's entities. An L-BFGS bucket (every
-cell's) runs ``optimize/lbfgs.py::minimize_lbfgs_lanes``: one ``while_loop``
-over the batched state, one evaluation a lane a trip, every lane at its own
-place in its own solve, so the bucket runs what its slowest lane evaluates
-(``passes`` on the ``game.re.solve`` span). OWL-QN and TRON buckets are still
+cell's) runs ``optimize/lbfgs.py::minimize_lbfgs_lanes``: a flat loop over
+the batched state, one evaluation a lane a trip, every lane at its own place
+in its own solve, so the bucket runs what its slowest lane evaluates
+(``passes`` on the ``game.re.solve`` span); a bucket of 1,024 lanes or more
+runs it in two stages, its width and then an eighth of it holding the lanes
+still running, so a lane that has ended stops riding along
+(``lane_passes``, ``compactions``).
+OWL-QN and TRON buckets are still
 the single solve's nested loops under ``vmap`` (``minimize_owlqn``,
 ``minimize_tron``: convergence is per-lane masked inside them, a finished
 lane stops changing), which share the inner loop's trips among the running
@@ -641,7 +645,8 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
     scalars for its ``game.re.solve`` span (lanes that weigh something, the
     sums of their iterations and evaluations, plain and weighted by each
     lane's real rows, how many converged, and the most evaluations any lane
-    made)."""
+    made; for a flat loop also its ``passes``, ``lane_passes`` and
+    ``compactions``)."""
     problem = solver._problem()
     objective = problem.objective
 
@@ -667,8 +672,9 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
         data = GLMData(design=DenseDesign(x=x), labels=labels,
                        offsets=offsets, weights=weights)
         # the one place that knows its solves are a batch: an L-BFGS
-        # bucket runs one evaluation a lane a trip (optimize/lbfgs.py)
-        result, passes = problem.run_lanes(data, w0, lam)
+        # bucket runs one evaluation a lane a trip, in stages that drop the
+        # lanes that have ended (optimize/lbfgs.py)
+        result, loop = problem.run_lanes(data, w0, lam)
         variances = jax.vmap(
             lambda w, lane: problem.compute_variances(w, lane, lam))(
                 result.w, data)
@@ -678,12 +684,13 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
                     result.iterations, result.evaluations)
         if pad:
             per_lane = tuple(a[:e_real] for a in per_lane)
-        # one count a shard; none where the solve's loops count no passes
-        passes = (jnp.zeros((0,), jnp.int32) if passes is None
-                  else passes[None])
-        return (*per_lane, passes)
+        # one (passes, lane_passes, compactions) a shard; none where the
+        # solve's loops count no passes
+        loop = (jnp.zeros((0,), jnp.int32) if loop is None
+                else jnp.stack(loop))
+        return (*per_lane, loop)
 
-    def counted(w_out, variances, conv, iterations, evaluations, passes):
+    def counted(w_out, variances, conv, iterations, evaluations, loop):
         # a lane that weighs nothing (the mesh's pad lanes) solved nothing
         rows = jnp.sum(weights > 0, axis=1)
         real = rows > 0
@@ -700,10 +707,15 @@ def _solve_bucket_impl(solver, x, labels, offsets, weights, w0, lam):
             # required passes and its evaluations in rows read
             "row_iterations": by_rows(iterations),
             "row_evaluations": by_rows(evaluations)}
-        if passes.size:
+        if loop.size:
+            passes, lane_passes, compactions = loop.reshape(-1, 3).T
             # the passes it did run, counted by the loop itself (under a
-            # mesh the shards run side by side: the longest shard's)
+            # mesh the shards run side by side: the longest shard's), the
+            # lanes they ran over (every shard's, pad lanes included) and
+            # the stage boundaries a lane still running crossed
             counts["passes"] = jnp.max(passes)
+            counts["lane_passes"] = jnp.sum(lane_passes)
+            counts["compactions"] = jnp.max(compactions)
         return w_out, variances, conv, counts
 
     if solver.mesh is None:

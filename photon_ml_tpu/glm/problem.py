@@ -39,7 +39,9 @@ from photon_ml_tpu.optimize import (
     minimize_tron,
 )
 from photon_ml_tpu.optimize.lbfgs import (
+    LanePasses,
     minimize_lbfgs_lanes,
+    stage_widths,
     vmapped_evaluation,
 )
 from photon_ml_tpu.types import OptimizerType, VarianceComputationType
@@ -118,7 +120,7 @@ class OptimizationProblem:
         return minimize_lbfgs(fun, w0, cfg)
 
     def run_lanes(self, data: GLMData, w0: Array, lam=0.0
-                  ) -> tuple[OptimizerResult, Optional[Array]]:
+                  ) -> tuple[OptimizerResult, Optional[LanePasses]]:
         """:meth:`run` for every lane of a batch: ``data``'s leaves and
         ``w0`` lead with the lane axis, and so do the result's fields; a
         lane's result is what :meth:`run` gives it (an L-BFGS lane's up to
@@ -126,25 +128,34 @@ class OptimizationProblem:
 
         A caller that knows its solves are a batch (a random-effect bucket)
         calls this, not ``vmap(run)``: an L-BFGS batch then runs the flat
-        loop (:func:`minimize_lbfgs_lanes`: one evaluation a lane a trip)
-        and the second value is its ``passes``, the batched evaluations it
-        made. The loop is given the batch's evaluation, lanes last as it
-        holds them: the entity kernel's where the objective's gate says it
-        serves such lanes (by their ``(S, D)`` and dtype), else the
-        objective under ``vmap``. OWL-QN and TRON batches are ``vmap(run)``,
-        whose nested loops count no passes: ``None``.
+        loop (:func:`minimize_lbfgs_lanes`: one evaluation a lane a trip, in
+        stages that drop the lanes that have ended) and the second value is
+        its :class:`LanePasses`: ``passes``, the batched evaluations it made
+        (its slowest lane's evaluations), and ``lane_passes``, the lanes
+        they were over. The loop is given the batch's evaluation, lanes last
+        as it holds them, for each stage's lanes: the entity kernel's where
+        the objective's gate says it serves such lanes (by their ``(S, D)``
+        and dtype), its operands laid out once a stage and every stage as
+        wide as the kernel's block plan takes; else the objective under
+        ``vmap``, every stage a whole number of 128-lane rows. OWL-QN and
+        TRON batches are ``vmap(run)``, whose nested loops count no passes:
+        ``None``.
         """
         if self.config.solver != OptimizerType.LBFGS:
             return jax.vmap(self.run, in_axes=(0, 0, None))(
                 data, w0, lam), None
         _, l2 = self._split(lam)
-        evaluate = self.objective.entity_kernel_evaluation(data, l2)
-        if evaluate is None:
-            evaluate = vmapped_evaluation(
-                lambda lane, w: self.objective.value_and_grad(w, lane, l2),
-                data)
-        return minimize_lbfgs_lanes(evaluate, w0,
-                                    self.config.optimizer_config)
+        objective = self.objective
+        fit = objective.entity_kernel_fit(data)
+        if fit is None:
+            evaluation_of = lambda lanes: vmapped_evaluation(
+                lambda lane, w: objective.value_and_grad(w, lane, l2), lanes)
+        else:
+            evaluation_of = lambda lanes: objective.entity_kernel_evaluation(
+                lanes, l2)
+        return minimize_lbfgs_lanes(
+            evaluation_of, data, w0, self.config.optimizer_config,
+            stage_widths(w0.shape[0], fit))
 
     # --- variance (reference VarianceComputationType SIMPLE / FULL) -------
     def compute_variances(self, w: Array, data: GLMData, lam=0.0) -> Optional[Array]:

@@ -244,6 +244,20 @@ class GLMObjective:
 
         return entity_pad(e, s, d, x.dtype)
 
+    def entity_kernel_fit(self, data: GLMData):
+        """``n -> `` the lanes the entity kernel's block plan takes for
+        ``n`` lanes like ``data``'s (``n`` and the weight-0 lanes that
+        :meth:`entity_pad` would add), the widths the flat L-BFGS loop's
+        stages round to; ``None`` where the gate keeps the closed form for
+        such lanes. ``data``'s leaves lead with the lane axis."""
+        design = data.design
+        s, d = data.labels.shape[-1], design.dim
+        if not self._entity_kernel_serves(design, s, d):
+            return None
+        from photon_ml_tpu.ops.pallas_re import entity_pad
+
+        return lambda n: n + entity_pad(n, s, d, design.x.dtype)
+
     def entity_kernel_evaluation(self, data: GLMData, l2=0.0):
         """The value and gradient of every lane of a batch in one call,
         ``w (d, E) -> (values (E,), grads (d, E))``, lanes last as the flat
@@ -252,7 +266,8 @@ class GLMObjective:
         lanes. ``data``'s leaves lead with the lane axis. The kernel's
         operands are laid out here, once: the returned function closes over
         them, so a loop that calls it reads the design once an evaluation
-        and copies nothing. A batch the solver has not padded to the block
+        and copies nothing (the flat loop asks once a stage, for the lanes
+        the stage holds). A batch the solver has not padded to the block
         plan (:meth:`entity_pad`) is served too, at a pad of ``w`` a call.
         """
         design = data.design

@@ -24,15 +24,22 @@ iteration, the longest search among its lanes: the sum of the iterations'
 slowest searches, where its slowest lane needs far less (OWL-QN and TRON
 buckets still run so). :func:`minimize_lbfgs_lanes` is the flat form for a
 batch that knows it is one (an L-BFGS bucket of a random effect, through
-``glm/problem.py::OptimizationProblem.run_lanes``): ONE loop over the batched
+``glm/problem.py::OptimizationProblem.run_lanes``): one loop over the batched
 state whose trip is one evaluation a lane, each lane at its own place in its
-own solve, so the batch runs what its slowest lane evaluates, and counts its
-passes itself; the price is a direction worked out, and selected, at every
-trip. The needs conflict, so the loops are two. The rules, the constants and
-the order of a lane's operations are one; the flat form writes the two-loop
-recursion and the history update out once more on arrays that carry the lanes
-last, because the per-lane pieces under ``vmap`` cost a TPU eight times the
-bucket's kernel at every trip (the comment above ``_dot`` says how).
+own solve, so the batch runs what its slowest lane evaluates; the price is a
+direction worked out, and selected, at every trip. That loop runs in stages
+of fixed widths (:func:`stage_widths`: the batch's, then an eighth of it):
+when no more lanes run than the next stage holds, the running lanes are
+gathered to its front with their data, and the lanes that have ended no
+longer ride along. It counts what it ran (:class:`LanePasses`):
+``passes``, its batched evaluations, which its slowest lane's evaluations
+equal, and ``lane_passes``, the lanes those evaluations were over, summed
+over trips and stages. The needs conflict, so the loops are two. The rules,
+the constants and the order of a lane's operations are one; the flat form
+writes the two-loop recursion and the history update out once more on
+arrays that carry the lanes last, because the per-lane pieces under
+``vmap`` cost a TPU eight times the bucket's kernel at every trip (the
+comment above ``_dot`` says how).
 
 Line search: backtracking Armijo with adaptive growth. For the convex GLM
 objectives this framework trains, the minimizer is unique, so solutions agree
@@ -44,7 +51,7 @@ though the iteration paths differ; parity is asserted on solutions, not paths
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -247,37 +254,71 @@ def minimize_lbfgs(fun: ValueAndGrad, w0: Array,
 # here are in order of age, newest first, shifted when a pair is stored: no
 # ring position, no per-lane index) and how a dot product over ``d`` is
 # summed (in index order), which moves last bits and nothing else.
+#
+# The loop is traced once a stage in every process, so its pieces bind the
+# operations ``jnp`` would (``jnp.where``: a broadcast and a select;
+# ``jnp.sum``: a reduction) directly, the recursion in ``lax``:
+# ``jnp.where`` traces and lowers a function of its own at each of its
+# sixty-odd calls a stage, which set-up pays. The compiled loop is the same.
+
+
+def _select(c: Array, x, y) -> Array:
+    """``jnp.where(c, x, y)``, its operations bound directly; ``x`` and
+    ``y`` arrays or Python numbers, at least one an array, of one dtype."""
+    like = x if isinstance(x, jax.Array) else y
+    shape = jnp.broadcast_shapes(jnp.shape(c), jnp.shape(x), jnp.shape(y))
+
+    def full(v, dtype):
+        if not isinstance(v, jax.Array):
+            v = lax.convert_element_type(v, dtype)
+        if v.shape == shape:
+            return v
+        return lax.broadcast_in_dim(
+            v, shape, tuple(range(len(shape) - v.ndim, len(shape))))
+    return lax.select(full(c, bool), full(x, like.dtype), full(y, like.dtype))
 
 
 def _dot(a: Array, b: Array) -> Array:
-    return jnp.sum(a * b, axis=0)
+    return lax.reduce_sum(lax.mul(a, b), (0,))
 
 
 def _norm(a: Array) -> Array:
-    return jnp.sqrt(jnp.sum(a * a, axis=0))
+    return lax.sqrt(_dot(a, a))
+
+
+def _by_lane(a: Array) -> Array:
+    """A lane's scalars ``(E,)`` as a ``(1, E)`` row, which scales a
+    ``(d, E)`` array lane by lane (the broadcast ``jnp`` would bind)."""
+    return lax.broadcast_in_dim(a, (1,) + a.shape, (1,))
 
 
 def _two_loop_direction_lanes(g: Array, s_hist: Array, y_hist: Array,
                               rho: Array, n_pairs: Array) -> Array:
-    """:func:`two_loop_direction` for every lane (slot 0 the newest pair)."""
+    """:func:`two_loop_direction` for every lane (slot 0 the newest pair),
+    in ``lax``: the recursion is most of what a stage traces."""
     m = s_hist.shape[0]
-    valid = jnp.minimum(n_pairs, m)
+    count, eps = n_pairs.dtype.type, rho.dtype.type(_EPS)
+    valid = lax.min(n_pairs, count(m))
+    s, y, p = ([lax.index_in_dim(h, k, keepdims=False) for k in range(m)]
+               for h in (s_hist, y_hist, rho))
     q = g
     alphas = []
     for k in range(m):
-        a = jnp.where(k < valid, rho[k] * _dot(s_hist[k], q), 0.0)
-        q = q - a * y_hist[k]
+        a = _select(lax.gt(valid, count(k)), lax.mul(p[k], _dot(s[k], q)),
+                    0.0)
+        q = lax.sub(q, lax.mul(_by_lane(a), y[k]))
         alphas.append(a)
 
-    yy = _dot(y_hist[0], y_hist[0])
-    sy = _dot(s_hist[0], y_hist[0])
-    gamma = jnp.where((valid > 0) & (yy > _EPS), sy / jnp.maximum(yy, _EPS),
-                      1.0)
-    r = gamma * q
+    yy, sy = _dot(y[0], y[0]), _dot(s[0], y[0])
+    gamma = _select(
+        lax.bitwise_and(lax.gt(valid, count(0)), lax.gt(yy, eps)),
+        lax.div(sy, lax.max(yy, eps)), 1.0)
+    r = lax.mul(_by_lane(gamma), q)
     for k in reversed(range(m)):
-        b = jnp.where(k < valid, rho[k] * _dot(y_hist[k], r), 0.0)
-        r = r + (alphas[k] - b) * s_hist[k]
-    return -r
+        b = _select(lax.gt(valid, count(k)), lax.mul(p[k], _dot(y[k], r)),
+                    0.0)
+        r = lax.add(r, lax.mul(_by_lane(lax.sub(alphas[k], b)), s[k]))
+    return lax.neg(r)
 
 
 def _update_history_lanes(s_hist: Array, y_hist: Array, rho: Array,
@@ -287,11 +328,11 @@ def _update_history_lanes(s_hist: Array, y_hist: Array, rho: Array,
     slot 0 and the oldest of ``m`` is dropped."""
     sy = _dot(step, y)
     store = accept & (sy > _EPS * _norm(step) * _norm(y))
-    push = lambda hist, new: jnp.where(
+    push = lambda hist, new: _select(
         store, jnp.concatenate([new[None], hist[:-1]]), hist)
     return (push(s_hist, step), push(y_hist, y),
             push(rho, 1.0 / jnp.maximum(sy, _EPS)),
-            jnp.where(store, n_pairs + 1, n_pairs))
+            _select(store, n_pairs + 1, n_pairs))
 
 
 @jax.tree_util.register_dataclass
@@ -318,9 +359,9 @@ def _begin_search(s: _State):
     d_dir = _two_loop_direction_lanes(s.g, s.s_hist, s.y_hist, s.rho,
                                       s.n_pairs)
     descent = _dot(s.g, d_dir) < 0
-    d_dir = jnp.where(descent, d_dir, -s.g)
-    alpha0 = jnp.where(s.n_pairs > 0, 1.0,
-                       1.0 / jnp.maximum(_norm(d_dir), 1.0))
+    d_dir = _select(descent, d_dir, -s.g)
+    alpha0 = _select(s.n_pairs > 0, 1.0,
+                     1.0 / jnp.maximum(_norm(d_dir), 1.0))
     return d_dir, _dot(s.g, d_dir), alpha0
 
 
@@ -341,27 +382,27 @@ def _trip(fun, lanes: _Lanes, tol: Array, config: OptimizerConfig) -> _Lanes:
 
     s_hist, y_hist, rho, n_pairs = _update_history_lanes(
         s.s_hist, s.y_hist, s.rho, s.n_pairs, w_t - s.w, g_t - s.g, moves)
-    it = jnp.where(ends, s.it + 1, s.it)
+    it = _select(ends, s.it + 1, s.it)
     gnorm = _norm(g_t)
     values, gnorms = s.values, s.grad_norms
     if values.shape[0]:  # track_states
         at = ends & (jnp.arange(values.shape[0])[:, None] == it)
-        values = jnp.where(
-            at, jnp.where(ok, f_t, s.f).astype(jnp.float32), values)
-        gnorms = jnp.where(
-            at, jnp.where(ok, gnorm, _norm(s.g)).astype(jnp.float32), gnorms)
-    stalls = jnp.where(
-        ends, jnp.where(ok & (f_t >= s.f), s.stalls + 1, jnp.int32(0)),
+        values = _select(
+            at, _select(ok, f_t, s.f).astype(jnp.float32), values)
+        gnorms = _select(
+            at, _select(ok, gnorm, _norm(s.g)).astype(jnp.float32), gnorms)
+    stalls = _select(
+        ends, _select(ok & (f_t >= s.f), s.stalls + 1, jnp.int32(0)),
         s.stalls)
     solve = _State(
-        w=jnp.where(moves, w_t, s.w),
-        f=jnp.where(moves, f_t, s.f),
-        g=jnp.where(moves, g_t, s.g),
+        w=_select(moves, w_t, s.w),
+        f=_select(moves, f_t, s.f),
+        g=_select(moves, g_t, s.g),
         s_hist=s_hist, y_hist=y_hist, rho=rho, n_pairs=n_pairs,
         it=it,
-        evals=jnp.where(running, s.evals + 1, s.evals),
-        converged=jnp.where(ends, ok & (gnorm <= tol), s.converged),
-        failed=jnp.where(ends, (~ok) | (stalls >= 2), s.failed),
+        evals=_select(running, s.evals + 1, s.evals),
+        converged=_select(ends, ok & (gnorm <= tol), s.converged),
+        failed=_select(ends, (~ok) | (stalls >= 2), s.failed),
         stalls=stalls,
         values=values, grad_norms=gnorms,
     )
@@ -369,13 +410,13 @@ def _trip(fun, lanes: _Lanes, tol: Array, config: OptimizerConfig) -> _Lanes:
     halves = running & ~ends
     return _Lanes(
         solve=solve,
-        d=jnp.where(ends, d_dir, lanes.d),
-        gd=jnp.where(ends, gd, lanes.gd),
-        alpha=jnp.where(ends, alpha0,
-                        jnp.where(halves, lanes.alpha * 0.5, lanes.alpha)),
-        halvings=jnp.where(ends, jnp.int32(0),
-                           jnp.where(halves, lanes.halvings + 1,
-                                     lanes.halvings)),
+        d=_select(ends, d_dir, lanes.d),
+        gd=_select(ends, gd, lanes.gd),
+        alpha=_select(ends, alpha0,
+                      _select(halves, lanes.alpha * 0.5, lanes.alpha)),
+        halvings=_select(ends, jnp.int32(0),
+                         _select(halves, lanes.halvings + 1,
+                                 lanes.halvings)),
     )
 
 
@@ -394,36 +435,110 @@ def lanes_first(a: Array) -> Array:
 
 def vmapped_evaluation(fun: Callable[[Any, Array], tuple[Array, Array]],
                        lanes: Any) -> Callable[[Array], tuple[Array, Array]]:
-    """The evaluation :func:`minimize_lbfgs_lanes` takes, from a per-lane
-    ``fun(lane, w (d,)) -> (value, grad (d,))`` and ``lanes``, a pytree whose
-    leaves lead with the lane axis: ``vmap(fun)`` between two column stacks,
-    paid at every trip. (A batch whose objective evaluates lanes-last
-    arrays itself hands that over and pays neither.)"""
+    """An evaluation of the kind :func:`minimize_lbfgs_lanes` asks for, from
+    a per-lane ``fun(lane, w (d,)) -> (value, grad (d,))`` and ``lanes``, a
+    pytree whose leaves lead with the lane axis: ``vmap(fun)`` between two
+    column stacks, paid at every trip. (A batch whose objective evaluates
+    lanes-last arrays itself hands that over and pays neither.)"""
     def evaluate(w):  # (d, E) -> (E,), (d, E)
         f, g = jax.vmap(fun)(lanes, lanes_first(w))
         return f, lanes_last(g)
     return evaluate
 
 
-def minimize_lbfgs_lanes(evaluate: Callable[[Array], tuple[Array, Array]],
-                         w0: Array,
-                         config: OptimizerConfig = OptimizerConfig()
-                         ) -> tuple[OptimizerResult, Array]:
-    """Minimize every lane of a batch from ``w0[e]`` (``w0``: ``(E, d)``),
-    given the batch's evaluation ``w (d, E) -> (values (E,), grads (d, E))``
-    on arrays that carry the lanes last, in which a lane's value and
-    gradient depend on its own column alone: the flat form (module
-    docstring).
+#: the narrowest stage of the flat loop: one row of the chip's 128 lanes
+LANE_ROW = 128
+#: how many times narrower the flat loop's second stage is than its first
+NARROWING = 8
+
+
+def stage_widths(n_lanes: int,
+                 fit: Optional[Callable[[int], int]] = None
+                 ) -> tuple[int, ...]:
+    """The widths of :func:`minimize_lbfgs_lanes`' stages for a batch of
+    ``n_lanes``: the batch's own, then an eighth of it rounded up by ``fit``
+    to a width the batch's evaluation takes (a whole number of
+    :data:`LANE_ROW` where ``fit`` is None), where that eighth is a row or
+    more; a narrower batch is one stage. Two stages, not a halving at every
+    stage: each stage is a loop of its own, which every process traces,
+    lowers and loads at set-up, and each boundary moves its lanes; past an
+    eighth the lanes still running cost the chip little (PERF.md, the
+    random-effect sweep's time).
+    The widths follow from the batch's shape alone, so one compiled
+    program serves every batch of it."""
+    if fit is None:
+        fit = lambda n: -(-n // LANE_ROW) * LANE_ROW
+    if n_lanes < NARROWING * LANE_ROW:
+        return (n_lanes,)
+    return (n_lanes, fit(-(-n_lanes // NARROWING)))
+
+
+class LanePasses(NamedTuple):
+    """What :func:`minimize_lbfgs_lanes` ran, counted by the loop (int32
+    scalars)."""
+
+    #: batched evaluations: the one at ``w0`` and one a trip of any stage;
+    #: the largest ``evaluations`` of any lane
+    passes: Array
+    #: the lanes those evaluations were over: the batch's width for the one
+    #: at ``w0``, then each stage's width times its trips, lanes that had
+    #: ended (or hold no data) included
+    lane_passes: Array
+    #: the stage boundaries crossed with a lane still running
+    compactions: Array
+
+
+def _take_lanes(tree, idx: Array):
+    """Every leaf of a lanes-last ``tree`` at lanes ``idx``."""
+    return jax.tree_util.tree_map(lambda a: jnp.take(a, idx, axis=-1), tree)
+
+
+def _put_lanes(tree, idx: Array, new):
+    """``tree`` with lanes ``idx`` of every leaf set to ``new``'s."""
+    return jax.tree_util.tree_map(lambda a, v: a.at[..., idx].set(v),
+                                  tree, new)
+
+
+def _outputs(s: _State) -> tuple:
+    """The fields of a solve that :func:`minimize_lbfgs_lanes` returns."""
+    return (s.w, s.f, s.g, s.it, s.evals, s.converged, s.values,
+            s.grad_norms)
+
+
+def minimize_lbfgs_lanes(evaluation_of: Callable[[Any], Callable[
+                             [Array], tuple[Array, Array]]],
+                         lanes: Any, w0: Array,
+                         config: OptimizerConfig = OptimizerConfig(),
+                         widths: Optional[tuple[int, ...]] = None
+                         ) -> tuple[OptimizerResult, LanePasses]:
+    """Minimize every lane of a batch from ``w0[e]`` (``w0``: ``(E, d)``):
+    the flat form (module docstring). ``lanes`` is the batch's data, a
+    pytree whose leaves lead with the lane axis, and ``evaluation_of(lanes)``
+    its evaluation ``w (d, E) -> (values (E,), grads (d, E))`` on arrays
+    that carry the lanes last, in which a lane's value and gradient depend on
+    its own column alone; it is asked once a stage, for the lanes the stage
+    holds, so that what it lays out is laid out once a stage.
+
+    The loop runs in stages of ``widths`` (:func:`stage_widths` of ``E`` by
+    default; the first is ``E``). A stage's trips run while more lanes run
+    than the next stage holds, the last stage's while any lane runs. Then
+    the running lanes, their state and their data are gathered to the front
+    of the next stage, lanes that have ended fill its rest, and the lanes
+    the stage held are written back to the batch's results. A lane's trip
+    reads its own column alone, so where it runs does not change it.
 
     Returns the lanes' results (every field leads with the lane axis), each
-    what the loop gives that lane alone, bit for bit, and what
-    :func:`minimize_lbfgs` gives it up to the order in which a dot product
-    over ``d`` is summed; and ``passes`` (int32 scalar): the batched
-    evaluations made, the one at ``w0`` and one a trip of the loop, counted
-    by the loop itself. ``passes`` equals the largest ``evaluations`` of any
-    lane.
+    what the loop gives that lane alone, bit for bit, in one stage or many,
+    and what :func:`minimize_lbfgs` gives it up to the order in which a dot
+    product over ``d`` is summed; and the loop's :class:`LanePasses`.
     """
     m, (n_lanes, d) = config.history, w0.shape
+    widths = stage_widths(n_lanes) if widths is None else tuple(widths)
+    if widths[0] != n_lanes or any(
+            b >= a for a, b in zip(widths, widths[1:])):
+        raise ValueError(f"stage widths {widths} do not narrow from the "
+                         f"batch's {n_lanes} lanes")
+    evaluate = evaluation_of(lanes)
     w = lanes_last(w0)
     f0, g0 = evaluate(w)
     gnorm0 = _norm(g0)
@@ -444,22 +559,43 @@ def minimize_lbfgs_lanes(evaluate: Callable[[Array], tuple[Array, Array]],
         values=values, grad_norms=gnorms,
     )
     d_dir, gd, alpha0 = _begin_search(solve)
-    init = _Lanes(solve=solve, d=d_dir, gd=gd, alpha=alpha0,
-                  halvings=jnp.zeros((n_lanes,), jnp.int32))
+    state = (_Lanes(solve=solve, d=d_dir, gd=gd, alpha=alpha0,
+                    halvings=jnp.zeros((n_lanes,), jnp.int32)), tol)
+    results = where = None  # the batch's results; the lane a slot holds
+    trips, lane_passes, compactions = (jnp.int32(0), jnp.int32(n_lanes),
+                                       jnp.int32(0))
+    for k, width in enumerate(widths):
+        if k:
+            running = _running(state[0].solve, config)
+            results = (_outputs(state[0].solve) if where is None else
+                       _put_lanes(results, where, _outputs(state[0].solve)))
+            # the running lanes first, in their order (a stable sort)
+            pick = jnp.argsort(~running, stable=True)[:width]
+            where = pick if where is None else where[pick]
+            state = _take_lanes(state, pick)
+            evaluate = evaluation_of(jax.tree_util.tree_map(
+                lambda a: a[where], lanes))
+            compactions = compactions + jnp.any(running).astype(jnp.int32)
+        holds = widths[k + 1] if k + 1 < len(widths) else 0
 
-    def cond(carry):
-        return jnp.any(_running(carry[0].solve, config))
+        def cond(carry, holds=holds):
+            return jnp.sum(_running(carry[0][0].solve, config)) > holds
 
-    def body(carry):
-        state, trips = carry
-        return _trip(evaluate, state, tol, config), trips + 1
+        def body(carry, evaluate=evaluate):
+            (lanes_k, tol_k), n = carry
+            return (_trip(evaluate, lanes_k, tol_k, config), tol_k), n + 1
 
-    state, trips = lax.while_loop(cond, body, (init, jnp.int32(0)))
-    final = state.solve
+        state, n = lax.while_loop(cond, body, (state, jnp.int32(0)))
+        trips = trips + n
+        lane_passes = lane_passes + width * n
+    final = _outputs(state[0].solve)
+    if where is not None:
+        final = _put_lanes(results, where, final)
+    w, f, g, it, evals, converged, values, gnorms = final
     return OptimizerResult(
-        w=lanes_first(final.w), value=final.f, grad_norm=_norm(final.g),
-        iterations=final.it, evaluations=final.evals,
-        converged=final.converged,
-        values=final.values.T, grad_norms=final.grad_norms.T,
-        hvps=jnp.zeros_like(final.it),
-    ), trips + 1
+        w=lanes_first(w), value=f, grad_norm=_norm(g),
+        iterations=it, evaluations=evals, converged=converged,
+        values=values.T, grad_norms=gnorms.T,
+        hvps=jnp.zeros_like(it),
+    ), LanePasses(passes=trips + 1, lane_passes=lane_passes,
+                  compactions=compactions)

@@ -224,6 +224,10 @@ def test_a_traced_fit_records_one_solve_span_a_bucket(problem, records):
                 <= s["lanes"] * s["max_lane_evaluations"]
             # the bucket ran what its slowest lane evaluated, and no more
             assert s["passes"] == s["max_lane_evaluations"]
+            # over the lanes still running, in stages (none under 1,024)
+            assert s["evaluations"] <= s["lane_passes"] \
+                <= e * s["passes"]
+            assert e >= 1024 or s["compactions"] == 0
             assert s["iterations"] <= s["evaluations"] - s["lanes"]
             # every lane has a row at least and s_max at most
             assert s["evaluations"] <= s["row_evaluations"] \
@@ -333,11 +337,17 @@ def test_a_buckets_counts_are_its_lanes_results_summed(kernel):
         == (128 - lanes if kernel == "pallas_interpreter" else 0)
     data = GLMData(design=DenseDesign(x=args[0]), labels=args[1],
                    offsets=args[2], weights=args[3])
-    per_lane, passes = jax.jit(problem_.run_lanes)(data, w0, lam)
+    per_lane, loop = jax.jit(problem_.run_lanes)(data, w0, lam)
     np.testing.assert_array_equal(w, per_lane.w)
     np.testing.assert_array_equal(converged, per_lane.converged)
-    assert int(counts["passes"]) == int(passes) \
+    assert int(counts["passes"]) == int(loop.passes) \
         == int(counts["max_lane_evaluations"])
+    # one stage under eight 128-lane rows: every lane rides every pass, the
+    # bucket's pad lanes included
+    padded = lanes + problem_.objective.entity_pad(args[0])
+    assert int(counts["lane_passes"]) == padded * int(loop.passes)
+    assert int(loop.lane_passes) == lanes * int(loop.passes)
+    assert int(counts["compactions"]) == int(loop.compactions) == 0
     nested = jax.jit(jax.vmap(problem_.run, in_axes=(0, 0, None)))(
         data, w0, lam)
     np.testing.assert_allclose(w, nested.w, rtol=1e-3, atol=1e-5)
@@ -420,4 +430,5 @@ def test_the_kernels_bucket_solve_is_the_closed_forms_lane_for_lane(
     evaluations = np.asarray(kernel.evaluations)
     assert (evaluations[[3, 77]] == 1).all()
     assert len(set(evaluations)) >= 4
-    assert int(kernel_passes) == int(closed_passes) == evaluations.max()
+    assert int(kernel_passes.passes) == int(closed_passes.passes) \
+        == evaluations.max()

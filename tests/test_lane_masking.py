@@ -302,9 +302,10 @@ def _zeros(lanes):
 def _flat(lanes, config, counter=None):
     """``(result, passes)`` of the flat loop from zero."""
     fun = _lane_fun(counter or Counter())
-    out = jax.jit(lambda l, w: minimize_lbfgs_lanes(
-        vmapped_evaluation(fun, l), w, config))(lanes, _zeros(lanes))
-    return jax.block_until_ready(out)
+    result, loop = jax.jit(lambda l, w: minimize_lbfgs_lanes(
+        lambda ls: vmapped_evaluation(fun, ls), l, w, config))(
+            lanes, _zeros(lanes))
+    return jax.block_until_ready((result, loop.passes))
 
 
 def _nested(lanes, config, counter=None):

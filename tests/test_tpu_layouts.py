@@ -9,7 +9,9 @@ then costs eight times the bucket's kernel (PERF.md, PR 29). The bucket's
 entity kernel (``ops/pallas_re.py``) takes its operands entities-last for the
 same reason, laid out once before the loop (PERF.md, PR 31). The tests are in
 this one file and describe the topology inside a fixture: one process loads
-the TPU's library.
+the TPU's library. The loop runs in stages, each a ``while`` of its own over
+the lanes still running; what moves the lanes between stages runs between
+the loops.
 """
 
 import re
@@ -21,8 +23,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from photon_ml_tpu.glm.problem import GLMOptimizationConfiguration
+from photon_ml_tpu.ops.pallas_re import entity_pad, lane_fits_vmem
 from photon_ml_tpu.ops.regularization import L2Regularization
 from photon_ml_tpu.optimize import OptimizerConfig
+from photon_ml_tpu.optimize.lbfgs import stage_widths
 from photon_ml_tpu.types import TaskType
 
 E, S, D, M = 4000, 16, 8, 10  # E: the kernel's block plan pads it to 4096
@@ -75,7 +79,15 @@ def bucket_program(one_chip):
     return text
 
 
-LANES = r"40\d\d"  # E, or E padded to the kernel's block plan
+def _fit(n):
+    """The lanes the kernel's block plan takes for ``n`` of the bucket's."""
+    return n + entity_pad(n, S, D, jnp.float32)
+
+
+#: the flat loop's stages: E padded to the block plan (4096), then an eighth
+WIDTHS = stage_widths(_fit(E), _fit)
+#: E, or a stage's width
+LANES = "|".join(str(w) for w in (E,) + WIDTHS)
 
 
 def _layouts(text, shape):
@@ -84,55 +96,77 @@ def _layouts(text, shape):
     return set(re.findall(rf"f32\[{shape}\]{{([0-9,]+):", text))
 
 
-def test_the_buckets_histories_are_dense_on_the_chip(bucket_program):
-    assert _layouts(bucket_program, f"{M},{D},(?:{LANES})") == {"2,1,0"}
-    assert not _layouts(bucket_program, f"(?:{LANES}),{M},{D}")
-    # the iterate, the gradient and the direction: lanes minor too
-    assert _layouts(bucket_program, f"{D},(?:{LANES})") == {"1,0"}
-
-
-def test_the_bucket_is_one_loop(bucket_program):
-    """No loop inside the loop: one evaluation a trip."""
-    assert len(re.findall(r" while\(", bucket_program)) == 1
-
-
-def _loop_body(text):
-    """The text of the ``while`` loop's body and of every computation it
+def _loop_bodies(text):
+    """The text of each ``while`` loop's body and of every computation it
     calls (its fusions, the kernel's call), from the compiled module."""
     computations = dict(re.findall(
         r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S))
-    (body,) = re.findall(r" while\(.*body=%?([\w.\-]+)", text)
-    seen, todo = {}, [body]
-    while todo:
-        name = todo.pop()
-        if name not in seen:
-            seen[name] = computations[name]
-            todo += re.findall(
-                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
-                seen[name])
-    return "\n".join(seen.values())
+    bodies = []
+    for body in re.findall(r" while\(.*body=%?([\w.\-]+)", text):
+        seen, todo = {}, [body]
+        while todo:
+            name = todo.pop()
+            if name not in seen:
+                seen[name] = computations[name]
+                todo += re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                    seen[name])
+        bodies.append("\n".join(seen.values()))
+    return bodies
+
+
+def test_the_buckets_histories_are_dense_on_the_chip(bucket_program):
+    """Inside every stage's loop. Between the loops, where the running
+    lanes are gathered to the next stage's front once a stage, the compiler
+    may lay a history lanes-major for the gather: no trip pays that."""
+    bodies = _loop_bodies(bucket_program)
+    for body in bodies:
+        assert _layouts(body, f"{M},{D},(?:{LANES})") == {"2,1,0"}
+        assert not _layouts(body, f"(?:{LANES}),{M},{D}")
+        # the iterate, the gradient and the direction: lanes minor too
+        assert _layouts(body, f"{D},(?:{LANES})") == {"1,0"}
+    # every stage's width is in some loop's arrays
+    for w in WIDTHS:
+        assert any(_layouts(body, f"{M},{D},{w}") for body in bodies), w
+
+
+def test_the_bucket_is_one_loop(bucket_program):
+    """One flat loop, one evaluation a trip, run as a ``while`` a stage: no
+    loop inside a loop."""
+    bodies = _loop_bodies(bucket_program)
+    assert len(bodies) == len(WIDTHS) == 2
+    for body in bodies:
+        assert not re.search(r" while\(", body)
 
 
 def test_the_kernel_takes_the_bucket_entities_last(bucket_program):
     """The design reaches the kernel as ``(D, S, E)``, the entities in the
-    128-lane dimension, dense; and the loop's body neither reads nor writes
-    an array that holds ``D`` there (the bucket's ``(E, S, D)`` statics are
-    laid out once, before the loop), nor stacks the columns of ``w`` or of
-    the gradient: the kernel takes the loop's ``(d, E)`` iterate as it is."""
+    128-lane dimension, dense; and no stage's loop body reads or writes an
+    array that holds ``D`` there (the bucket's ``(E, S, D)`` statics are
+    laid out once a stage, before its loop), nor stacks the columns of ``w``
+    or of the gradient: the kernel takes the loop's ``(d, E)`` iterate as it
+    is."""
     kernel = re.findall(r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*",
                         bucket_program)
-    assert len(kernel) == 2  # the evaluation at w0, and the loop's
+    # the evaluation at w0, and each stage's loop
+    assert len(kernel) == 1 + len(WIDTHS)
     for call in kernel:
         assert re.search(rf"operand_layout_constraints={{f32\[{D},{S},"
                          rf"(?:{LANES})\]{{2,1,0}}", call)
-    assert _layouts(bucket_program, f"{D},{S},(?:{LANES})") == {"2,1,0"}
-    body = _loop_body(bucket_program)
-    assert "tpu_custom_call" in body
-    assert not re.search(rf"f32\[(?:{LANES}),{S},{D}\]", body)
-    assert not re.search(rf"f32\[(?:{LANES}),{D}\]", body)
-    assert not re.search(rf"f32\[{D},(?:{LANES})\][^ ]* concatenate\(", body)
-    assert not re.search(rf"f32\[{D},{S},(?:{LANES})\][^ ]* (?:copy|transpose)\(",
-                         body)
+    # one dense layout of a stage's design, made before the stage's loop
+    for w in WIDTHS:
+        made = re.findall(rf"= f32\[{D},{S},{w}\]{{2,1,0:[^}}]*}} "
+                          rf"(?:copy|transpose|fusion)\(", bucket_program)
+        assert len(made) == 1, w
+    for body in _loop_bodies(bucket_program):
+        assert _layouts(body, f"{D},{S},(?:{LANES})") <= {"2,1,0"}
+        assert "tpu_custom_call" in body
+        assert not re.search(rf"f32\[(?:{LANES}),{S},{D}\]", body)
+        assert not re.search(rf"f32\[(?:{LANES}),{D}\]", body)
+        assert not re.search(rf"f32\[{D},(?:{LANES})\][^ ]* concatenate\(",
+                             body)
+        assert not re.search(
+            rf"f32\[{D},{S},(?:{LANES})\][^ ]* (?:copy|transpose)\(", body)
 
 
 # --- the random-effect sweep's moves (PERF.md, PR 33) ------------------------
@@ -172,14 +206,31 @@ def sweep_program(one_chip):
     return text
 
 
+def _stage_widths(e, s):
+    """The flat loop's stage widths in an ``(e, s)`` bucket of the sweep."""
+    if not lane_fits_vmem(s, D, jnp.float32):
+        return stage_widths(e)
+    fit = lambda n: n + entity_pad(n, s, D, jnp.float32)
+    return stage_widths(fit(e), fit)
+
+
+#: the lanes a stage of some bucket's loop holds: between its stages the
+#: loop moves lanes (entities), each a bucket's width or a stage's
+STAGE_LANES = {w for e, s in SWEEP_BUCKETS for w in _stage_widths(e, s)}
+
+
 def test_the_sweep_moves_rows_and_not_padded_slots(sweep_program):
     """What the sweep indexes, it indexes by the row: one scatter (the
     offsets into their slots) and its sort, each over the ``n`` rows, none
     over a bucket's padded slots; and every gather (the scores' look-up,
     the warm start's and the coefficient mirror's) fetches rows of 128
-    lanes: a gather of scalars runs an element at a time on the chip."""
+    lanes: a gather of scalars runs an element at a time on the chip. The
+    flat loops' stages move their lanes apart from these: every other
+    sort, scatter and gather is over a stage's lanes."""
     moved = re.findall(r"= \(?[fs]32\[(\d+)\][^\n]* (sort|scatter)\(",
                        sweep_program)
+    moved = [(size, kind) for size, kind in moved
+             if int(size) not in STAGE_LANES]
     assert {kind for _, kind in moved} == {"sort", "scatter"}
     assert len(moved) == 2
     slots = sum(e * s for e, s in SWEEP_BUCKETS)
@@ -188,9 +239,13 @@ def test_the_sweep_moves_rows_and_not_padded_slots(sweep_program):
     gathers = re.findall(
         r"= f32\[([0-9,]+)\]\S* gather\([^\n]*slice_sizes={([0-9,]+)}",
         sweep_program)
-    assert len(gathers) >= 2 + 2 * len(SWEEP_BUCKETS)
-    assert all(shape.endswith(",128") and fetched == "1,128"
-               for shape, fetched in gathers), gathers
+    rows = [(shape, fetched) for shape, fetched in gathers
+            if fetched == "1,128"]
+    assert len(rows) >= 2 + 2 * len(SWEEP_BUCKETS)
+    assert all(shape.endswith(",128") for shape, _ in rows), rows
+    lanes = [shape for shape, fetched in gathers if fetched != "1,128"]
+    assert all(any(int(n) in STAGE_LANES for n in shape.split(","))
+               for shape in lanes), lanes
 
 
 def test_the_sweep_holds_one_index_of_the_rows(sweep_program):

@@ -168,8 +168,9 @@ def test_minimizers_without_products_count_none(name):
     else:
         lanes = (x.reshape(3, ROWS // 3, DIM), y.reshape(3, ROWS // 3))
         result, _ = minimize_lbfgs_lanes(
-            vmapped_evaluation(lambda lane, w: evaluation(*lane)(w), lanes),
-            jnp.zeros((3, DIM)), config)
+            lambda ls: vmapped_evaluation(
+                lambda lane, w: evaluation(*lane)(w), ls),
+            lanes, jnp.zeros((3, DIM)), config)
     assert result.hvps.dtype == jnp.int32
     assert result.hvps.shape == result.iterations.shape
     assert int(jnp.sum(result.iterations)) > 0
